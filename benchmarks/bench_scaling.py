@@ -47,11 +47,8 @@ step, shardings, bspecs, layout = make_train_step(cfg, mesh)
 sstructs, _, _, _ = state_struct(cfg, mesh)
 bstructs, _ = batch_struct(cfg, mesh, layout)
 # no jax.set_mesh here: the shard_mapped step carries its mesh explicitly
-# (and set_mesh does not exist on pre-0.5 jax)
 compiled = step.lower(sstructs, bstructs).compile()
 ca = compiled.cost_analysis() or {{}}
-if isinstance(ca, (list, tuple)):      # pre-0.5 jax: one dict per device
-    ca = ca[0] if ca else {{}}
 coll = parse_collective_bytes(compiled.as_text())
 print(json.dumps(dict(ranks={ranks}, batch={batch}, microbatches={mb},
                       flops=float(ca.get("flops", 0)),
@@ -60,7 +57,9 @@ print(json.dumps(dict(ranks={ranks}, batch={batch}, microbatches={mb},
 
 
 def run_point(ranks: int, batch: int, microbatches: int = 1) -> dict:
-    env = dict(os.environ, PYTHONPATH=str(SRC))
+    # forced host devices: the child stays on the CPU, off any chip
+    # the parent process may hold
+    env = dict(os.environ, PYTHONPATH=str(SRC), JAX_PLATFORMS="cpu")
     code = textwrap.dedent(SUB.format(ranks=ranks, batch=batch,
                                       mb=microbatches))
     r = subprocess.run([sys.executable, "-c", code], capture_output=True,

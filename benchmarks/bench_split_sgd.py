@@ -76,7 +76,10 @@ def optimizer_bytes_row(name: str, U: int, E: int, NB: int, L: int) -> dict:
     slab's WIDTH and DTYPE: momentum / elementwise adagrad E lanes (fp32,
     or 2-byte bf16-hi for the compressed ``*_bf16`` kinds — half the
     state bytes), row-wise adagrad ONE fp32 scalar, the stateless kinds
-    zero."""
+    zero.  A lower bound on the chip: the kernel moves the tile-aligned
+    row GROUP holding each touched row (128 rows of a narrow slab, 8 or 16
+    of a 128-lane-multiple one), so rows that share no group cost a whole
+    group each."""
     from repro.optim import row as row_optim
     opt = row_optim.get(name)
     state_bytes = sum((w or E) * dt.itemsize

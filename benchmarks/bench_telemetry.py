@@ -169,7 +169,9 @@ print(json.dumps(dict(
 
 
 def _run_sub(code: str) -> dict:
-    env = dict(os.environ, PYTHONPATH=str(SRC))
+    # forced host devices: the child stays on the CPU, off any chip
+    # the parent process may hold
+    env = dict(os.environ, PYTHONPATH=str(SRC), JAX_PLATFORMS="cpu")
     r = subprocess.run([sys.executable, "-c", textwrap.dedent(code)],
                        capture_output=True, text=True, env=env, timeout=900)
     if r.returncode != 0:

@@ -1,65 +1,32 @@
-"""JAX version-portability shims.
+"""Shared spellings of the mesh / shard_map API (jax >= 0.9).
 
-The codebase targets the current jax API (``jax.shard_map`` with
-``check_vma``, ``jax.make_mesh(..., axis_types=...)``,
-``jax.sharding.get_abstract_mesh``).  Older runtimes (<= 0.4.x) spell these
-``jax.experimental.shard_map.shard_map(check_rep=...)``, ``jax.make_mesh``
-without axis types, and the thread-resources physical mesh.  Every internal
-module routes through here so the repo runs unmodified on both.
+Every internal module builds meshes and shard_maps through here, so the
+axis types and the ``check_vma`` default live in one place.
 """
 
 from __future__ import annotations
 
 import jax
-
-_P = jax.sharding.PartitionSpec
+from jax.sharding import AxisType
 
 
 def shard_map(f, *, mesh, in_specs, out_specs, check_vma: bool = True):
-    """jax.shard_map with the pre-0.5 fallback (check_vma ~ check_rep)."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=check_vma)
-    from jax.experimental.shard_map import shard_map as _sm
-    return _sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-               check_rep=check_vma)
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=check_vma)
 
 
 def make_mesh(shape, axes):
-    """jax.make_mesh with explicit-Auto axis types where supported."""
-    shape, axes = tuple(shape), tuple(axes)
-    try:
-        from jax.sharding import AxisType
-        return jax.make_mesh(shape, axes,
-                             axis_types=(AxisType.Auto,) * len(axes))
-    except (ImportError, TypeError):
-        pass
-    if hasattr(jax, "make_mesh"):
-        return jax.make_mesh(shape, axes)
-    import math
-    import numpy as np
-    devices = np.asarray(jax.devices()[:math.prod(shape)]).reshape(shape)
-    return jax.sharding.Mesh(devices, axes)
+    """jax.make_mesh with explicit-Auto axis types."""
+    return jax.make_mesh(tuple(shape), tuple(axes),
+                         axis_types=(AxisType.Auto,) * len(axes))
 
 
 def axis_size(axis_name) -> int:
     """Size of a named mesh axis (or product over a tuple of axes) inside
-    shard_map.  Pre-0.5 jax has no jax.lax.axis_size; psum of a literal 1
-    constant-folds to the size there."""
-    if isinstance(axis_name, (tuple, list)):
-        s = 1
-        for a in axis_name:
-            s *= axis_size(a)
-        return s
-    if hasattr(jax.lax, "axis_size"):
-        return jax.lax.axis_size(axis_name)
-    return jax.lax.psum(1, axis_name)
+    shard_map."""
+    return jax.lax.axis_size(axis_name)
 
 
 def get_abstract_mesh():
-    """Mesh of the current tracing context (abstract on new jax, the
-    physical thread-resources mesh on old)."""
-    if hasattr(jax.sharding, "get_abstract_mesh"):
-        return jax.sharding.get_abstract_mesh()
-    from jax._src.mesh import thread_resources
-    return thread_resources.env.physical_mesh
+    """Mesh of the current tracing context."""
+    return jax.sharding.get_abstract_mesh()

@@ -62,6 +62,7 @@ from repro import compat
 from repro.core import sharded_embedding as se
 from repro.data.pipeline import PSORT_KEYS
 from repro.dist import exchange as exchange_cfg
+from repro.kernels import ops
 from repro.optim import data_parallel as dp
 from repro.optim import row as row_optim
 
@@ -208,7 +209,9 @@ def build_stages(mdef, mesh, layout) -> PipelineStages:
     ex_cfg = exchange_cfg.resolve_exchange(mdef)
     impl = ex_cfg.impl
     B = mdef.batch
-    fused = (jax.default_backend() == "tpu" if mdef.fused_update is None
+    # unset: the fused kernel wherever it runs compiled (a TPU), the
+    # reference where it would only be interpreted (the CPU)
+    fused = (not ops._default_interpret() if mdef.fused_update is None
              else mdef.fused_update)
     opt = row_optim.resolve(mdef)
 

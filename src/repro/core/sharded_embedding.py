@@ -424,7 +424,7 @@ def _row_sorted_streams(layout: ShardedEmbeddingLayout, g_flat: jax.Array,
     """Device-side sorted streams for the ROW-mode fused update, computed
     from the GLOBAL row ids: one axis-INVARIANT stable argsort of the
     global keys, then an elementwise localization into this shard's
-    window (subtract ``start``, mask, clip).  Three reasons this shape —
+    window (subtract ``start``, mask, clip).  Two reasons this shape —
     and not a per-shard sort of the axis-index-derived local rows:
 
     * the global sort is computed once and identically on every shard
@@ -433,12 +433,7 @@ def _row_sorted_streams(layout: ShardedEmbeddingLayout, g_flat: jax.Array,
     * per touched row the run holds the SAME lookups in the SAME stable
       flat order as the per-shard local sort (shifting all keys by
       ``start`` permutes nothing within the owned window), so the kernel
-      output is bit-identical to the host-pre-sorted stream;
-    * XLA CPU (jax<0.5) miscompiles the interpret-mode kernel under
-      jit+shard_map when its scalar-prefetch operands descend from an
-      axis_index-dependent argsort — the invariant sort + elementwise
-      localization is the formulation it compiles correctly (verified
-      against the pure-jnp oracle; see tests/test_row_optim.py).
+      output is bit-identical to the host-pre-sorted stream.
 
     Non-owned lookups keep ``msk == 0`` and clip to row 0 / R-1 — exact
     no-op rewrites (stateless kinds) or flag-guarded write-throughs
@@ -526,18 +521,6 @@ def apply_update(layout: ShardedEmbeddingLayout, store: dict, optimizer,
         return optimizer.apply_sparse(store, SparseStream(presort=streams,
                                                           dY=dY), lr,
                                       seed=seed)
-    if fused and layout.mode == "table" and layout.num_shards > 1 \
-            and jax.default_backend() != "tpu":
-        # KNOWN LIMITATION: XLA CPU (jax<0.5) miscompiles the
-        # interpret-mode kernel under jit+shard_map when the sorted
-        # streams descend from the axis-varying padded-slot offsets, and
-        # table mode has no axis-invariant sort formulation (each shard
-        # sorts genuinely different slot content).  Fall back to the
-        # reference math here — identical semantics (the split path is
-        # bit-identical to the kernel by contract); the multi-shard
-        # table KERNEL path is exercised via host_presort, and on TPU
-        # (compiled, non-interpret) the direct path stays on.
-        fused = False
     local, valid = _local_rows(layout, idx_local, axis_name)
     B, S, P = local.shape
     E = dY.shape[-1]
@@ -559,13 +542,14 @@ def apply_update(layout: ShardedEmbeddingLayout, store: dict, optimizer,
 
     if n == 1:
         return chunk_update(store, local, valid, dY, weights)
-    if optimizer.state_keys:
-        # stateful reference, chunked: the optimizer transition (momentum
-        # decay, Adagrad accumulate) must run ONCE per touched row per
-        # step — re-running it per chunk compounds the decay beta^n-style
-        # and squares partial sums.  Two phases: scatter-accumulate the
-        # per-row gradient across chunks (the [cb,S,P,E] expansion stays
-        # chunk-bounded), then one reduced transition on the unique rows.
+    if optimizer.flat_reference is None:
+        # chunked reference: the row step must run ONCE per touched row
+        # per step, as in the kernel — re-running it per chunk compounds
+        # the momentum decay, squares Adagrad partial sums and rounds a
+        # split_sgd row once per chunk.  Two phases: scatter-accumulate
+        # the per-row gradient across chunks in flat order (the
+        # [cb,S,P,E] expansion stays chunk-bounded), then one reduced
+        # transition on the unique rows.
         rows = optimizer.fwd_weights(store).shape[0]
 
         def acc_chunk(dW, inp):

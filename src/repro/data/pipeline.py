@@ -36,6 +36,7 @@ from typing import Callable, Iterable, Iterator, Optional
 import numpy as np
 
 from repro import telemetry
+from repro.data.synthetic import to_padded_slots
 
 PSORT_KEYS = ("psort_rows", "psort_bags", "psort_msk", "psort_wgt")
 
@@ -81,13 +82,9 @@ def presort_batch(layout, idx: np.ndarray,
         # original slots -> padded (bin-major) order, dummy slots read
         # index 0 (the scratch row) with weight 0 — exactly the
         # permute_indices + zeroed-weights stream the exchange ships
-        src = np.where(layout.padded_slots >= 0, layout.padded_slots, 0)
-        dummy = layout.padded_slots < 0
-        padded = np.asarray(idx, np.int32)[:, src, :]
-        padded[:, dummy, :] = 0
+        padded = to_padded_slots(layout, np.asarray(idx, np.int32))
         if weights is not None:
-            wp = np.asarray(weights, np.float32)[:, src, :]
-            wp[:, dummy, :] = 0.0
+            wp = to_padded_slots(layout, np.asarray(weights, np.float32))
         K = layout.slots_per_shard
         off = np.asarray(layout.slot_local_offsets,
                          np.int32).reshape(ns, K)

@@ -67,14 +67,33 @@ def sparse_batch(rng: np.random.Generator, spec: SparseBatchSpec) -> dict:
     return batch
 
 
-def dlrm_stream(seed: int, cfg, alpha: float = 0.0) -> Iterator[dict]:
-    """Batches for repro.core.dlrm.DLRMConfig (row mode slot order)."""
+def to_padded_slots(layout, idx: np.ndarray) -> np.ndarray:
+    """[B, S, P] per-slot values in original slot order (indices, or bag
+    weights) -> the [B, num_padded_slots, P] padded (bin-major) order a
+    table-mode step reads from a replicated index stream; dummy slots
+    hold 0, so they read row 0 with weight 0 (host twin of
+    ``sharded_embedding.permute_indices``)."""
+    src = np.where(layout.padded_slots >= 0, layout.padded_slots, 0)
+    out = idx[:, src]
+    out[:, layout.padded_slots < 0] = 0
+    return out
+
+
+def dlrm_stream(seed: int, cfg, alpha: float = 0.0,
+                layout=None) -> Iterator[dict]:
+    """Batches for repro.core.dlrm.DLRMConfig: original slot order, or
+    the padded slot order when ``layout`` is a table-mode layout and the
+    config reads a replicated index stream."""
     rng = np.random.default_rng(seed)
     spec = SparseBatchSpec(cfg.table_rows, None, cfg.pooling, cfg.batch,
                            num_dense=cfg.num_dense, alpha=alpha)
+    padded = (layout is not None and layout.mode == "table"
+              and cfg.idx_input == "replicated")
     while True:
         b = sparse_batch(rng, spec)
         b["dense_x"] = b["dense_x"].astype(np.float32)
+        if padded:
+            b["idx"] = to_padded_slots(layout, b["idx"])
         yield b
 
 

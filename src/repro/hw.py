@@ -1,7 +1,7 @@
 """Target-hardware constants (TPU v5e) used by the roofline analysis.
 
-The container runs on CPU; these describe the TARGET platform that the
-dry-run artifacts are analyzed against (see EXPERIMENTS.md section Roofline).
+These describe the TARGET platform that the dry-run artifacts are
+analyzed against (``benchmarks/roofline.py``).
 """
 
 from dataclasses import dataclass

@@ -1,4 +1,4 @@
-"""Pallas TPU kernel: fused sparse embedding backward + Split-SGD row update
+"""Pallas TPU kernel: fused sparse embedding backward + row-optimizer update
 (paper Alg. 3 + contribution C5 composed — the operator behind the headline
 110x).
 
@@ -10,491 +10,277 @@ flat lookups:
 
 * XLA side (cheap, O(L) on int32): sort the flat local row ids, so duplicate
   rows form contiguous runs and each touched row is visited exactly once.
-* The sorted row ids are scalar-prefetched and drive the (hi, lo) row DMA —
-  a new row block is fetched only when the run changes.
+* The sorted row ids are scalar-prefetched and drive the slab DMA.  The
+  unit of HBM traffic is the ROW GROUP: the ``G`` consecutive rows of one
+  native TPU tile (8 rows for 32-bit slabs, 16 when any slab of the store
+  is 16-bit — bf16 ``hi``, uint16 ``lo``, bf16 state).  A single row of a
+  16-bit slab is not a legal DMA or block on the chip (the tiling packs
+  row pairs into one 32-bit sublane), so the kernel blocks whole groups:
+  a group is fetched when the sorted stream enters it, every run inside it
+  updates its row in VMEM, and the group is written back once when the
+  stream leaves it.  Consecutive runs in one group see each other's
+  writes because the output block stays resident while its index repeats.
 * Inside the kernel the duplicate contributions are accumulated in a VMEM
-  fp32 scratch (segment accumulation), then at the run end the row is
-  reconstructed ``(hi<<16)|lo``, stepped ``w -= lr * acc``, and re-split —
-  all in VMEM.
-* ``input_output_aliases`` makes the update in-place on the HBM table, so
-  rows NOT touched by the minibatch are never read, never written, and no
+  fp32 scratch (segment accumulation); at the run end the optimizer's row
+  step (the RowOptimizer ``step`` hook) runs elementwise on the group
+  block and a row mask keeps only the run's row.
+* ``input_output_aliases`` makes the update in-place on the HBM table:
+  groups holding no touched row are never read or written, the untouched
+  rows of a touched group are written back with their own bits, and no
   dense ``dW`` or fp32 shard copy ever exists.
 
-Bytes per step (shard of M rows x E, L flat lookups, U unique touched rows,
-NB = L / pooling bags):
+Bytes per step (shard of M rows x E, L flat lookups, T touched groups of
+G rows, NB = L / pooling bags, ``s`` bytes per row over all slabs):
 
     path                         reads                       writes
     ------------------------------------------------------------------
-    reference (segment_sum +     L*E*4 (grad expand)         M*E*4 (new hi+lo
-    combine_split + functional   + U*E*4 (gather hi,lo)       shard copies)
-    scatter)                     + M*E*4 (scatter copy-in)
-    fused (this kernel)          U*E*4 (hi+lo rows)          U*E*4 (hi+lo rows)
-                                 + NB*E*4 (dY)
+    reference (segment_sum +     L*E*4 (grad expand)         M*s (new slab
+    functional scatter)          + M*s (scatter copy-in)      copies)
+    fused (this kernel)          T*G*s + L*8*E*4 (dY group   T*G*s
+                                 per lookup)
 
-i.e. the fused path touches ``O(U)`` row data instead of ``O(M)`` shard data
-— the bandwidth profile Hsia et al. (2020) identify as the dominant memory
-bottleneck of DLRM-class training.
+i.e. the fused path touches ``O(touched groups)`` slab data instead of
+``O(M)`` — the bandwidth profile Hsia et al. (2020) identify as the
+dominant memory bottleneck of DLRM-class training.
+
+The sorted stream lives in SMEM (scalar prefetch), which holds a few
+hundred KiB, so a step's stream is cut into chunks of at most
+``CHUNK`` lookups, one kernel call each.  A run that crosses a chunk
+boundary hands its partial fp32 sum and liveness flag to the next call,
+which continues the SAME sequential accumulation — the result does not
+depend on where the cuts fall.
 
 Numerics: duplicate contributions are pre-reduced in fp32 in sorted order —
 the same order ``jax.ops.segment_sum`` uses on sorted segments — and the
-step is applied once per row, so the result is bit-identical to the
+step is applied once per row, so the split result is bit-identical to the
 ``dedup_rows`` + ``combine_split`` reference path
-(:func:`repro.optim.row.apply_rows_split_sgd`).
-
-Stateful row optimizers (momentum / Adagrad; :mod:`repro.optim.row`) ride
-the SAME machinery with one extra row-addressed operand: the per-row
-optimizer-state slab (a momentum row, an elementwise accumulator row, or a
-per-row scalar lane) is DMA'd by the same ``rows[i]`` index map as the
-weight row, updated once at the run end, and written back through its own
-``input_output_aliases`` entry — state traffic stays O(touched rows) per
-step, exactly like the weights.  A run consisting ONLY of masked padding
-lookups (the sorted tail) must not touch state (``beta * m`` is not a
-no-op the way ``w - lr * 0`` is), so the stateful kernels carry a 1-word
-SMEM liveness flag per run and write the operand back unchanged when no
-valid lookup contributed.
+(:func:`repro.optim.row.apply_rows_split_sgd`).  A run made ONLY of masked
+padding lookups (the sorted tail, other shards' rows) writes nothing:
+``beta * m`` is not a no-op the way ``w - lr * 0`` is, so every run carries
+a 1-word SMEM liveness flag and the row step runs only on live runs.
 """
 
 from __future__ import annotations
 
-import functools
-
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.layout import Layout
 from jax.experimental.pallas import tpu as pltpu
 
-# plain lax bit ops — trace fine inside the kernel body, and sharing the
-# exact expressions with the optimizer is what makes the bit-identity claim
-# structural rather than coincidental
-from repro.optim.split_sgd import combine_split, split_fp32
-from repro.optim.stochastic import sr_noise, sr_round_bf16
+# lookups per kernel call: rows/bags/msk/wgt are 4 x 4 B each per lookup
+# in SMEM, so 16384 lookups take 256 KiB of it
+CHUNK = 16384
+LANES = 128
 
 
-def _run_bounds(rows_ref, i):
-    """(is_start, is_end) of the sorted duplicate run at position ``i``."""
-    L = pl.num_programs(0)
-    row = rows_ref[i]
-    prev = rows_ref[jnp.maximum(i - 1, 0)]
-    nxt = rows_ref[jnp.minimum(i + 1, L - 1)]
-    return (i == 0) | (row != prev), (i == L - 1) | (nxt != row)
+def tile_rows(dtype) -> int:
+    """Rows of one native TPU tile of ``dtype``: 8 for 32-bit, 16 for
+    16-bit (two rows share a 32-bit sublane)."""
+    return 8 * max(1, 4 // jnp.dtype(dtype).itemsize)
 
 
-def _kernel_split(rows_ref, bags_ref, msk_ref, lr_ref, wgt_ref, hi_ref,
-                  lo_ref, dY_ref, nhi_ref, nlo_ref, acc_ref):
-    i = pl.program_id(0)
-    is_start, is_end = _run_bounds(rows_ref, i)
-
-    @pl.when(is_start)
-    def _init():
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-
-    # masked accumulate: padding / invalid (non-owned) lookups add exact 0.0.
-    # Weighted bags scale each lookup's cotangent row BEFORE the VMEM
-    # pre-reduction.  The compiler contracts the scale into the accumulate
-    # (FMA — observed on the XLA CPU backend even through barriers/bitcasts,
-    # and what Mosaic emits on TPU), so the WEIGHTED result sits within
-    # 1 ulp/step of the pre-scaled segment_sum reference rather than
-    # bitwise on it; weight == 1.0 multiplies exactly, so the unweighted
-    # path keeps its bit-identity contract.
-    g = dY_ref[...].astype(jnp.float32) * wgt_ref[i]
-    acc_ref[...] += jnp.where(msk_ref[i] != 0, g, 0.0)
-
-    @pl.when(is_end)
-    def _apply():
-        # same expression as the combine_split reference: XLA contracts the
-        # mul+sub identically under jit, so the update is bit-identical to
-        # the jitted segment_sum + combine_split path
-        w32 = combine_split(hi_ref[...], lo_ref[...])
-        w32 = w32 - lr_ref[0] * acc_ref[...]
-        nh, nl = split_fp32(w32)
-        nhi_ref[...] = nh
-        nlo_ref[...] = nl
+def _round_up(x: int, m: int) -> int:
+    return -(-x // m) * m
 
 
-def _kernel_fp32(rows_ref, bags_ref, msk_ref, lr_ref, wgt_ref, w_ref,
-                 dY_ref, nw_ref, acc_ref):
-    i = pl.program_id(0)
-    is_start, is_end = _run_bounds(rows_ref, i)
+def rows_on_lanes(shape, dtype, device=None) -> bool:
+    """Whether an [M, W] slab is stored transposed on a TPU — column-major,
+    rows along the 128 lanes, W along sublanes.  The kernel reads a slab
+    in the orientation it is stored in: the transposed view of a
+    column-major slab is a bitcast, where a row-major operand would cost
+    a relayout copy of the whole slab on every call.
 
-    @pl.when(is_start)
-    def _init():
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-
-    g = dY_ref[...].astype(jnp.float32) * wgt_ref[i]
-    acc_ref[...] += jnp.where(msk_ref[i] != 0, g, 0.0)
-
-    @pl.when(is_end)
-    def _apply():
-        w32 = w_ref[...].astype(jnp.float32) - lr_ref[0] * acc_ref[...]
-        nw_ref[...] = w32.astype(nw_ref.dtype)
-
-
-def _accumulate_run(rows_ref, msk_ref, wgt_ref, dY_ref, acc_ref, flg_ref,
-                    i):
-    """Shared preamble of the stateful kernels: zero the VMEM accumulator
-    and the SMEM liveness flag at a run start, masked-accumulate this
-    lookup's weighted cotangent row, and OR its validity into the flag.
-    Returns (is_end, run-liveness-so-far is in ``flg_ref``)."""
-    is_start, is_end = _run_bounds(rows_ref, i)
-
-    @pl.when(is_start)
-    def _init():
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-        flg_ref[0] = 0
-
-    g = dY_ref[...].astype(jnp.float32) * wgt_ref[i]
-    acc_ref[...] += jnp.where(msk_ref[i] != 0, g, 0.0)
-    flg_ref[0] = flg_ref[0] | msk_ref[i]
-    return is_end
+    On a TPU (``device``, else the default device when the backend is a
+    TPU) the answer is the compiler's default layout for the shape, the
+    layout a jitted step's parameters take.  Elsewhere (interpret mode,
+    which runs the chip's blocks) it is modelled: XLA:TPU picks the
+    layout that pads less, so a narrow slab (W = 64, or a [M, 1]
+    per-row scalar) is column-major and a W % 128 == 0 slab row-major.
+    The model matches the compiler at every shape tested above 16 rows
+    (tests/test_tpu_compile.py); below that the compiler uses smaller
+    tiles and may disagree."""
+    if device is None and jax.default_backend() == "tpu":
+        device = jax.devices()[0]
+    if device is not None:
+        layout = Layout.from_pjrt_layout(device.client.get_default_layout(
+            jnp.dtype(dtype), tuple(shape), device))
+        return layout.major_to_minor == (1, 0)
+    M, W = shape
+    sub = tile_rows(dtype)
+    return (_round_up(M, sub) * _round_up(W, LANES)
+            > _round_up(W, sub) * _round_up(M, LANES))
 
 
-def _kernel_momentum(rows_ref, bags_ref, msk_ref, hp_ref, wgt_ref, w_ref,
-                     m_ref, dY_ref, nw_ref, nm_ref, acc_ref, flg_ref):
-    """fp32 weights + fp32 momentum row.  hp = [lr, beta, eps]."""
-    i = pl.program_id(0)
-    is_end = _accumulate_run(rows_ref, msk_ref, wgt_ref, dY_ref, acc_ref,
-                             flg_ref, i)
+def _make_kernel(step, cols: tuple, G: int, Gd: int):
+    """The fused kernel body for row-aligned slabs (all read and written
+    back; ``cols[k]`` says slab ``k`` arrives transposed, as [W, M]).
+    ``step(blocks, g, lr, seed, rows) -> blocks`` is the optimizer's row
+    math on [G, W] group blocks (``g`` [1, E] is the run's pre-reduced
+    gradient, ``rows`` [G, 1] the group's row ids).
 
-    @pl.when(is_end)
-    def _apply():
-        live = flg_ref[0] != 0
-        m_old = m_ref[...].astype(jnp.float32)
-        m_new = hp_ref[1] * m_old + acc_ref[...]
-        w_old = w_ref[...].astype(jnp.float32)
-        w_new = w_old - hp_ref[0] * m_new
-        nm_ref[...] = jnp.where(live, m_new, m_old).astype(nm_ref.dtype)
-        nw_ref[...] = jnp.where(live, w_new, w_old).astype(nw_ref.dtype)
+    Scalar prefetch: sorted rows / bags / msk / wgt of this chunk, ``lr``
+    [1] fp32, ``sd`` [1] int32 seed, ``ctl`` [3] int32 = (last row of the
+    previous chunk or -1, first row of the next chunk or -1, liveness of
+    the run carried in)."""
+    n_slabs = len(cols)
 
-
-def _kernel_adagrad(rows_ref, bags_ref, msk_ref, hp_ref, wgt_ref, w_ref,
-                    s_ref, dY_ref, nw_ref, ns_ref, acc_ref, flg_ref):
-    """fp32 weights + fp32 elementwise accumulator row.  hp = [lr, beta,
-    eps]; ``s += g^2``, ``w -= lr * g / (sqrt(s) + eps)`` per touched row
-    on the pre-reduced gradient."""
-    i = pl.program_id(0)
-    is_end = _accumulate_run(rows_ref, msk_ref, wgt_ref, dY_ref, acc_ref,
-                             flg_ref, i)
-
-    @pl.when(is_end)
-    def _apply():
-        live = flg_ref[0] != 0
-        acc = acc_ref[...]
-        s_old = s_ref[...].astype(jnp.float32)
-        s_new = s_old + acc * acc
-        w_old = w_ref[...].astype(jnp.float32)
-        w_new = w_old - hp_ref[0] * acc / (jnp.sqrt(s_new) + hp_ref[2])
-        ns_ref[...] = jnp.where(live, s_new, s_old).astype(ns_ref.dtype)
-        nw_ref[...] = jnp.where(live, w_new, w_old).astype(nw_ref.dtype)
-
-
-def _make_kernel_adagrad_rowwise(e_real: int):
-    """Row-wise Adagrad (Naumov et al. 2019): ONE accumulator scalar per
-    row — ``s += mean_e(g^2)``, ``w -= lr * g / (sqrt(s) + eps)``.  The
-    state operand is a (1, Ws) lane block whose lanes all carry the same
-    scalar (lane 0 is authoritative); ``e_real`` is the unpadded embedding
-    width so the mean ignores lane padding (padded dY lanes are zero)."""
-
-    def kernel(rows_ref, bags_ref, msk_ref, hp_ref, wgt_ref, w_ref, s_ref,
-               dY_ref, nw_ref, ns_ref, acc_ref, flg_ref):
+    def kernel(rows_ref, bags_ref, msk_ref, wgt_ref, lr_ref, sd_ref, ctl_ref,
+               *refs):
+        slabs = refs[:n_slabs]
+        dY_ref, cacc_ref = refs[n_slabs:n_slabs + 2]
+        outs = refs[n_slabs + 2:2 * n_slabs + 2]
+        oacc_ref, oflg_ref, acc_ref, flg_ref = refs[2 * n_slabs + 2:]
         i = pl.program_id(0)
-        is_end = _accumulate_run(rows_ref, msk_ref, wgt_ref, dY_ref,
-                                 acc_ref, flg_ref, i)
+        n = pl.num_programs(0)
+        row = rows_ref[i]
+        prev = jnp.where(i == 0, ctl_ref[0], rows_ref[jnp.maximum(i - 1, 0)])
+        nxt = jnp.where(i == n - 1, ctl_ref[1],
+                        rows_ref[jnp.minimum(i + 1, n - 1)])
 
-        @pl.when(is_end)
+        # entering a group (or a new call): the output block is a fresh
+        # VMEM buffer, so seed it with the group's current HBM contents
+        @pl.when((i == 0) | (row // G != prev // G))
+        def _load_group():
+            for o, s in zip(outs, slabs):
+                o[...] = s[...]
+
+        @pl.when(row != prev)
+        def _start_run():
+            acc_ref[...] = jnp.zeros_like(acc_ref)
+            flg_ref[0] = 0
+
+        @pl.when((i == 0) & (row == prev))
+        def _continue_run():
+            acc_ref[...] = cacc_ref[...]
+            flg_ref[0] = ctl_ref[2]
+
+        # this lookup's cotangent row out of its bag's tile: a masked
+        # sublane sum is exact (one nonzero term; a -0.0 turning +0.0
+        # cannot change an accumulation that starts at +0.0)
+        pick = (jax.lax.broadcasted_iota(jnp.int32, dY_ref.shape, 0)
+                == bags_ref[i] % Gd)
+        g = jnp.sum(jnp.where(pick, dY_ref[...].astype(jnp.float32), 0.0),
+                    axis=0, keepdims=True)
+        acc_ref[...] += jnp.where(msk_ref[i] != 0, g * wgt_ref[i], 0.0)
+        flg_ref[0] = flg_ref[0] | msk_ref[i]
+
+        @pl.when((nxt != row) & (flg_ref[0] != 0))
         def _apply():
-            live = flg_ref[0] != 0
-            acc = acc_ref[...]
-            s_old = s_ref[0, 0]
-            s_new = s_old + jnp.sum(acc * acc) / e_real
-            w_old = w_ref[...].astype(jnp.float32)
-            w_new = w_old - hp_ref[0] * acc / (jnp.sqrt(s_new) + hp_ref[2])
-            s_out = jnp.where(live, s_new, s_old)
-            ns_ref[...] = jnp.broadcast_to(s_out, ns_ref.shape
-                                           ).astype(ns_ref.dtype)
-            nw_ref[...] = jnp.where(live, w_new, w_old).astype(nw_ref.dtype)
+            rows = (row // G) * G + jax.lax.broadcasted_iota(
+                jnp.int32, (G, 1), 0)
+            cur = tuple(o[...].T if c else o[...] for o, c in zip(outs, cols))
+            new = step(cur, acc_ref[...], lr_ref[0], sd_ref[0], rows)
+            hit = rows == row
+            for o, c, x, v in zip(outs, cols, cur, new):
+                v = jnp.where(hit, v.astype(o.dtype), x)
+                o[...] = v.T if c else v
+
+        # the last lookup of the call hands a continuing run to the next
+        @pl.when(i == n - 1)
+        def _carry_out():
+            oacc_ref[...] = acc_ref[...]
+            oflg_ref[0] = flg_ref[0]
 
     return kernel
 
 
-def _kernel_freq(rows_ref, bags_ref, msk_ref, hp_ref, wgt_ref, w_ref,
-                 s_ref, dY_ref, nw_ref, nc_ref, acc_ref, flg_ref):
-    """Frequency-adaptive sparse LR (``adagrad_freq``): fp32 weights + the
-    reserved int32 touch-counter lane.  hp = [lr, 0, eps].  The counter is
-    ALREADY bumped by ``RowOptimizer.apply_sparse`` before the kernel runs
-    (+1 per valid lookup, O(touched rows)), so the kernel only READS it —
-    ``w -= lr * g / (sqrt(max(cnt, 1)) + eps)`` per touched row — and
-    passes the slab through unchanged (lane 0 authoritative; ops.py pads
-    the [M, 1] slab to the lane width on the compiled path)."""
-    i = pl.program_id(0)
-    is_end = _accumulate_run(rows_ref, msk_ref, wgt_ref, dY_ref, acc_ref,
-                             flg_ref, i)
-    nc_ref[...] = s_ref[...]
-
-    @pl.when(is_end)
-    def _apply():
-        live = flg_ref[0] != 0
-        c = s_ref[0, 0].astype(jnp.float32)
-        denom = jnp.sqrt(jnp.maximum(c, 1.0)) + hp_ref[2]
-        w_old = w_ref[...].astype(jnp.float32)
-        w_new = w_old - hp_ref[0] * acc_ref[...] / denom
-        nw_ref[...] = jnp.where(live, w_new, w_old).astype(nw_ref.dtype)
-
-
-def _kernel_momentum_bf16(rows_ref, bags_ref, msk_ref, hp_ref, sd_ref,
-                          wgt_ref, w_ref, m_ref, dY_ref, nw_ref, nm_ref,
-                          acc_ref, flg_ref):
-    """fp32 weights + COMPRESSED bf16-hi momentum row with seeded
-    stochastic rounding.  hp = [lr, beta, eps]; sd = [seed].  The bf16 ->
-    fp32 decode is exact, the transition runs in fp32, and only the store
-    back to the state slab rounds — with the counter-based dither of
-    :mod:`repro.optim.stochastic`, so the reference scan computes the
-    same bits for the same (seed, row, lane)."""
-    i = pl.program_id(0)
-    is_end = _accumulate_run(rows_ref, msk_ref, wgt_ref, dY_ref, acc_ref,
-                             flg_ref, i)
-
-    @pl.when(is_end)
-    def _apply():
-        live = flg_ref[0] != 0
-        m_old = m_ref[...]
-        m_new = hp_ref[1] * m_old.astype(jnp.float32) + acc_ref[...]
-        w_old = w_ref[...].astype(jnp.float32)
-        w_new = w_old - hp_ref[0] * m_new
-        noise = sr_noise(sd_ref[0], rows_ref[i][None], m_new.shape[-1])
-        nm_ref[...] = jnp.where(live, sr_round_bf16(m_new, noise), m_old)
-        nw_ref[...] = jnp.where(live, w_new, w_old).astype(nw_ref.dtype)
-
-
-def _kernel_adagrad_bf16(rows_ref, bags_ref, msk_ref, hp_ref, sd_ref,
-                         wgt_ref, w_ref, s_ref, dY_ref, nw_ref, ns_ref,
-                         acc_ref, flg_ref):
-    """fp32 weights + COMPRESSED bf16-hi elementwise Adagrad accumulator
-    with seeded stochastic rounding.  The weight step uses the UNROUNDED
-    fp32 ``s_new`` (rounding only affects what the next step decodes)."""
-    i = pl.program_id(0)
-    is_end = _accumulate_run(rows_ref, msk_ref, wgt_ref, dY_ref, acc_ref,
-                             flg_ref, i)
-
-    @pl.when(is_end)
-    def _apply():
-        live = flg_ref[0] != 0
-        acc = acc_ref[...]
-        s_old = s_ref[...]
-        s_new = s_old.astype(jnp.float32) + acc * acc
-        w_old = w_ref[...].astype(jnp.float32)
-        w_new = w_old - hp_ref[0] * acc / (jnp.sqrt(s_new) + hp_ref[2])
-        noise = sr_noise(sd_ref[0], rows_ref[i][None], s_new.shape[-1])
-        ns_ref[...] = jnp.where(live, sr_round_bf16(s_new, noise), s_old)
-        nw_ref[...] = jnp.where(live, w_new, w_old).astype(nw_ref.dtype)
-
-
-def _row_specs(E, n_out):
-    """(in_specs tail, out_specs) for the row-addressed operands.  The
-    scalar-prefetch refs (rows, bags, msk, then the kernel's scalar
-    operands — hyperparameters, optional SR seed, weights; SMEM is the
-    TPU-legal home for kernel scalars) are appended to every index_map;
-    the maps are variadic in everything after (rows, bags) so one spec
-    serves any scalar-prefetch arity."""
-    row = pl.BlockSpec((1, E), lambda i, rows, bags, *_: (rows[i], 0))
-    bag = pl.BlockSpec((1, E), lambda i, rows, bags, *_: (bags[i], 0))
-    return row, bag, [row] * n_out
-
-
-def fused_update_split_pallas(hi: jax.Array, lo: jax.Array,
-                              sorted_rows: jax.Array, sorted_bags: jax.Array,
-                              sorted_msk: jax.Array, sorted_wgt: jax.Array,
-                              dY: jax.Array, lr, interpret: bool = False
-                              ) -> tuple[jax.Array, jax.Array]:
-    """Fused sparse-backward + Split-SGD-BF16 update, in place on (hi, lo).
-
-    ``hi`` [M, E] bf16 / ``lo`` [M, E] uint16: the split table shard.
-    ``sorted_rows`` [L] int32: ASCENDING local row id per flat lookup
-    (duplicates contiguous; padding entries must repeat an in-range row and
-    carry ``sorted_msk == 0``).  ``sorted_bags`` [L] int32: row of ``dY``
-    holding each lookup's cotangent.  ``sorted_wgt`` [L] fp32: per-lookup
-    bag weight (1.0 for plain sum bags) scaling the cotangent row before
-    the VMEM pre-reduction.  ``dY`` [NB, E].  Returns the updated (hi, lo);
-    rows not named in ``sorted_rows`` are untouched (aliased buffers, no
-    shard copy).  E must be lane-aligned on the TPU target (ops.py pads).
-    """
-    M, E = hi.shape
-    L = sorted_rows.shape[0]
-    row, bag, outs = _row_specs(E, 2)
-    lr_arr = jnp.full((1,), lr, jnp.float32)
-    return pl.pallas_call(
-        _kernel_split,
+def _call(step, cols, slabs, rows, bags, msk, wgt, dY, lr, seed, ctl, cacc,
+          interpret):
+    """One kernel call on one chunk of the sorted stream (slabs already in
+    their stored orientation)."""
+    n = len(slabs)
+    # one group of rows for every slab: a lane tile when any slab holds
+    # its rows on lanes, else the tallest sublane tile
+    G = LANES if any(cols) else max(tile_rows(s.dtype) for s in slabs)
+    Gd = tile_rows(dY.dtype)
+    E = dY.shape[1]
+    slab_specs = [
+        pl.BlockSpec((s.shape[0], G), lambda i, rows, *_: (0, rows[i] // G))
+        if c else
+        pl.BlockSpec((G, s.shape[1]), lambda i, rows, *_: (rows[i] // G, 0))
+        for s, c in zip(slabs, cols)]
+    whole = pl.BlockSpec((1, E), lambda i, *_: (0, 0))
+    in_specs = slab_specs + [
+        pl.BlockSpec((Gd, E), lambda i, rows, bags, *_: (bags[i] // Gd, 0)),
+        whole]
+    out_specs = slab_specs + [whole, pl.BlockSpec(memory_space=pltpu.SMEM)]
+    out = pl.pallas_call(
+        _make_kernel(step, cols, G, Gd),
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=5,
-            grid=(L,),
-            in_specs=[row, row, bag],
-            out_specs=outs,
-            scratch_shapes=[pltpu.VMEM((1, E), jnp.float32)],
-        ),
-        out_shape=[jax.ShapeDtypeStruct((M, E), jnp.bfloat16),
-                   jax.ShapeDtypeStruct((M, E), jnp.uint16)],
-        # args: (rows, bags, msk, lr, wgt, hi, lo, dY) -> alias hi/lo->outs
-        input_output_aliases={5: 0, 6: 1},
-        interpret=interpret,
-    )(sorted_rows, sorted_bags, sorted_msk, lr_arr, sorted_wgt, hi, lo, dY)
-
-
-def fused_update_fp32_pallas(W: jax.Array, sorted_rows: jax.Array,
-                             sorted_bags: jax.Array, sorted_msk: jax.Array,
-                             sorted_wgt: jax.Array, dY: jax.Array, lr,
-                             interpret: bool = False) -> jax.Array:
-    """fp32/bf16-storage variant of :func:`fused_update_split_pallas`:
-    ``W[r] -= lr * sum(wgt * dY[bags of r])`` on the touched rows only."""
-    M, E = W.shape
-    L = sorted_rows.shape[0]
-    row, bag, outs = _row_specs(E, 1)
-    lr_arr = jnp.full((1,), lr, jnp.float32)
-    return pl.pallas_call(
-        _kernel_fp32,
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=5,
-            grid=(L,),
-            in_specs=[row, bag],
-            out_specs=outs,
-            scratch_shapes=[pltpu.VMEM((1, E), jnp.float32)],
-        ),
-        out_shape=[jax.ShapeDtypeStruct((M, E), W.dtype)],
-        # args: (rows, bags, msk, lr, wgt, W, dY) -> alias W->out0
-        input_output_aliases={5: 0},
-        interpret=interpret,
-    )(sorted_rows, sorted_bags, sorted_msk, lr_arr, sorted_wgt, W, dY)[0]
-
-
-def _state_spec(Ws):
-    """Row-addressed (1, Ws) BlockSpec for a per-row optimizer-state slab —
-    the same ``rows[i]`` index map as the weight row, at the slab's own
-    width (E for momentum / elementwise Adagrad, the padded scalar lane
-    for row-wise Adagrad)."""
-    return pl.BlockSpec((1, Ws), lambda i, rows, bags, *_: (rows[i], 0))
-
-
-def _stateful_call(kernel, w: jax.Array, s: jax.Array, sorted_rows,
-                   sorted_bags, sorted_msk, sorted_wgt, dY, hp,
-                   interpret: bool, extra_scalars: tuple = ()):
-    """Shared pallas_call plumbing for the (weights, state) kernels:
-    scalar-prefetch stream + two row-addressed aliased operands + the VMEM
-    accumulator and the SMEM run-liveness flag.  ``extra_scalars``: extra
-    scalar-prefetch operands (e.g. the int32 stochastic-rounding seed),
-    handed to the kernel BETWEEN ``hp`` and ``wgt`` — the index maps are
-    variadic, so any arity rides the same specs."""
-    M, E = w.shape
-    Ws = s.shape[1]
-    L = sorted_rows.shape[0]
-    row, bag, _ = _row_specs(E, 0)
-    st = _state_spec(Ws)
-    n_sp = 5 + len(extra_scalars)
-    return pl.pallas_call(
-        kernel,
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=n_sp,
-            grid=(L,),
-            in_specs=[row, st, bag],
-            out_specs=[row, st],
+            num_scalar_prefetch=7,
+            grid=(rows.shape[0],),
+            in_specs=in_specs,
+            out_specs=out_specs,
             scratch_shapes=[pltpu.VMEM((1, E), jnp.float32),
                             pltpu.SMEM((1,), jnp.int32)],
         ),
-        out_shape=[jax.ShapeDtypeStruct((M, E), w.dtype),
-                   jax.ShapeDtypeStruct((M, Ws), s.dtype)],
-        # args: (rows, bags, msk, hp, *extra, wgt, w, s, dY); alias the
-        # row-addressed w/s operands onto the outputs
-        input_output_aliases={n_sp: 0, n_sp + 1: 1},
+        out_shape=([jax.ShapeDtypeStruct(s.shape, s.dtype) for s in slabs]
+                   + [jax.ShapeDtypeStruct((1, E), jnp.float32),
+                      jax.ShapeDtypeStruct((1,), jnp.int32)]),
+        # args: (rows, bags, msk, wgt, lr, sd, ctl, *slabs, dY, cacc):
+        # the slabs alias their outputs
+        input_output_aliases={7 + k: k for k in range(n)},
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),
         interpret=interpret,
-    )(sorted_rows, sorted_bags, sorted_msk, hp, *extra_scalars,
-      sorted_wgt, w, s, dY)
+    )(rows, bags, msk, wgt, lr, seed, ctl, *slabs, dY, cacc)
+    return tuple(out[:n]), out[n], out[n + 1]
 
 
-def fused_update_momentum_pallas(w: jax.Array, mom: jax.Array, sorted_rows,
-                                 sorted_bags, sorted_msk, sorted_wgt, dY,
-                                 lr, beta, interpret: bool = False
-                                 ) -> tuple[jax.Array, jax.Array]:
-    """Fused sparse-backward + heavy-ball momentum update, in place on
-    ``(w, mom)``: per touched row ``m = beta * m + sum(wgt * dY)``,
-    ``w -= lr * m``.  ``mom`` [M, E] fp32 rides the same sorted-index
-    scalar prefetch as the weight row; untouched rows' weights AND state
-    are never read or written."""
-    hp = jnp.stack([jnp.asarray(lr, jnp.float32),
-                    jnp.asarray(beta, jnp.float32),
-                    jnp.zeros((), jnp.float32)])
-    return _stateful_call(_kernel_momentum, w, mom, sorted_rows, sorted_bags,
-                          sorted_msk, sorted_wgt, dY, hp, interpret)
+def sparse_row_update_pallas(step, slabs, sorted_rows, sorted_bags,
+                             sorted_msk, sorted_wgt, dY, lr, seed=0,
+                             interpret: bool = False) -> tuple:
+    """Fused sparse-backward + row-optimizer update, in place on ``slabs``.
 
+    ``slabs``: the store's row-aligned [M, W] slabs (weights first, then
+    per-row state; any widths, any mix of 32- and 16-bit dtypes).
+    ``step(blocks, g, lr, seed, rows) -> blocks``: the optimizer's row
+    math on [G, W] group blocks — elementwise per row, so a row's result
+    does not depend on its neighbours.  ``sorted_rows`` [L] int32:
+    ASCENDING local row id per flat lookup (duplicates contiguous; padding
+    entries must repeat an in-range row and carry ``sorted_msk == 0``).
+    ``sorted_bags`` [L] int32: row of ``dY`` holding each lookup's
+    cotangent.  ``sorted_wgt`` [L] fp32: per-lookup bag weight (1.0 for
+    plain sum bags) scaling the cotangent row before the VMEM
+    pre-reduction.  ``dY`` [NB, E].  ``seed``: int32 stochastic-rounding
+    seed handed to ``step``.  Returns the updated slabs; groups holding
+    no touched row are untouched (aliased buffers, no shard copy).
 
-def fused_update_adagrad_pallas(w: jax.Array, acc: jax.Array, sorted_rows,
-                                sorted_bags, sorted_msk, sorted_wgt, dY,
-                                lr, eps, rowwise: bool, e_real: int,
-                                interpret: bool = False
-                                ) -> tuple[jax.Array, jax.Array]:
-    """Fused sparse-backward + Adagrad update, in place on ``(w, acc)``.
-
-    ``rowwise=False``: ``acc`` [M, E] elementwise second-moment sum.
-    ``rowwise=True``: ``acc`` [M, Ws] per-row scalar lane (every lane
-    carries the row's accumulator; lane 0 authoritative) and the squared
-    gradient is averaged over ``e_real`` embedding lanes before the
-    accumulate — O(M) state instead of O(M*E)."""
-    hp = jnp.stack([jnp.asarray(lr, jnp.float32),
-                    jnp.zeros((), jnp.float32),
-                    jnp.asarray(eps, jnp.float32)])
-    kernel = (_make_kernel_adagrad_rowwise(e_real) if rowwise
-              else _kernel_adagrad)
-    return _stateful_call(kernel, w, acc, sorted_rows, sorted_bags,
-                          sorted_msk, sorted_wgt, dY, hp, interpret)
-
-
-def fused_update_momentum_bf16_pallas(w: jax.Array, mom: jax.Array,
-                                      sorted_rows, sorted_bags, sorted_msk,
-                                      sorted_wgt, dY, lr, beta, seed,
-                                      interpret: bool = False
-                                      ) -> tuple[jax.Array, jax.Array]:
-    """:func:`fused_update_momentum_pallas` with the momentum slab stored
-    COMPRESSED as bf16-hi: per touched row ``m = beta * decode(m) +
-    sum(wgt * dY)`` in fp32, ``w -= lr * m``, and the new ``m`` is written
-    back stochastically rounded under ``seed`` — half the state bytes per
-    touched row, unbiased in expectation."""
-    hp = jnp.stack([jnp.asarray(lr, jnp.float32),
-                    jnp.asarray(beta, jnp.float32),
-                    jnp.zeros((), jnp.float32)])
+    Each slab is handed to the kernel in the orientation XLA:TPU stores
+    it in (:func:`rows_on_lanes`), on every backend, so interpret mode
+    runs the same blocks as the chip."""
+    cols = tuple(rows_on_lanes(s.shape, s.dtype) for s in slabs)
+    views = tuple(s.T if c else s for s, c in zip(slabs, cols))
+    L = sorted_rows.shape[0]
+    C = min(CHUNK, L)
+    nc = -(-L // C)
+    lr_arr = jnp.full((1,), lr, jnp.float32)
     sd = jnp.full((1,), seed, jnp.int32)
-    return _stateful_call(_kernel_momentum_bf16, w, mom, sorted_rows,
-                          sorted_bags, sorted_msk, sorted_wgt, dY, hp,
-                          interpret, extra_scalars=(sd,))
+    E = dY.shape[1]
+    # pad the stream to whole chunks with masked repeats of the last row
+    # (the sort's maximum, so the stream stays ascending)
+    pad = nc * C - L
+    rows = jnp.concatenate([sorted_rows,
+                            jnp.broadcast_to(sorted_rows[-1:], (pad,))])
+    bags = jnp.pad(sorted_bags, (0, pad))
+    msk = jnp.pad(sorted_msk, (0, pad))
+    wgt = jnp.pad(sorted_wgt, (0, pad))
 
+    def body(k, carry):
+        views, cacc, cflg = carry
+        take = lambda a: jax.lax.dynamic_slice_in_dim(a, k * C, C)  # noqa: E731
+        prev = jnp.where(k == 0, -1, rows[jnp.maximum(k * C - 1, 0)])
+        nxt = jnp.where(k == nc - 1, -1,
+                        rows[jnp.minimum((k + 1) * C, nc * C - 1)])
+        ctl = jnp.stack([prev, nxt, cflg[0]]).astype(jnp.int32)
+        return _call(step, cols, views, take(rows), take(bags), take(msk),
+                     take(wgt), dY, lr_arr, sd, ctl, cacc, interpret)
 
-def fused_update_adagrad_bf16_pallas(w: jax.Array, acc: jax.Array,
-                                     sorted_rows, sorted_bags, sorted_msk,
-                                     sorted_wgt, dY, lr, eps, seed,
-                                     interpret: bool = False
-                                     ) -> tuple[jax.Array, jax.Array]:
-    """Elementwise Adagrad with the accumulator slab stored COMPRESSED as
-    bf16-hi + stochastic rounding (seeded).  The weight step divides by
-    ``sqrt`` of the UNROUNDED fp32 accumulator."""
-    hp = jnp.stack([jnp.asarray(lr, jnp.float32),
-                    jnp.zeros((), jnp.float32),
-                    jnp.asarray(eps, jnp.float32)])
-    sd = jnp.full((1,), seed, jnp.int32)
-    return _stateful_call(_kernel_adagrad_bf16, w, acc, sorted_rows,
-                          sorted_bags, sorted_msk, sorted_wgt, dY, hp,
-                          interpret, extra_scalars=(sd,))
-
-
-def fused_update_freq_pallas(w: jax.Array, cnt: jax.Array, sorted_rows,
-                             sorted_bags, sorted_msk, sorted_wgt, dY, lr,
-                             eps, interpret: bool = False
-                             ) -> tuple[jax.Array, jax.Array]:
-    """Fused sparse-backward + frequency-adaptive LR update, in place on
-    ``(w, cnt)``: per touched row ``w -= lr * sum(wgt * dY) /
-    (sqrt(max(cnt, 1)) + eps)`` where ``cnt`` [M, Ws] int32 is the
-    reserved touch-counter slab, pre-bumped by the caller
-    (``RowOptimizer.apply_sparse``) and carried through UNCHANGED here —
-    the counter transition is a cheap XLA scatter-add, not kernel work."""
-    hp = jnp.stack([jnp.asarray(lr, jnp.float32),
-                    jnp.zeros((), jnp.float32),
-                    jnp.asarray(eps, jnp.float32)])
-    return _stateful_call(_kernel_freq, w, cnt, sorted_rows, sorted_bags,
-                          sorted_msk, sorted_wgt, dY, hp, interpret)
+    out, _, _ = jax.lax.fori_loop(
+        0, nc, body, (views, jnp.zeros((1, E), jnp.float32),
+                      jnp.zeros((1,), jnp.int32)))
+    return tuple(v.T if c else v for v, c in zip(out, cols))
 
 
 def sort_lookups(tgt: jax.Array, valid: jax.Array | None, num_rows: int,
@@ -506,9 +292,9 @@ def sort_lookups(tgt: jax.Array, valid: jax.Array | None, num_rows: int,
     ``valid`` [L] bool or None; flat lookup ``i`` reads bag ``i // pooling``.
     ``weights`` [L] fp32 per-lookup bag weights or None (sum bags).
     Invalid/padding lookups are sorted to the tail as a zero-contribution
-    run on the last row (a bit-exact no-op rewrite of that row).  Returns
+    run on the last row (a masked run the kernel skips).  Returns
     (sorted_rows, sorted_bags, sorted_msk, sorted_wgt) — ready for the
-    kernels above.  Only scalars are sorted; the [*, E] gradient data is
+    kernel above.  Only scalars are sorted; the [*, E] gradient data is
     never permuted or expanded.
     """
     valid = ((tgt >= 0) & (tgt < num_rows)) if valid is None else (

@@ -2,8 +2,9 @@
 
 Handles shape padding to hardware-aligned blocks, GQA head expansion, and
 the interpret switch (``interpret=True`` executes the kernel bodies in
-Python — the validation mode on this CPU container; on TPU it compiles to
-Mosaic).  Default interpret mode follows the backend.
+Python — the validation mode on the CPU backend; on TPU it compiles to
+Mosaic).  Default interpret mode follows the backend: interpreted on the
+CPU, compiled on a TPU, and an error anywhere else.
 """
 
 from __future__ import annotations
@@ -15,7 +16,8 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.kernels.embedding_bag import embedding_bag_pallas
-from repro.kernels.embedding_update import sort_lookups
+from repro.kernels.embedding_update import (sort_lookups,
+                                            sparse_row_update_pallas)
 from repro.kernels.flash_attention import flash_attention_pallas
 from repro.kernels.fused_mlp import fused_mlp_pallas
 from repro.kernels.interaction import interaction_pallas
@@ -23,7 +25,14 @@ from repro.kernels.split_sgd import split_sgd_pallas
 
 
 def _default_interpret() -> bool:
-    return jax.default_backend() != "tpu"
+    """Interpret the kernels exactly when the backend is the CPU.  There is
+    no fallback: a TPU always runs them compiled, and any other backend is
+    refused rather than silently emulated."""
+    backend = jax.default_backend()
+    if backend not in ("cpu", "tpu"):
+        raise RuntimeError(f"Pallas kernels target the TPU (compiled) or the "
+                           f"CPU (interpreted), not {backend!r}")
+    return backend == "cpu"
 
 
 def _pad_dim(x: jax.Array, axis: int, mult: int):
@@ -71,17 +80,16 @@ def embedding_bag(W, idx, bags_per_block: int = 8,
 
 # ---------------------------------------------------------------------------
 # Fused sparse row-optimizer update — ONE entry point for every registered
-# RowOptimizer (repro/optim/row.py), replacing the former 4-way
-# fused_embedding_update{,_fp32}{,_presorted} surface.  Nothing outside
-# repro.optim.row should call these: model/pipeline code goes through
+# RowOptimizer (repro/optim/row.py).  Nothing outside repro.optim.row
+# should call these: model/pipeline code goes through
 # ``RowOptimizer.apply_sparse``, which owns the store layout and the
 # reference-path parity contracts.
 #
 # There is NO per-optimizer dispatch here (enforced by a source-scan
-# test): the optimizer instance carries its own fused Pallas entry as the
-# ``kernel`` registration hook, and this module only owns the generic
-# plumbing — lane padding, the sorted-stream prep, the interpret switch.
-# ``register()`` alone (plus one kernel body) adds an optimizer.
+# test): the optimizer instance carries its row math as the ``step``
+# registration hook, and this module only owns the generic plumbing —
+# the sorted-stream prep and the interpret switch.  ``register()`` alone
+# adds an optimizer.
 # ---------------------------------------------------------------------------
 
 
@@ -96,24 +104,14 @@ def _coerce_opt(opt):
 
 def _dispatch_row_kernel(opt, store, srows, sbags, smsk, swgt, dY, lr,
                          seed, interpret):
-    """Pad every slab's lane dim to a 128 multiple (compiled path), run
-    the optimizer's Pallas kernel hook on the sorted stream, and slice
-    the padding back off per slab.  On the compiled TPU path a
-    non-128-multiple width is padded, which copies the slab and forfeits
-    the O(unique_rows) traffic — production shards keep E % 128 == 0 so
-    the pad is a no-op (a [M, 1] per-row scalar lane always pads; its
-    per-row traffic is one scalar either way).  Interpret mode (the CPU
-    validation path) has no lane constraint and never pads."""
-    e_real = store[opt.weight_keys[0]].shape[1]
-    if interpret:
-        return opt.kernel(opt, store, srows, sbags, smsk, swgt, dY, lr,
-                          seed, e_real, True)
-    widths = {k: v.shape[1] for k, v in store.items()}
-    padded = {k: _pad_dim(v, 1, 128)[0] for k, v in store.items()}
-    dYp, _ = _pad_dim(dY, 1, 128)
-    out = opt.kernel(opt, padded, srows, sbags, smsk, swgt, dYp, lr, seed,
-                     e_real, interpret)
-    return {k: v[:, :widths[k]] for k, v in out.items()}
+    """Run the fused kernel with the optimizer's row step on its slabs.
+    Any slab width goes to the chip as it is: the kernel's blocks span
+    the full width, so no lane padding (and no slab copy) is needed."""
+    keys = opt.slab_keys
+    out = sparse_row_update_pallas(partial(opt.step, opt),
+                                   [store[k] for k in keys], srows, sbags,
+                                   smsk, swgt, dY, lr, seed, interpret)
+    return dict(zip(keys, out))
 
 
 @partial(jax.jit, static_argnames=("opt", "pooling", "interpret"))
@@ -124,7 +122,7 @@ def fused_row_update(opt, store, tgt, dY, lr, *, seed=0, valid=None,
     generalized to pluggable per-row state).
 
     ``opt``: a registered RowOptimizer (or its registry name) — its
-    ``kernel`` hook owns which Pallas body runs.  ``store``: the
+    ``step`` hook is the row math the kernel runs.  ``store``: the
     optimizer's EmbeddingStore dict — weight slab(s) (``hi``/``lo``
     split-bf16 or ``w`` fp32) plus zero or more per-row state slabs
     (``mom``/``acc``, fp32 or compressed bf16-hi), all row-aligned on the
@@ -136,11 +134,10 @@ def fused_row_update(opt, store, tgt, dY, lr, *, seed=0, valid=None,
     per-lookup bag weights scaling each cotangent row before the in-VMEM
     duplicate pre-reduction.  ``seed``: int32 per-step stochastic-rounding
     seed (ignored by deterministic optimizers).  Returns the updated
-    store: only touched rows (weights AND state) are read/written, in
-    place via aliasing.  The unweighted ``split_sgd`` result is
-    bit-identical to the jitted ``apply_rows_split_sgd`` reference; the
-    WEIGHTED accumulation is FMA-contracted and sits within 1 ulp/step of
-    the pre-scaled reference."""
+    store: only the tile-aligned row groups holding touched rows (weights
+    AND state) are read/written, in place via aliasing.  The unweighted
+    ``split_sgd`` result is bit-identical to the jitted
+    ``apply_rows_split_sgd`` reference."""
     opt = _coerce_opt(opt)
     interpret = _default_interpret() if interpret is None else interpret
     M = store[opt.weight_keys[0]].shape[0]

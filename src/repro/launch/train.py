@@ -1,15 +1,20 @@
 """Training driver.
 
 Runs REDUCED-scale versions of the registered architectures on the local
-device set (the full configs are exercised via the dry-run).  Examples:
+device set by default; ``--paper`` trains the paper's own DLRM configs
+(``configs/dlrm_paper.py``: published widths and batch) instead.
+Examples:
 
     PYTHONPATH=src python -m repro.launch.train --arch dlrm-small --steps 50
+    PYTHONPATH=src python -m repro.launch.train --arch dlrm-small --paper \
+        --emb-mode table --steps 20
     PYTHONPATH=src python -m repro.launch.train --arch fm --steps 100
     PYTHONPATH=src python -m repro.launch.train --arch internlm2-1.8b \
         --steps 20 --preset smoke
 
 With XLA_FLAGS=--xla_force_host_platform_device_count=8 the hybrid-parallel
-paths run on a real (2, 4) mesh; single-device otherwise.
+paths run on a real (2, 4) mesh; single-device otherwise.  Compiled
+programs persist in JAX's compilation cache (``repro.launch.compile_cache``).
 
 Recsys archs can stream a PACKED dataset (docs/data.md) instead of the
 in-process synthetic generator:
@@ -29,12 +34,14 @@ from __future__ import annotations
 
 import argparse
 import dataclasses
+import types
 from pathlib import Path
 
 import jax
 import numpy as np
 
 from repro import telemetry
+from repro.launch import compile_cache
 from repro.launch.mesh import make_mesh
 from repro.train import TrainLoop, TrainLoopConfig
 
@@ -129,6 +136,57 @@ def reduced_dlrm(name: str, batch: int):
                       pooling=10, batch=batch)
 
 
+def dlrm_config(args):
+    """The DLRMConfig a run trains: with ``--paper`` the registered paper
+    config (``configs/dlrm_paper.py``) at its published widths and batch
+    (``--batch`` overrides the batch), else the reduced one; then the
+    run's optimizer / pipeline flags."""
+    if args.paper:
+        from repro.configs import dlrm_paper
+        make = {"dlrm-small": dlrm_paper.dlrm_small,
+                "dlrm-large": dlrm_paper.dlrm_large,
+                "dlrm-mlperf": dlrm_paper.dlrm_mlperf}[args.arch]
+        cfg = make(mode=args.emb_mode or "row",
+                   **({} if args.batch is None else {"batch": args.batch}))
+    else:
+        cfg = reduced_dlrm(args.arch, args.batch or 256)
+        if args.emb_mode:
+            cfg = dataclasses.replace(cfg, emb_mode=args.emb_mode)
+    lr = args.lr if args.lr is not None else cfg.lr if args.paper else 0.05
+    return dataclasses.replace(
+        cfg, lr=lr, sparse_optimizer=args.optimizer, opt_beta=args.beta,
+        opt_eps=args.eps, microbatches=args.microbatches,
+        host_presort=args.host_presort, weighted=args.weighted,
+        sr_seed=args.seed, hot_rows=args.hot_rows,
+        promote_every=args.promote_every, hot_sync=args.hot_sync,
+        exchange_dtype=args.exchange_dtype, step_metrics=args.step_metrics)
+
+
+def build_dlrm(args, mesh, key):
+    """Everything a DLRM run needs, built the one way both ``main``
+    and ``chip_smoke.py`` use: config, initial state, the jitted
+    pipelined step, the batch placement and the batch source."""
+    from repro.core import dlrm as D
+    from repro.data.synthetic import dlrm_stream
+    cfg = dlrm_config(args)
+    state, layout = D.init_state(key, cfg, mesh)
+    step, shardings, bspecs, _ = D.make_train_step(cfg, mesh)
+    if args.data_format == "packed":
+        stream = packed_stream(
+            args, dict(batch=cfg.batch, table_rows=cfg.table_rows,
+                       pooling=cfg.pooling, num_dense=cfg.num_dense,
+                       weighted=cfg.weighted),
+            layout, args.host_presort)
+    else:
+        stream = dlrm_stream(0, cfg, args.alpha, layout)
+    return types.SimpleNamespace(
+        cfg=cfg, state=state, layout=layout, step=step,
+        shardings=shardings, bspecs=bspecs,
+        batch_shardings=_bspec_shardings(mesh, bspecs), stream=stream,
+        smoke_stream=lambda: dlrm_stream(1, cfg, args.alpha, layout),
+        profile_def=D.as_hybrid_def(cfg))
+
+
 def reduced_hybrid(name: str, batch: int):
     from repro.models import recsys as R
     if name == "fm":
@@ -157,13 +215,26 @@ def reduced_lm(name: str, batch: int, seq: int):
     return TransformerConfig(name=name, **base), batch, seq
 
 
-def main():
+def parse_args(argv=None):
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", required=True)
+    ap.add_argument("--paper", action="store_true",
+                    help="train the paper's own config of a dlrm arch "
+                         "(configs/dlrm_paper.py: dlrm-small | dlrm-large "
+                         "| dlrm-mlperf) at its published widths and "
+                         "batch, instead of the reduced one")
+    ap.add_argument("--emb-mode", choices=("row", "table"), default=None,
+                    help="embedding placement of a dlrm arch: row-wise "
+                         "sharded rows or the paper's table-wise "
+                         "placement (default: the config's own, row)")
     ap.add_argument("--steps", type=int, default=50)
-    ap.add_argument("--batch", type=int, default=256)
+    ap.add_argument("--batch", type=int, default=None,
+                    help="global batch (default: 256 for the reduced "
+                         "archs, the published batch with --paper)")
     ap.add_argument("--seq", type=int, default=128)
-    ap.add_argument("--lr", type=float, default=0.05)
+    ap.add_argument("--lr", type=float, default=None,
+                    help="learning rate (default: 0.05 for the reduced "
+                         "archs, the config's own with --paper)")
     ap.add_argument("--optimizer", default=None,
                     help="sparse RowOptimizer for the embedding path "
                          "(repro/optim/row.py): sgd | split_sgd | momentum "
@@ -267,11 +338,23 @@ def main():
     ap.add_argument("--serve-buckets", default="8,32,128",
                     help="compiled serving batch-shape ladder for "
                          "--serve-smoke (ascending, comma-separated)")
-    args = ap.parse_args()
-    if args.trace_dir:
-        telemetry.configure(enabled=True, trace_dir=args.trace_dir)
+    args = ap.parse_args(argv)
     if args.data_format is None:
         args.data_format = "packed" if args.data_dir else "synthetic"
+    if args.paper and args.arch not in ("dlrm-small", "dlrm-large",
+                                        "dlrm-mlperf"):
+        raise SystemExit("--paper trains a registered paper config: "
+                         "dlrm-small | dlrm-large | dlrm-mlperf")
+    if args.emb_mode and not args.arch.startswith("dlrm"):
+        raise SystemExit("--emb-mode places the embeddings of a dlrm arch")
+    return args
+
+
+def main():
+    args = parse_args()
+    compile_cache.enable()
+    if args.trace_dir:
+        telemetry.configure(enabled=True, trace_dir=args.trace_dir)
     if args.data_format == "packed" and not args.data_dir:
         raise SystemExit("--data-format packed requires --data-dir")
     if args.weighted and args.data_format != "packed":
@@ -297,41 +380,20 @@ def main():
         row_optim.get(args.optimizer)   # unknown name fails here, loudly
 
     if args.arch.startswith("dlrm"):
-        from repro.core import dlrm as D
-        from repro.data.synthetic import dlrm_stream
-        cfg = dataclasses.replace(reduced_dlrm(args.arch, args.batch),
-                                  lr=args.lr,
-                                  sparse_optimizer=args.optimizer,
-                                  opt_beta=args.beta, opt_eps=args.eps,
-                                  microbatches=args.microbatches,
-                                  host_presort=args.host_presort,
-                                  weighted=args.weighted,
-                                  sr_seed=args.seed,
-                                  hot_rows=args.hot_rows,
-                                  promote_every=args.promote_every,
-                                  hot_sync=args.hot_sync,
-                                  exchange_dtype=args.exchange_dtype,
-                                  step_metrics=args.step_metrics)
-        state, layout = D.init_state(key, cfg, mesh)
-        profile_def = D.as_hybrid_def(cfg)
-        step, shardings, bspecs, _ = D.make_train_step(cfg, mesh)
-        batch_shardings = _bspec_shardings(mesh, bspecs)
-        if args.data_format == "packed":
-            stream = packed_stream(
-                args, dict(batch=cfg.batch, table_rows=cfg.table_rows,
-                           pooling=cfg.pooling, num_dense=cfg.num_dense,
-                           weighted=cfg.weighted),
-                layout, args.host_presort)
-        else:
-            stream = dlrm_stream(0, cfg, args.alpha)
-        smoke_stream = lambda: dlrm_stream(1, cfg, args.alpha)  # noqa: E731
+        run = build_dlrm(args, mesh, key)
+        cfg, state, step = run.cfg, run.state, run.step
+        shardings, batch_shardings = run.shardings, run.batch_shardings
+        stream, smoke_stream = run.stream, run.smoke_stream
+        profile_def = run.profile_def
         n_params = cfg.spec.total_rows * cfg.emb_dim
         print(f"[train] {args.arch}: ~{n_params/1e6:.1f}M embedding params")
     elif args.arch in ("fm", "bst", "sasrec", "din"):
         from repro.core import hybrid as H
         from repro.data.synthetic import hybrid_stream
-        mdef = dataclasses.replace(reduced_hybrid(args.arch, args.batch),
-                                   lr=args.lr, emb_lr=args.lr,
+        lr = 0.05 if args.lr is None else args.lr
+        mdef = dataclasses.replace(reduced_hybrid(args.arch,
+                                                  args.batch or 256),
+                                   lr=lr, emb_lr=lr,
                                    sparse_optimizer=args.optimizer,
                                    opt_beta=args.beta, opt_eps=args.eps,
                                    microbatches=args.microbatches,
@@ -399,11 +461,11 @@ def main():
                 "--publish-every/--serve-smoke publish the recsys serving "
                 "snapshot (dlrm/fm/bst/sasrec/din); LM archs have no "
                 "serving path")
-        cfg, B, L = reduced_lm(args.arch, args.batch, args.seq)
+        cfg, B, L = reduced_lm(args.arch, args.batch or 256, args.seq)
         profile_def = None
         state = lm_steps.init_lm_state(key, cfg, mesh)
         step, structs, shardings = lm_steps.make_lm_train_step(
-            cfg, mesh, B, L, lr=args.lr)
+            cfg, mesh, B, L, lr=0.05 if args.lr is None else args.lr)
         shardings = shardings[0]
         stream = ({k: jax.numpy.asarray(v) for k, v in b.items()}
                   for b in token_stream(0, cfg.vocab, B, L))

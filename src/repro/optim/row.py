@@ -18,22 +18,22 @@ module is the plug-in point:
   (reference scan, fused Pallas kernel, host-pre-sorted stream) goes
   through.
 
-* The per-optimizer MATH lives on the instance, as three hooks supplied
-  at registration time (the ROADMAP "strategy registration" refactor):
+* The per-optimizer MATH lives on the instance, as hooks supplied at
+  registration time (the ROADMAP "strategy registration" refactor):
 
-  - ``kernel``          — the fused Pallas entry: called by
-    ``kernels.ops`` on the (lane-aligned) sorted stream; owns which
-    kernel body runs and how the hyperparameters/seed reach it.
-  - ``reference``       — the reduced-stream reference transition
-    (unique rows + per-row gradient sums), applied exactly once per row
-    per step; the chunked scan path accumulates across chunks first.
+  - ``step``            — the row step: the optimizer's per-row math,
+    elementwise over [n, W] row blocks of every slab.  The fused Pallas
+    kernel runs it on one tile-aligned row group per touched row; the
+    reference runs the SAME function on the gathered unique rows with
+    their per-row gradient sums, once per row per step (the chunked scan
+    path accumulates across chunks first).
   - ``flat_reference``  — optional per-lookup reference (the stateless
-    kinds' legacy scatter semantics); defaults to dedup + ``reference``.
+    kinds' legacy scatter semantics); defaults to dedup + ``step``.
 
   ``kernels/ops.py``, ``core/sharded_embedding.py`` and
   ``core/pipeline.py`` contain NO per-optimizer dispatch (enforced by a
-  source-scan test): :func:`register` alone — plus one Pallas kernel
-  body — adds an optimizer end-to-end.
+  source-scan test): :func:`register` alone — one row step — adds an
+  optimizer end-to-end, fused kernel included.
 
 * The registry (:func:`register` / :func:`get` / :func:`make`) names the
   built-ins: ``sgd``, ``split_sgd``, ``momentum``, ``adagrad_rowwise``,
@@ -48,7 +48,7 @@ Determinism / parity contracts (tests/test_row_optim.py,
 tests/test_stochastic.py):
 
 * ``split_sgd``: fused == the jitted ``split_fp32``/``combine_split``
-  reference, BITWISE (inherited from the PR-1 kernel, pinned).
+  reference, BITWISE (one row step serves both; pinned).
 * ``momentum(beta=0)``: bitwise == ``sgd`` on the fused path (both
   pre-reduce duplicates; ``0 * m + acc`` is an exact fp32 identity).
 * ``adagrad`` / ``adagrad_rowwise`` first step from zero state == SGD
@@ -88,7 +88,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.optim.split_sgd import combine_split, split_fp32
-from repro.optim.stochastic import sr_noise, sr_round_bf16
+from repro.optim.stochastic import sr_noise_col, sr_round_bf16
 
 
 # ---------------------------------------------------------------------------
@@ -205,22 +205,22 @@ class SparseStream:
 class RowOptimizer:
     """A sparse embedding optimizer: store layout + one fused apply.
 
-    The three callables are the REGISTRATION HOOKS — they carry the whole
+    The callables are the REGISTRATION HOOKS — they carry the whole
     per-optimizer math, so nothing outside the instance dispatches on an
     optimizer kind:
 
-    ``kernel(opt, store, srows, sbags, smsk, swgt, dY, lr, seed, e_real,
-    interpret) -> store``
-        fused Pallas entry on the sorted stream (slabs already
-        lane-aligned by ``kernels.ops``; ``e_real`` is the unpadded E).
-    ``reference(opt, store, rep, summed, lr, seed) -> store``
-        reduced-stream reference transition — ``rep`` [n] unique touched
-        rows (``num_rows`` fillers dropped by the scatter), ``summed``
-        [n, E] per-row gradient sums; applied exactly ONCE per row per
-        step.
+    ``step(opt, blocks, g, lr, seed, rows) -> blocks``
+        the row step.  ``blocks``: one [n, W] block per slab, in
+        ``slab_keys`` order; ``g`` [n or 1, E] fp32 per-row gradient sums;
+        ``lr`` fp32 scalar; ``seed`` int32 stochastic-rounding seed;
+        ``rows`` [n, 1] int32 row ids of the blocks.  Returns the new
+        blocks, elementwise per row (a row's result must not depend on
+        its neighbours: the fused kernel runs it on a whole tile-aligned
+        row group and keeps one row).  Applied exactly ONCE per touched
+        row per step, by the kernel and by the reference alike.
     ``flat_reference(opt, store, tgt, grad, lr, seed) -> store``
         optional per-lookup reference (the stateless kinds' scatter
-        semantics); ``None`` means dedup + ``reference``.
+        semantics); ``None`` means dedup + ``step``.
 
     ``split`` says whether the master weights live as (hi bf16, lo
     uint16) or one fp32 ``w`` slab; ``state`` lists the per-row state
@@ -237,8 +237,7 @@ class RowOptimizer:
     beta: float = 0.0            # momentum coefficient
     eps: float = 1e-8            # adagrad denominator floor
     stochastic_round: bool = False
-    kernel: Optional[Callable] = None
-    reference: Optional[Callable] = None
+    step: Optional[Callable] = None
     flat_reference: Optional[Callable] = None
 
     # ---------------------------------------------------------- store --
@@ -249,6 +248,11 @@ class RowOptimizer:
     @property
     def state_keys(self) -> tuple:
         return tuple(s[0] for s in self.state)
+
+    @property
+    def slab_keys(self) -> tuple:
+        """The slabs the row step reads and writes, in its block order."""
+        return self.weight_keys + self.state_keys
 
     def state_slabs(self) -> tuple:
         """Normalized ``(key, width, dtype)`` per state slab."""
@@ -340,8 +344,7 @@ class RowOptimizer:
             store = dict(store)
             store["cnt"] = bump_counters(store["cnt"], touch, num_rows)
         elif "cnt" in store:
-            # auxiliary counter: the hooks (and the kernel lane padding in
-            # kernels.ops, which drops unknown input keys) must not see it
+            # auxiliary counter: the hooks must not see it
             store = dict(store)
             aux_cnt = bump_counters(store.pop("cnt"), touch, num_rows)
 
@@ -372,7 +375,7 @@ class RowOptimizer:
                                              interpret=interpret))
         # reference: expand dY to per-lookup grads (the thing the fused
         # kernel never materializes), zero the masked entries, and apply
-        # the instance's reference row math
+        # the instance's row step to the deduplicated rows
         grad = jnp.broadcast_to(dY[..., None, :],
                                 idx.shape + (E,)).astype(jnp.float32)
         if stream.weights is not None:
@@ -401,194 +404,105 @@ class RowOptimizer:
 
     def apply_rows_reduced(self, store: dict, rep: jax.Array,
                            summed: jax.Array, lr, seed=None) -> dict:
-        """Stateful reference transition on a PRE-REDUCED stream: ``rep``
-        [n] unique touched rows (``num_rows`` fillers are dropped by the
-        scatter), ``summed`` [n, E] their per-row gradient sums.  Applied
-        exactly ONCE per row per step — the contract a batch-chunked
-        caller must preserve by accumulating gradients across chunks
-        first (``se.apply_update``) instead of re-running the momentum
-        decay / Adagrad accumulate per chunk.  Dispatches to the
-        instance's ``reference`` hook.
+        """Reference transition on a PRE-REDUCED stream: ``rep`` [n]
+        unique touched rows (``num_rows`` fillers are dropped by the
+        scatter), ``summed`` [n, E] their per-row gradient sums.  Gathers
+        the rows of every slab, runs the instance's row ``step`` on them
+        and scatters the result back — applied exactly ONCE per row per
+        step, the contract a batch-chunked caller must preserve by
+        accumulating gradients across chunks first (``se.apply_update``)
+        instead of re-running the momentum decay / Adagrad accumulate per
+        chunk.
 
         An AUXILIARY ``cnt`` slab is carried through UNCHANGED — on this
         pre-reduced entry the caller owns the bump (``rep`` is
         deduplicated, so +1 per entry would undercount duplicates); a
-        declared-state ``cnt`` (``adagrad_freq``) reaches the hook as-is
+        declared-state ``cnt`` (``adagrad_freq``) reaches the step as-is
         and the caller must have bumped it already."""
-        if self.reference is None:
-            raise ValueError(
-                f"row optimizer {self.name!r} registered no reduced "
-                "reference transition (reference=) — required for "
-                "stateful optimizers")
         seed = jnp.asarray(0 if seed is None else seed, jnp.int32)
-        aux_cnt = None
-        if "cnt" in store and "cnt" not in self.state_keys:
-            store = dict(store)
-            aux_cnt = store.pop("cnt")
-        out = self.reference(self, store, rep, summed, lr, seed)
-        if aux_cnt is not None:
-            out = dict(out)
-            out["cnt"] = aux_cnt
+        # the sums are complete before the step reads them: without the
+        # barrier XLA folds ``beta * m + segment_sum(g)`` into one
+        # scatter-add seeded with ``beta * m``, which reassociates the
+        # fp32 sum and leaves the kernel's sum-then-step order
+        summed = jax.lax.optimization_barrier(summed)
+        out = dict(store)
+        safe = jnp.minimum(rep, self.fwd_weights(store).shape[0] - 1)
+        blocks = tuple(jnp.take(store[k], safe, axis=0)
+                       for k in self.slab_keys)
+        new = self.step(self, blocks, summed, lr, seed, safe[:, None])
+        for k, v in zip(self.slab_keys, new):
+            out[k] = store[k].at[rep].set(v.astype(store[k].dtype))
         return out
 
 
 # ---------------------------------------------------------------------------
-# Built-in hook implementations.  ``kernel`` hooks import the Pallas
-# entries lazily (kernels.embedding_update) so the reference paths stay
-# importable without the kernel stack; each one is a thin adapter from
-# the generic hook signature to one kernel entry.
+# Built-in row steps.  Each is the optimizer's whole per-row math, written
+# elementwise over [n, W] row blocks so the SAME expression runs in the
+# fused kernel (on one tile-aligned row group, ``g`` [1, E]) and in the
+# reference (on the gathered unique rows, ``g`` [n, E]).  ``blocks`` and
+# the returned tuple follow ``RowOptimizer.slab_keys``; ``rows`` [n, 1]
+# are the blocks' row ids (the stochastic-rounding counter).
 # ---------------------------------------------------------------------------
 
-def _take_rows(store: dict, rep: jax.Array) -> tuple:
-    """(safe gather index, fp32 weight rows) for a reduced stream."""
-    W = store["w"]
-    safe = jnp.minimum(rep, W.shape[0] - 1)
-    return safe, jnp.take(W, safe, axis=0)
+def _step_sgd(opt, blocks, g, lr, seed, rows):
+    (w,) = blocks
+    return (w - lr * g,)
+
+
+def _step_split_sgd(opt, blocks, g, lr, seed, rows):
+    hi, lo = blocks
+    return split_fp32(combine_split(hi, lo) - lr * g)
+
+
+def _step_momentum(opt, blocks, g, lr, seed, rows):
+    w, m = blocks
+    m_new = opt.beta * m + g
+    return w - lr * m_new, m_new
+
+
+def _step_adagrad(opt, blocks, g, lr, seed, rows):
+    w, s = blocks
+    s_new = s + g * g
+    return w - lr * g / (jnp.sqrt(s_new) + opt.eps), s_new
+
+
+def _step_adagrad_rowwise(opt, blocks, g, lr, seed, rows):
+    # ONE accumulator scalar per row (Naumov et al. 2019): [n, 1] slab
+    w, s = blocks
+    s_new = s + jnp.mean(g * g, axis=-1, keepdims=True)
+    return w - lr * g / (jnp.sqrt(s_new) + opt.eps), s_new
+
+
+def _step_momentum_bf16(opt, blocks, g, lr, seed, rows):
+    # decode exact, fp32 transition, stochastically round ONLY the stored
+    # state — the dither is a pure function of (seed, row, lane)
+    w, m = blocks
+    m_new = opt.beta * m.astype(jnp.float32) + g
+    noise = sr_noise_col(seed, rows, m_new.shape[-1])
+    return w - lr * m_new, sr_round_bf16(m_new, noise)
+
+
+def _step_adagrad_bf16(opt, blocks, g, lr, seed, rows):
+    # the weight step divides by the UNROUNDED fp32 accumulator
+    w, s = blocks
+    s_new = s.astype(jnp.float32) + g * g
+    noise = sr_noise_col(seed, rows, s_new.shape[-1])
+    return (w - lr * g / (jnp.sqrt(s_new) + opt.eps),
+            sr_round_bf16(s_new, noise))
+
+
+def _step_adagrad_freq(opt, blocks, g, lr, seed, rows):
+    # frequency-adaptive sparse LR: the ``cnt`` slab is the POST-bump
+    # touch counter (apply_sparse bumps the reserved slab before
+    # dispatch), so hot rows take proportionally smaller steps.  The step
+    # only READS the counter; the bump owns the transition.
+    w, c = blocks
+    denom = jnp.sqrt(jnp.maximum(c.astype(jnp.float32), 1.0)) + opt.eps
+    return w - lr * g / denom, c
 
 
 def _flatref_sgd(opt, store, tgt, grad, lr, seed):
     return {"w": apply_rows_sgd(store["w"], tgt, grad, lr)}
-
-
-def _flatref_split_sgd(opt, store, tgt, grad, lr, seed):
-    nh, nl = apply_rows_split_sgd(store["hi"], store["lo"], tgt, grad, lr)
-    return {"hi": nh, "lo": nl}
-
-
-def _ref_momentum(opt, store, rep, summed, lr, seed):
-    safe, w_rows = _take_rows(store, rep)
-    m_rows = jnp.take(store["mom"], safe, axis=0)
-    m_new = opt.beta * m_rows + summed
-    w_new = w_rows - lr * m_new
-    return {"w": store["w"].at[rep].set(w_new),
-            "mom": store["mom"].at[rep].set(m_new)}
-
-
-def _ref_adagrad(opt, store, rep, summed, lr, seed):
-    safe, w_rows = _take_rows(store, rep)
-    s_rows = jnp.take(store["acc"], safe, axis=0)
-    s_new = s_rows + summed * summed
-    w_new = w_rows - lr * summed / (jnp.sqrt(s_new) + opt.eps)
-    return {"w": store["w"].at[rep].set(w_new),
-            "acc": store["acc"].at[rep].set(s_new)}
-
-
-def _ref_adagrad_rowwise(opt, store, rep, summed, lr, seed):
-    safe, w_rows = _take_rows(store, rep)
-    s_rows = jnp.take(store["acc"], safe, axis=0)          # [n, 1]
-    ms = jnp.mean(summed * summed, axis=1, keepdims=True)
-    s_new = s_rows + ms
-    w_new = w_rows - lr * summed / (jnp.sqrt(s_new) + opt.eps)
-    return {"w": store["w"].at[rep].set(w_new),
-            "acc": store["acc"].at[rep].set(s_new)}
-
-
-def _ref_momentum_bf16(opt, store, rep, summed, lr, seed):
-    # same expressions as _kernel_momentum_bf16: decode exact, fp32
-    # transition, stochastically round ONLY the stored state — noise is a
-    # pure function of (seed, row, lane), so this path is bitwise the
-    # fused kernel on the same stream
-    safe, w_rows = _take_rows(store, rep)
-    m_rows = jnp.take(store["mom"], safe, axis=0).astype(jnp.float32)
-    m_new = opt.beta * m_rows + summed
-    w_new = w_rows - lr * m_new
-    m_out = sr_round_bf16(m_new, sr_noise(seed, safe, m_new.shape[-1]))
-    return {"w": store["w"].at[rep].set(w_new),
-            "mom": store["mom"].at[rep].set(m_out)}
-
-
-def _ref_adagrad_bf16(opt, store, rep, summed, lr, seed):
-    safe, w_rows = _take_rows(store, rep)
-    s_rows = jnp.take(store["acc"], safe, axis=0).astype(jnp.float32)
-    s_new = s_rows + summed * summed
-    w_new = w_rows - lr * summed / (jnp.sqrt(s_new) + opt.eps)
-    s_out = sr_round_bf16(s_new, sr_noise(seed, safe, s_new.shape[-1]))
-    return {"w": store["w"].at[rep].set(w_new),
-            "acc": store["acc"].at[rep].set(s_out)}
-
-
-def _ref_adagrad_freq(opt, store, rep, summed, lr, seed):
-    # frequency-adaptive sparse LR: store["cnt"] is the POST-bump touch
-    # counter (apply_sparse bumps the reserved slab before dispatch), so
-    # hot rows — large counts — take proportionally smaller steps.  The
-    # hook only READS the counter; the bump owns the transition.
-    safe, w_rows = _take_rows(store, rep)
-    c = jnp.take(store["cnt"], safe, axis=0).astype(jnp.float32)   # [n, 1]
-    denom = jnp.sqrt(jnp.maximum(c, 1.0)) + opt.eps
-    w_new = w_rows - lr * summed / denom
-    return {"w": store["w"].at[rep].set(w_new), "cnt": store["cnt"]}
-
-
-def _k_sgd(opt, store, srows, sbags, smsk, swgt, dY, lr, seed, e_real,
-           interpret):
-    from repro.kernels import embedding_update as ku
-    return {"w": ku.fused_update_fp32_pallas(store["w"], srows, sbags, smsk,
-                                             swgt, dY, lr,
-                                             interpret=interpret)}
-
-
-def _k_split_sgd(opt, store, srows, sbags, smsk, swgt, dY, lr, seed, e_real,
-                 interpret):
-    from repro.kernels import embedding_update as ku
-    nh, nl = ku.fused_update_split_pallas(store["hi"], store["lo"], srows,
-                                          sbags, smsk, swgt, dY, lr,
-                                          interpret=interpret)
-    return {"hi": nh, "lo": nl}
-
-
-def _k_momentum(opt, store, srows, sbags, smsk, swgt, dY, lr, seed, e_real,
-                interpret):
-    from repro.kernels import embedding_update as ku
-    nw, nm = ku.fused_update_momentum_pallas(store["w"], store["mom"],
-                                             srows, sbags, smsk, swgt, dY,
-                                             lr, opt.beta,
-                                             interpret=interpret)
-    return {"w": nw, "mom": nm}
-
-
-def _k_adagrad(opt, store, srows, sbags, smsk, swgt, dY, lr, seed, e_real,
-               interpret):
-    from repro.kernels import embedding_update as ku
-    nw, ns = ku.fused_update_adagrad_pallas(
-        store["w"], store["acc"], srows, sbags, smsk, swgt, dY, lr,
-        opt.eps, False, e_real, interpret=interpret)
-    return {"w": nw, "acc": ns}
-
-
-def _k_adagrad_rowwise(opt, store, srows, sbags, smsk, swgt, dY, lr, seed,
-                       e_real, interpret):
-    from repro.kernels import embedding_update as ku
-    nw, ns = ku.fused_update_adagrad_pallas(
-        store["w"], store["acc"], srows, sbags, smsk, swgt, dY, lr,
-        opt.eps, True, e_real, interpret=interpret)
-    return {"w": nw, "acc": ns}
-
-
-def _k_momentum_bf16(opt, store, srows, sbags, smsk, swgt, dY, lr, seed,
-                     e_real, interpret):
-    from repro.kernels import embedding_update as ku
-    nw, nm = ku.fused_update_momentum_bf16_pallas(
-        store["w"], store["mom"], srows, sbags, smsk, swgt, dY, lr,
-        opt.beta, seed, interpret=interpret)
-    return {"w": nw, "mom": nm}
-
-
-def _k_adagrad_bf16(opt, store, srows, sbags, smsk, swgt, dY, lr, seed,
-                    e_real, interpret):
-    from repro.kernels import embedding_update as ku
-    nw, ns = ku.fused_update_adagrad_bf16_pallas(
-        store["w"], store["acc"], srows, sbags, smsk, swgt, dY, lr,
-        opt.eps, seed, interpret=interpret)
-    return {"w": nw, "acc": ns}
-
-
-def _k_adagrad_freq(opt, store, srows, sbags, smsk, swgt, dY, lr, seed,
-                    e_real, interpret):
-    from repro.kernels import embedding_update as ku
-    nw, nc = ku.fused_update_freq_pallas(store["w"], store["cnt"], srows,
-                                         sbags, smsk, swgt, dY, lr,
-                                         opt.eps, interpret=interpret)
-    return {"w": nw, "cnt": nc}
 
 
 # ---------------------------------------------------------------------------
@@ -601,13 +515,9 @@ _REGISTRY: dict[str, RowOptimizer] = {}
 def register(opt: RowOptimizer) -> RowOptimizer:
     if opt.name in _REGISTRY:
         raise ValueError(f"row optimizer {opt.name!r} already registered")
-    if opt.kernel is None:
-        raise ValueError(f"row optimizer {opt.name!r} registered no fused "
-                         "kernel entry (kernel=)")
-    if opt.reference is None and opt.flat_reference is None:
-        raise ValueError(f"row optimizer {opt.name!r} registered no "
-                         "reference transition (reference= or "
-                         "flat_reference=)")
+    if opt.step is None:
+        raise ValueError(f"row optimizer {opt.name!r} registered no row "
+                         "step (step=)")
     _REGISTRY[opt.name] = opt
     return opt
 
@@ -675,36 +585,26 @@ def resolve(mdef: Any) -> RowOptimizer:
 
 
 register(RowOptimizer(name="sgd", split=False,
-                      kernel=_k_sgd, flat_reference=_flatref_sgd))
-register(RowOptimizer(name="split_sgd", split=True,
-                      kernel=_k_split_sgd,
-                      flat_reference=_flatref_split_sgd))
+                      step=_step_sgd, flat_reference=_flatref_sgd))
+register(RowOptimizer(name="split_sgd", split=True, step=_step_split_sgd))
 register(RowOptimizer(name="momentum", split=False,
-                      state=(("mom", 0),), beta=0.9,
-                      kernel=_k_momentum, reference=_ref_momentum))
+                      state=(("mom", 0),), beta=0.9, step=_step_momentum))
 register(RowOptimizer(name="adagrad_rowwise", split=False,
                       state=(("acc", 1),), eps=1e-8,
-                      kernel=_k_adagrad_rowwise,
-                      reference=_ref_adagrad_rowwise))
+                      step=_step_adagrad_rowwise))
 register(RowOptimizer(name="adagrad", split=False,
-                      state=(("acc", 0),), eps=1e-8,
-                      kernel=_k_adagrad, reference=_ref_adagrad))
+                      state=(("acc", 0),), eps=1e-8, step=_step_adagrad))
 # compressed bf16-hi state + seeded stochastic rounding: half the
 # state-slab bytes per touched row (see docs/optim.md for when NOT to)
 register(RowOptimizer(name="momentum_bf16", split=False,
                       state=(("mom", 0, "bfloat16"),), beta=0.9,
-                      stochastic_round=True,
-                      kernel=_k_momentum_bf16,
-                      reference=_ref_momentum_bf16))
+                      stochastic_round=True, step=_step_momentum_bf16))
 register(RowOptimizer(name="adagrad_bf16", split=False,
                       state=(("acc", 0, "bfloat16"),), eps=1e-8,
-                      stochastic_round=True,
-                      kernel=_k_adagrad_bf16,
-                      reference=_ref_adagrad_bf16))
+                      stochastic_round=True, step=_step_adagrad_bf16))
 # frequency-adaptive sparse LR driven by the reserved touch-counter slab
 # (hot rows — large counts — decay faster); the same counters feed the
 # hot-row cache's promotion policy (docs/cache.md)
 register(RowOptimizer(name="adagrad_freq", split=False,
                       state=(("cnt", 1, "int32"),), eps=1e-8,
-                      kernel=_k_adagrad_freq,
-                      reference=_ref_adagrad_freq))
+                      step=_step_adagrad_freq))

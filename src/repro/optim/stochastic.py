@@ -55,16 +55,23 @@ def sr_noise(seed: jax.Array, rows: jax.Array, width: int) -> jax.Array:
     """The dither stream: uint32 noise of shape ``rows.shape + (width,)``.
 
     Pure function of ``(seed, rows[...], lane)``; ``rows`` are (local) row
-    ids of any integer shape/dtype.  The lane counter is a 2-D+
-    ``broadcasted_iota`` (TPU-legal in kernel bodies).  Every path that
-    rounds the same row under the same seed sees the same bits.
+    ids of any integer shape/dtype.  Every path that rounds the same row
+    under the same seed sees the same bits.
     """
+    return sr_noise_col(seed, jnp.asarray(rows)[..., None], width)
+
+
+def sr_noise_col(seed: jax.Array, rows: jax.Array, width: int) -> jax.Array:
+    """:func:`sr_noise` for row ids held as a trailing unit column
+    ``rows`` [..., 1]: noise [..., width].  This is the 2-D form a kernel
+    body holds (one row id per sublane); the lane counter is a 2-D+
+    ``broadcasted_iota`` (TPU-legal in kernel bodies)."""
     seed_u = jnp.asarray(seed).astype(jnp.uint32)
     rows_u = jnp.asarray(rows).astype(jnp.uint32)
     base = mix32(seed_u * jnp.uint32(_GOLD) ^ rows_u * jnp.uint32(_ROWC))
-    lane = jax.lax.broadcasted_iota(jnp.uint32, rows_u.shape + (width,),
-                                    rows_u.ndim)
-    return mix32(base[..., None] ^ (lane * jnp.uint32(_GOLD) + jnp.uint32(1)))
+    lane = jax.lax.broadcasted_iota(jnp.uint32, rows_u.shape[:-1] + (width,),
+                                    rows_u.ndim - 1)
+    return mix32(base ^ (lane * jnp.uint32(_GOLD) + jnp.uint32(1)))
 
 
 def sr_round_bf16(x: jax.Array, noise_u32: jax.Array) -> jax.Array:

@@ -311,3 +311,59 @@ def test_dlrm_step_fused_trajectory_identical():
     assert out[False][0] == out[True][0]
     np.testing.assert_array_equal(out[False][1], out[True][1])
     np.testing.assert_array_equal(out[False][2], out[True][2])
+
+
+# ---------------------------------------------------------------------------
+# Chunked stream and slab orientation
+# ---------------------------------------------------------------------------
+
+def test_rows_on_lanes_follows_the_tpu_layout_rule():
+    """Narrow slabs are held transposed (rows on lanes), lane-multiple
+    widths row-major — the layout XLA:TPU's default picks (it pads less)."""
+    assert EU.rows_on_lanes((8_000_000, 64), jnp.bfloat16)
+    assert EU.rows_on_lanes((8_000_000, 64), jnp.float32)
+    assert EU.rows_on_lanes((8_000_000, 1), jnp.int32)
+    assert not EU.rows_on_lanes((8_000_000, 128), jnp.uint16)
+    assert not EU.rows_on_lanes((8_000_000, 256), jnp.float32)
+    assert EU.rows_on_lanes((5000, 192), jnp.float32)
+    assert not EU.rows_on_lanes((60, 64), jnp.float32)     # tie: row-major
+    assert EU.rows_on_lanes((60, 32), jnp.bfloat16)
+
+
+@pytest.mark.parametrize("name", ["split_sgd", "momentum", "momentum_bf16",
+                                  "adagrad_rowwise"])
+@pytest.mark.parametrize("E_", [16, 128])
+def test_chunked_stream_matches_reference_bitwise(monkeypatch, name, E_):
+    """A stream cut into many kernel calls (runs straddling every cut,
+    carried partial sums) equals the one-call kernel AND the jitted
+    reference bitwise, in both slab orientations (E=16: every slab on
+    lanes; E=128: row-major weights beside a lanes-held [M, 1] slab)."""
+    from functools import partial
+    from repro.optim import row
+    opt = row.get(name)
+    M, L, P = 300, 96, 3
+    W, _, _, tgt, dY = _mk(M, E_, L, P, dup_vocab=13, seed=5)
+    store = opt.init_store(W)
+    if opt.state:
+        # nonzero state, so the transition is not a degenerate first step
+        store = opt.apply_sparse(store, row.SparseStream(
+            idx=tgt.reshape(-1, 1, P), dY=dY[:, None]), 0.05, seed=1)
+    srt = EU.sort_lookups(tgt, None, M, P)
+    keys = opt.slab_keys
+
+    def kernel():
+        out = EU.sparse_row_update_pallas(partial(opt.step, opt),
+                                          [store[k] for k in keys], *srt,
+                                          dY, 0.05, 7, interpret=True)
+        return dict(zip(keys, out))
+
+    whole = kernel()
+    monkeypatch.setattr(EU, "CHUNK", 5)
+    cut = kernel()
+    want = jax.jit(lambda s: opt.apply_sparse(s, row.SparseStream(
+        idx=tgt.reshape(-1, 1, P), dY=dY[:, None]), 0.05, seed=7))(store)
+    for k in keys:
+        for got in (whole[k], cut[k]):
+            np.testing.assert_array_equal(
+                np.asarray(got).view(np.uint8), np.asarray(want[k]).view(
+                    np.uint8), err_msg=k)

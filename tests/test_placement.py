@@ -70,10 +70,7 @@ def test_decode_cache_sharding_choice():
     from repro.models import lm_steps
     from repro.models.transformer import TransformerConfig
     # AbstractMesh: sharding decisions are testable without 8 real devices
-    try:
-        mesh = jax.sharding.AbstractMesh((2, 4), ("data", "model"))
-    except TypeError:   # jax<0.5: AbstractMesh(((name, size), ...))
-        mesh = jax.sharding.AbstractMesh((("data", 2), ("model", 4)))
+    mesh = jax.sharding.AbstractMesh((2, 4), ("data", "model"))
     # Hkv=4 % 4 == 0 -> heads sharded
     cfg = TransformerConfig("a", n_layers=2, d_model=32, n_heads=4,
                             n_kv_heads=4, d_head=8, d_ff=64, vocab=64)

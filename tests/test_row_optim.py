@@ -235,20 +235,26 @@ def test_momentum_reference_matches_fused_and_state_hygiene():
 
 
 def test_pinned_legacy_split_kernel_bit_identity():
-    """The split_sgd path through the NEW RowOptimizer surface is bitwise
-    the PRE-REFACTOR ``ops.fused_embedding_update`` wrapper — pinned here
-    verbatim against the unchanged Pallas kernel."""
-    from repro.kernels.embedding_update import (fused_update_split_pallas,
-                                                sort_lookups)
+    """The split_sgd path through the RowOptimizer surface is bitwise the
+    PRE-REFACTOR ``ops.fused_embedding_update`` wrapper: the sort, then
+    the Pallas kernel with the Split-SGD row math (combine, step, split)
+    pinned here verbatim."""
+    from repro.kernels.embedding_update import (sort_lookups,
+                                                sparse_row_update_pallas)
+    from repro.optim.split_sgd import combine_split, split_fp32
+
+    def split_step(blocks, g, lr, seed, rows):
+        hi, lo = blocks
+        return split_fp32(combine_split(hi, lo) - lr * g)
 
     def legacy_fused_embedding_update(hi, lo, tgt, dY, lr, valid=None,
                                       weights=None, pooling=1):
-        # pre-refactor ops.py wrapper, interpret branch (CPU), verbatim
+        # pre-refactor ops.py wrapper, interpret branch (CPU)
         M = hi.shape[0]
         srows, sbags, smsk, swgt = sort_lookups(tgt, valid, M, pooling,
                                                 weights)
-        return fused_update_split_pallas(hi, lo, srows, sbags, smsk, swgt,
-                                         dY, lr, interpret=True)
+        return sparse_row_update_pallas(split_step, (hi, lo), srows, sbags,
+                                        smsk, swgt, dY, lr, interpret=True)
 
     W, idx, dY = _mk(vocab=8, seed=7)
     B, S, P = idx.shape
@@ -317,6 +323,51 @@ def test_chunked_stateful_reference_single_transition(monkeypatch):
                               np.ones_like(chunked["mom"][touched]))
     untouched = np.setdiff1d(np.arange(layout.total_rows), touched)
     np.testing.assert_array_equal(chunked["mom"][untouched], 1.0)
+
+
+@pytest.mark.parametrize("name", ["split_sgd", "momentum"])
+def test_chunked_reference_matches_fused_bitwise(monkeypatch, name):
+    """The batch-chunked reference sums each row's gradient over every
+    chunk in flat order and steps the row once, so it stays bitwise
+    equal to the fused kernel (which sums the same lookups in the same
+    order): a split_sgd row is rounded once per step, not once per
+    chunk."""
+    from jax.sharding import PartitionSpec as P
+    from repro import compat
+    from repro.core import sharded_embedding as se
+    from repro.core.embedding import EmbeddingSpec
+    from repro.launch.mesh import make_mesh
+
+    layout = se.make_layout(EmbeddingSpec((40, 30), 8), 1, "row")
+    opt = row.get(name)
+    rng = np.random.default_rng(3)
+    W = jnp.asarray(rng.standard_normal((layout.total_rows, 8)),
+                    jnp.float32)
+    idx = jnp.asarray(rng.integers(0, 5, (8, 2, 3)), jnp.int32)
+    dY = jnp.asarray(rng.standard_normal((8, 2, 8)), jnp.float32)
+    store = opt.init_store(W)
+    mesh = make_mesh((1, 1), ("data", "model"))
+    axes = ("data", "model")
+
+    def run(fused):
+        def f(st, idxj, dYj):
+            return se.apply_update(layout, st, opt, idxj, dYj, 0.05, axes,
+                                   fused=fused)
+        sm = jax.jit(compat.shard_map(
+            f, mesh=mesh,
+            in_specs=(jax.tree.map(lambda _: P(axes, None), store),
+                      P(None, None, None), P(None, None, None)),
+            out_specs=jax.tree.map(lambda _: P(axes, None), store),
+            check_vma=False))
+        return {k: np.asarray(v) for k, v in sm(store, idx, dY).items()}
+
+    fused = run(True)
+    # per-row bytes = S*P*E*4 = 192; a 200-byte budget forces 8 chunks
+    monkeypatch.setenv("REPRO_EMB_CHUNK_BUDGET", "200")
+    chunked = run(False)
+    for k in store:
+        np.testing.assert_array_equal(chunked[k].view(np.uint8),
+                                      fused[k].view(np.uint8), err_msg=k)
 
 
 # ---------------------------------------------------------------------------
@@ -503,18 +554,17 @@ def test_all_optimizers_through_pipeline():
         print(name, 'ROW_OK')
 
     # TABLE mode: padded-slot permute folded into the host sort.  The
-    # device-sort side runs the reference fallback on CPU (documented
-    # XLA-CPU interpret limitation in se.apply_update), so parity is
-    # BITWISE for split_sgd (reference == kernel by contract) and
-    # tolerance-close for the stateful fp32 kinds.
+    # device-sort side runs the same kernel on the device-sorted stream,
+    # so parity is BITWISE; split_sgd also matches the reference path
+    # (fused_update=False) bitwise, by contract.
     for name in ('split_sgd', 'adagrad_rowwise'):
         opt = row.get(name)
         res = {}
-        for presort in (False, True):
+        for presort, fused in ((False, True), (True, True), (False, False)):
             cfg = dataclasses.replace(
                 BASE, sparse_optimizer=name, emb_mode='table',
                 idx_input='sharded', host_presort=presort,
-                fused_update=True)
+                fused_update=fused)
             state, layout = init_state(jax.random.PRNGKey(0), cfg, mesh)
             step, _, _, _ = make_train_step(cfg, mesh)
             batch = dict(base_batch)
@@ -523,19 +573,15 @@ def test_all_optimizers_through_pipeline():
                               presort_batch(layout, idx).items()})
             for _ in range(2):
                 state, loss = step(state, batch)
-            res[presort] = (float(loss), emb_np(state))
+            res[(presort, fused)] = (float(loss), emb_np(state))
+        pairs = [((False, True), (True, True))]
         if name == 'split_sgd':
-            assert res[False][0] == res[True][0], (name, 'table loss')
-            for k in res[False][1]:
-                assert np.array_equal(res[False][1][k], res[True][1][k]), \\
-                    (name, k, 'table presort parity')
-        else:
-            assert abs(res[False][0] - res[True][0]) < 1e-5, (name,
-                                                             'table loss')
-            for k in res[False][1]:
-                np.testing.assert_allclose(res[False][1][k],
-                                           res[True][1][k],
-                                           rtol=1e-5, atol=1e-6)
+            pairs.append(((False, True), (False, False)))
+        for a, b in pairs:
+            assert res[a][0] == res[b][0], (name, a, b, 'table loss')
+            for k in res[a][1]:
+                assert np.array_equal(res[a][1][k], res[b][1][k]), \\
+                    (name, k, a, b, 'table parity')
         print(name, 'TABLE_OK')
     """)
     assert out.count("ROW_OK") == 8

@@ -12,11 +12,11 @@ Contracts under test:
   multi-step trajectory; changing the seed changes the stored state.
 * Trajectory: ``momentum_bf16`` stays within a pinned tolerance of fp32
   ``momentum`` over 50 steps on a zipf lookup stream.
-* Register-only flow: a toy optimizer registered HERE (its own Pallas
-  kernel body + reference hook, ``register()`` only) runs the pipelined
-  train step end-to-end with zero edits to ``kernels/ops.py``,
-  ``core/sharded_embedding.py`` or ``core/pipeline.py`` — and a source
-  scan proves those modules carry no per-optimizer dispatch to edit.
+* Register-only flow: a toy optimizer registered HERE (one row step,
+  ``register()`` only) runs the pipelined train step end-to-end with
+  zero edits to ``kernels/ops.py``, ``core/sharded_embedding.py`` or
+  ``core/pipeline.py`` — and a source scan proves those modules carry no
+  per-optimizer dispatch to edit.
 """
 
 import ast
@@ -234,8 +234,7 @@ def test_momentum_bf16_tracks_fp32_over_50_zipf_steps():
 # Register-only optimizer flow + source scan
 # ---------------------------------------------------------------------------
 
-def _toy_kernel_body(rows_ref, bags_ref, msk_ref, hp_ref, wgt_ref, w_ref,
-                     s_ref, dY_ref, nw_ref, ns_ref, acc_ref, flg_ref):
+def _toy_step(opt, blocks, g, lr, seed, rows):
     """Toy 'touch-count LR' rule: per touched row ``tc += 1``,
     ``w -= lr * g / sqrt(tc)`` — the frequency-adaptive shape that
     graduated into the first-class ``adagrad_freq`` optimizer and the
@@ -243,45 +242,13 @@ def _toy_kernel_body(rows_ref, bags_ref, msk_ref, hp_ref, wgt_ref, w_ref,
     cut down to a registration-flow probe.  The state key is ``tc`` (not
     ``cnt``) on purpose: ``cnt`` now has reserved generic bump semantics
     in ``apply_sparse`` and this toy owns its own counting."""
-    import jax.experimental.pallas as pl
-    from repro.kernels import embedding_update as ku
-    i = pl.program_id(0)
-    is_end = ku._accumulate_run(rows_ref, msk_ref, wgt_ref, dY_ref, acc_ref,
-                                flg_ref, i)
-
-    @pl.when(is_end)
-    def _apply():
-        live = flg_ref[0] != 0
-        s_old = s_ref[...].astype(jnp.float32)
-        s_new = s_old + 1.0
-        w_old = w_ref[...].astype(jnp.float32)
-        w_new = w_old - hp_ref[0] * acc_ref[...] / jnp.sqrt(s_new)
-        ns_ref[...] = jnp.where(live, s_new, s_old).astype(ns_ref.dtype)
-        nw_ref[...] = jnp.where(live, w_new, w_old).astype(nw_ref.dtype)
-
-
-def _toy_kernel(opt, store, srows, sbags, smsk, swgt, dY, lr, seed, e_real,
-                interpret):
-    from repro.kernels import embedding_update as ku
-    hp = jnp.stack([jnp.asarray(lr, jnp.float32),
-                    jnp.zeros((), jnp.float32),
-                    jnp.zeros((), jnp.float32)])
-    nw, ns = ku._stateful_call(_toy_kernel_body, store["w"], store["tc"],
-                               srows, sbags, smsk, swgt, dY, hp, interpret)
-    return {"w": nw, "tc": ns}
-
-
-def _toy_reference(opt, store, rep, summed, lr, seed):
-    W = store["w"]
-    safe = jnp.minimum(rep, W.shape[0] - 1)
-    s_new = jnp.take(store["tc"], safe, axis=0) + 1.0
-    w_new = jnp.take(W, safe, axis=0) - lr * summed / jnp.sqrt(s_new)
-    return {"w": W.at[rep].set(w_new),
-            "tc": store["tc"].at[rep].set(s_new)}
+    w, tc = blocks
+    s_new = tc + 1.0
+    return w - lr * g / jnp.sqrt(s_new), s_new
 
 
 def test_toy_optimizer_register_only_flow():
-    """Acceptance: a toy optimizer registered HERE — one kernel body +
+    """Acceptance: a toy optimizer registered HERE — one row step +
     ``register()`` — runs the pipelined train step end-to-end (fused
     kernel AND reference path), with NO edits to kernels/ops.py,
     core/sharded_embedding.py or core/pipeline.py."""
@@ -290,8 +257,7 @@ def test_toy_optimizer_register_only_flow():
     from repro.launch.mesh import make_mesh
 
     row.register(row.RowOptimizer(name="toy_counter", state=(("tc", 0),),
-                                  kernel=_toy_kernel,
-                                  reference=_toy_reference))
+                                  step=_toy_step))
     try:
         mesh = make_mesh((1, 1), ("data", "model"))
         rng = np.random.default_rng(0)
