@@ -74,16 +74,17 @@ from repro.optim import row as row_optim
 @dataclasses.dataclass(frozen=True)
 class Stage:
     """One named, composable piece of the hybrid step (runs INSIDE
-    shard_map).  ``comm`` labels the collective the stage issues —
-    introspection/debugging metadata only (the benchmark overlap model in
-    benchmarks/bench_comm_model.py is analytic and does not read it)."""
+    shard_map).  Its ops run under ``jax.named_scope(name)``, so every
+    instruction of the compiled step carries its stage in ``op_name`` —
+    trace-time metadata only, the compiled code is the same
+    (docs/telemetry.md, "Scopes in the compiled step")."""
 
     name: str
     fn: Callable
-    comm: str = ""
 
     def __call__(self, *args, **kwargs):
-        return self.fn(*args, **kwargs)
+        with jax.named_scope(self.name):
+            return self.fn(*args, **kwargs)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -263,8 +264,8 @@ def build_stages(mdef, mesh, layout) -> PipelineStages:
         return loss, g_dense, d_emb
 
     def dY_exchange(d_emb, seed=None, tag=0):
-        # seed = the per-step sr counter (None outside the train step,
-        # e.g. the stage profiler — the dither then keys off step 0);
+        # seed = the per-step sr counter (None when the state carries
+        # none — the dither then keys off step 0);
         # tag = the microbatch index, so no two payloads share a stream
         return se.gather_dY(layout, d_emb, emb_ax, replica_ax,
                             wire_dtype=ex_cfg.dY_dtype, seed=seed, tag=tag)
@@ -296,18 +297,13 @@ def build_stages(mdef, mesh, layout) -> PipelineStages:
                                  seed=seed)
         return {"hi": st2.hi, "lo": st2.lo_shard, "err": st2.err_shard}
 
-    ex_comm = ("all_gather(idx)" if mdef.idx_input == "sharded"
-               or mdef.emb_mode == "table" else "none")
-    fwd_comm = ("psum_scatter" if mdef.emb_mode == "row" else "all_to_all")
     return PipelineStages(
-        index_exchange=Stage("index_exchange", exchange, comm=ex_comm),
-        embedding_fwd=Stage("embedding_fwd", embedding_fwd, comm=fwd_comm),
-        dense_fwd_bwd=Stage("dense_fwd_bwd", dense_fwd_bwd, comm="none"),
-        dY_exchange=Stage("dY_exchange", dY_exchange,
-                          comm=("all_gather(dY)" if mdef.emb_mode == "row"
-                                else "all_to_all(dY)")),
-        sparse_update=Stage("sparse_update", sparse_update, comm="none"),
-        dense_update=Stage("dense_update", dense_update, comm="rs+ag"),
+        index_exchange=Stage("index_exchange", exchange),
+        embedding_fwd=Stage("embedding_fwd", embedding_fwd),
+        dense_fwd_bwd=Stage("dense_fwd_bwd", dense_fwd_bwd),
+        dY_exchange=Stage("dY_exchange", dY_exchange),
+        sparse_update=Stage("sparse_update", sparse_update),
+        dense_update=Stage("dense_update", dense_update),
     )
 
 
@@ -399,7 +395,10 @@ def make_pipelined_train_step(mdef, mesh, microbatches: int = 1):
 
     def step_local(state, batch):
         emb_store = state["emb"]
-        W_fwd = opt.fwd_weights(emb_store)
+        # the step's glue runs under the scope of the stage it serves, so
+        # (almost) no device time is left without a stage
+        with jax.named_scope("embedding_fwd"):
+            W_fwd = opt.fwd_weights(emb_store)
         dense_hi = state["dense"]["hi"]
         # per-step stochastic-rounding seed: a replicated int32 counter in
         # the train state (present when the optimizer registered
@@ -460,16 +459,18 @@ def make_pipelined_train_step(mdef, mesh, microbatches: int = 1):
                 # unchanged (write-through), so under hot_sync=
                 # 'allreduce' this is bitwise invisible.
                 cache = state["cache"]
-                hit, hot_bag = hot_cache.hot_bag_local(
-                    layout, cache["hot_w"], cache["hot_pos"], mb["idx"],
-                    mb.get("weights") if weighted else None)
-                emb_out = jnp.where(hit[..., None], hot_bag, emb_out)
+                with jax.named_scope("embedding_fwd"):
+                    hit, hot_bag = hot_cache.hot_bag_local(
+                        layout, cache["hot_w"], cache["hot_pos"], mb["idx"],
+                        mb.get("weights") if weighted else None)
+                    emb_out = jnp.where(hit[..., None], hot_bag, emb_out)
             loss, g_dense, d_emb = stages.dense_fwd_bwd(
                 dense_hi, emb_out, mb)
             dY = stages.dY_exchange(d_emb, seed=sr, tag=i)
-            loss_acc = loss if loss_acc is None else loss_acc + loss
-            g_acc = (g_dense if g_acc is None
-                     else jax.tree.map(jnp.add, g_acc, g_dense))
+            with jax.named_scope("dense_fwd_bwd"):
+                loss_acc = loss if loss_acc is None else loss_acc + loss
+                g_acc = (g_dense if g_acc is None
+                         else jax.tree.map(jnp.add, g_acc, g_dense))
             idx_parts.append(idx_upd)
             dY_parts.append(dY)
             if weighted:
@@ -481,21 +482,24 @@ def make_pipelined_train_step(mdef, mesh, microbatches: int = 1):
                 return parts[0]
             return jnp.take(jnp.concatenate(parts, axis=0), perm, axis=0)
 
-        idx_full, dY_full = restore(idx_parts), restore(dY_parts)
-        wgt_full = restore(wgt_parts) if weighted else None
+        with jax.named_scope("sparse_update"):
+            idx_full, dY_full = restore(idx_parts), restore(dY_parts)
+            wgt_full = restore(wgt_parts) if weighted else None
         new_emb = stages.sparse_update(emb_store, idx_full, dY_full,
                                        weights=wgt_full, presort=presort,
                                        seed=sr)
         new_dense = stages.dense_update(state["dense"], g_acc, seed=sr)
         new_state = {"emb": new_emb, "dense": new_dense}
         if sr is not None:
-            new_state["sr"] = sr + jnp.asarray(1, sr.dtype)
+            with jax.named_scope("dense_update"):
+                new_state["sr"] = sr + jnp.asarray(1, sr.dtype)
         if cache_on:
             # cache epilogue: promotion + mirror refresh read the POST-
             # update store, so an 'allreduce' mirror equals the cold
             # store entering the next step.
-            new_state["cache"] = hot_cache.step_cache(
-                mdef, layout, opt, state["cache"], new_emb, emb_ax)
+            with jax.named_scope("cache_epilogue"):
+                new_state["cache"] = hot_cache.step_cache(
+                    mdef, layout, opt, state["cache"], new_emb, emb_ax)
         if metrics_on:
             # metrics epilogue: accumulate this step's counters into the
             # replicated state["metrics"] vector.  Reads only the raw
@@ -503,37 +507,40 @@ def make_pipelined_train_step(mdef, mesh, microbatches: int = 1):
             # the forward consumed — and writes only its own slot, so
             # the training outputs are untouched (and with step_metrics
             # off, none of this exists in the lowered program).
-            idx_raw = batch["idx"]
-            if mdef.idx_input == "sharded":
-                # batch-sharded original-slot stream: every rank counts
-                # its own disjoint slice, psum makes it global
-                rows = jax.lax.psum(
-                    step_mx.valid_lookups(layout, idx_raw), all_axes)
-            elif mdef.emb_mode == "row":
-                # replicated stream: the local count IS the global count
-                rows = step_mx.valid_lookups(layout, idx_raw)
-            else:
-                # paper loader, table mode: padded-slot stream, slots
-                # sharded over 'model', batch over the rest — disjoint
-                # (row, slot) cells, so psum over everything is global
-                rows = jax.lax.psum(
-                    step_mx.valid_lookups_padded(layout, idx_raw, model),
-                    all_axes)
-            if bypass:
-                hl, hb = step_mx.cache_hit_counts(
-                    layout, state["cache"]["hot_pos"], idx_raw)
-                hit_lookups = jax.lax.psum(hl, all_axes)
-                skipped = jax.lax.psum(hb, all_axes)
-            else:
-                hit_lookups = jnp.float32(0)
-                skipped = jnp.float32(0)
-            bags = jnp.float32(mdef.batch * layout.num_orig_slots)
-            payload = (bags - skipped) * jnp.float32(mdef.spec.dim * 4)
-            new_state["metrics"] = state["metrics"] + step_mx.pack(
-                steps=1.0, hit_lookups=hit_lookups, skipped_bags=skipped,
-                bags=bags, rows_touched=rows,
-                exchange_payload_bytes=payload)
-        return new_state, jax.lax.psum(loss_acc, all_axes)
+            with jax.named_scope("metrics_epilogue"):
+                idx_raw = batch["idx"]
+                if mdef.idx_input == "sharded":
+                    # batch-sharded original-slot stream: every rank counts
+                    # its own disjoint slice, psum makes it global
+                    rows = jax.lax.psum(
+                        step_mx.valid_lookups(layout, idx_raw), all_axes)
+                elif mdef.emb_mode == "row":
+                    # replicated stream: the local count IS the global count
+                    rows = step_mx.valid_lookups(layout, idx_raw)
+                else:
+                    # paper loader, table mode: padded-slot stream, slots
+                    # sharded over 'model', batch over the rest — disjoint
+                    # (row, slot) cells, so psum over everything is global
+                    rows = jax.lax.psum(
+                        step_mx.valid_lookups_padded(layout, idx_raw, model),
+                        all_axes)
+                if bypass:
+                    hl, hb = step_mx.cache_hit_counts(
+                        layout, state["cache"]["hot_pos"], idx_raw)
+                    hit_lookups = jax.lax.psum(hl, all_axes)
+                    skipped = jax.lax.psum(hb, all_axes)
+                else:
+                    hit_lookups = jnp.float32(0)
+                    skipped = jnp.float32(0)
+                bags = jnp.float32(mdef.batch * layout.num_orig_slots)
+                payload = (bags - skipped) * jnp.float32(mdef.spec.dim * 4)
+                new_state["metrics"] = state["metrics"] + step_mx.pack(
+                    steps=1.0, hit_lookups=hit_lookups, skipped_bags=skipped,
+                    bags=bags, rows_touched=rows,
+                    exchange_payload_bytes=payload)
+        with jax.named_scope("dense_fwd_bwd"):
+            loss = jax.lax.psum(loss_acc, all_axes)
+        return new_state, loss
 
     step = compat.shard_map(step_local, mesh=mesh, in_specs=(specs, bspecs),
                             out_specs=(specs, P()), check_vma=False)

@@ -440,16 +440,17 @@ def _row_sorted_streams(layout: ShardedEmbeddingLayout, g_flat: jax.Array,
     (stateful kinds) under the kernel's liveness contract."""
     G = layout.total_rows
     R = layout.rows_per_shard
-    in_range = (g_flat >= 0) & (g_flat < G)
-    key = jnp.where(in_range, g_flat, G).astype(jnp.int32)
-    order = jnp.argsort(key)                 # stable: ties in flat order
-    skey = jnp.take(key, order)
-    bags = (order // pooling).astype(jnp.int32)
-    wgt = (jnp.ones(key.shape, jnp.float32) if weights_flat is None
-           else jnp.take(weights_flat.astype(jnp.float32), order))
-    local = skey - start
-    msk = ((skey < G) & (local >= 0) & (local < R)).astype(jnp.int32)
-    rows = jnp.clip(local, 0, R - 1)
+    with jax.named_scope("lookup_sort"):
+        in_range = (g_flat >= 0) & (g_flat < G)
+        key = jnp.where(in_range, g_flat, G).astype(jnp.int32)
+        order = jnp.argsort(key)             # stable: ties in flat order
+        skey = jnp.take(key, order)
+        bags = (order // pooling).astype(jnp.int32)
+        wgt = (jnp.ones(key.shape, jnp.float32) if weights_flat is None
+               else jnp.take(weights_flat.astype(jnp.float32), order))
+        local = skey - start
+        msk = ((skey < G) & (local >= 0) & (local < R)).astype(jnp.int32)
+        rows = jnp.clip(local, 0, R - 1)
     return rows, bags, msk, wgt
 
 

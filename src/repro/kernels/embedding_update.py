@@ -224,6 +224,9 @@ def _call(step, cols, slabs, rows, bags, msk, wgt, dY, lr, seed, ctl, cacc,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
         interpret=interpret,
+        # the kernel's name in the compiled step and the device trace
+        # (docs/telemetry.md: a new kernel brings its own name)
+        name="sparse_row_update",
     )(rows, bags, msk, wgt, lr, seed, ctl, *slabs, dY, cacc)
     return tuple(out[:n]), out[n], out[n + 1]
 
@@ -297,14 +300,15 @@ def sort_lookups(tgt: jax.Array, valid: jax.Array | None, num_rows: int,
     kernel above.  Only scalars are sorted; the [*, E] gradient data is
     never permuted or expanded.
     """
-    valid = ((tgt >= 0) & (tgt < num_rows)) if valid is None else (
-        valid & (tgt >= 0) & (tgt < num_rows))
-    key = jnp.where(valid, tgt, num_rows).astype(jnp.int32)
-    order = jnp.argsort(key)                      # stable: ties in flat order
-    sorted_key = jnp.take(key, order)
-    sorted_rows = jnp.minimum(sorted_key, num_rows - 1)
-    sorted_bags = (order // pooling).astype(jnp.int32)
-    sorted_msk = (sorted_key < num_rows).astype(jnp.int32)
-    sorted_wgt = (jnp.ones(tgt.shape, jnp.float32) if weights is None
-                  else jnp.take(weights.astype(jnp.float32), order))
+    with jax.named_scope("lookup_sort"):
+        valid = ((tgt >= 0) & (tgt < num_rows)) if valid is None else (
+            valid & (tgt >= 0) & (tgt < num_rows))
+        key = jnp.where(valid, tgt, num_rows).astype(jnp.int32)
+        order = jnp.argsort(key)                  # stable: ties in flat order
+        sorted_key = jnp.take(key, order)
+        sorted_rows = jnp.minimum(sorted_key, num_rows - 1)
+        sorted_bags = (order // pooling).astype(jnp.int32)
+        sorted_msk = (sorted_key < num_rows).astype(jnp.int32)
+        sorted_wgt = (jnp.ones(tgt.shape, jnp.float32) if weights is None
+                      else jnp.take(weights.astype(jnp.float32), order))
     return sorted_rows, sorted_bags, sorted_msk, sorted_wgt

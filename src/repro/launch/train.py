@@ -309,10 +309,12 @@ def parse_args(argv=None):
                     help="enable the process tracer (docs/telemetry.md): "
                          "writes <dir>/trace.json (Chrome trace-event "
                          "JSON, open in Perfetto), <dir>/heartbeat.jsonl "
-                         "(per-window train-loop heartbeats) and — unless "
-                         "--event-log points elsewhere — "
-                         "<dir>/events.jsonl; recsys archs append a "
-                         "per-stage pipeline profile to the trace")
+                         "(per-window train-loop heartbeats), a "
+                         "jax.profiler trace of the steps between the "
+                         "first and second heartbeat under <dir>/device/ "
+                         "(device ops, stage scopes and the same spans on "
+                         "one clock) and — unless --event-log points "
+                         "elsewhere — <dir>/events.jsonl")
     ap.add_argument("--step-metrics", action="store_true",
                     help="accumulate in-graph step metrics (cache hits, "
                          "rows touched, exchange payload bytes) in a "
@@ -503,18 +505,15 @@ def main():
                         skip_batch_budget=args.skip_batch_budget,
                         heartbeat_path=heartbeat_path,
                         heartbeat_every=args.metrics_every,
-                        metrics_every=args.metrics_every),
+                        metrics_every=args.metrics_every,
+                        device_trace_dir=(str(Path(args.trace_dir) / "device")
+                                          if args.trace_dir else None)),
         step, state, stream,
         state_shardings=shardings if args.ckpt_dir else None,
         batch_shardings=batch_shardings, faults=faults,
         event_log=event_log, step_hook=publisher, serve_stats=serve_stats)
     try:
         loop.run()
-        if args.trace_dir and profile_def is not None:
-            from repro.telemetry import stages as stage_profiler
-            print("[train] profiling pipeline stages (barrier mode)")
-            stage_profiler.profile_stages(profile_def,
-                                          tracer=telemetry.get_tracer())
         if args.serve_smoke:
             buckets = tuple(int(b) for b in args.serve_buckets.split(","))
             serve_smoke(profile_def, mesh, publisher,

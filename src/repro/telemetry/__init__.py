@@ -1,5 +1,5 @@
 """Unified telemetry: host-side tracing, in-graph step metrics, latency
-histograms, per-stage pipeline attribution (docs/telemetry.md).
+histograms (docs/telemetry.md).
 
 This package top level is STDLIB-ONLY (tracer + histogram) so the hot
 integration points — the loader worker, the checkpoint writer, the
@@ -8,8 +8,6 @@ pieces stay behind their submodules and import lazily:
 
 * :mod:`repro.telemetry.metrics` — the replicated in-graph metrics
   vector threaded through the pipelined train step;
-* :mod:`repro.telemetry.stages` — per-stage profiler for the pipeline's
-  Stage objects (spans + modeled bytes/flops);
 * :mod:`repro.telemetry.summarize` — offline trace analysis, also the
   ``python -m repro.telemetry summarize`` CLI.
 """

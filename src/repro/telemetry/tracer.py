@@ -7,8 +7,9 @@ Chrome trace-event JSON (the ``{"traceEvents": [...]}`` format), loadable
 in Perfetto (https://ui.perfetto.dev) or ``chrome://tracing``.  Each
 thread gets its own track (named after the thread, overridable with
 :meth:`Tracer.set_track`); spans emitted with an explicit ``track=`` land
-on a named VIRTUAL track instead (used for the per-stage pipeline
-profile, which runs on the main thread but reads as its own timeline).
+on a named VIRTUAL track instead (the fault log and the blocking
+checkpoint save, which run on the caller's thread but read as their own
+timeline).
 
 Design constraints, in order:
 
@@ -26,12 +27,19 @@ Timestamps are microseconds on the ``perf_counter`` clock, zeroed at
 tracer construction (Chrome trace viewers only care about relative time).
 The wall-clock epoch is recorded in the exported metadata for
 cross-referencing heartbeat / failure-log records.
+
+While enabled, every span is also a ``jax.profiler.TraceAnnotation`` of
+the same name and args (an instant a zero-length one), once the process
+has loaded JAX: a profiler trace then holds the program's spans on the
+calling thread, on the clock it shares with the device ops.  Whether a
+profiler is recording decides only whether the annotation is kept.
 """
 
 from __future__ import annotations
 
 import json
 import os
+import sys
 import threading
 import time
 from pathlib import Path
@@ -53,11 +61,20 @@ class _NoopSpan:
 _NOOP_SPAN = _NoopSpan()
 
 
+def _annotation(name: str, args: dict):
+    """The profiler annotation of one span, or None while JAX is not
+    loaded (never imported from here: the module stays stdlib-only)."""
+    profiler = getattr(sys.modules.get("jax"), "profiler", None)
+    if profiler is None:
+        return None
+    return profiler.TraceAnnotation(name, **args)
+
+
 class _Span:
     """One live span: records its own start, emits a complete ('X') event
     on exit.  Created only when the tracer is enabled."""
 
-    __slots__ = ("_tracer", "name", "cat", "args", "_tid", "_t0")
+    __slots__ = ("_tracer", "name", "cat", "args", "_tid", "_t0", "_ann")
 
     def __init__(self, tracer: "Tracer", name: str, cat: str, tid: int, args: dict):
         self._tracer = tracer
@@ -66,13 +83,19 @@ class _Span:
         self.args = args
         self._tid = tid
         self._t0 = 0.0
+        self._ann = None
 
     def __enter__(self):
+        self._ann = _annotation(self.name, self.args)
+        if self._ann is not None:
+            self._ann.__enter__()
         self._t0 = time.perf_counter()
         return self
 
     def __exit__(self, *exc):
         t1 = time.perf_counter()
+        if self._ann is not None:
+            self._ann.__exit__(None, None, None)
         tr = self._tracer
         ev = {
             "name": self.name,
@@ -175,6 +198,10 @@ class Tracer:
         """Zero-duration marker (failure-log events, preemptions, ...)."""
         if not self.enabled:
             return
+        ann = _annotation(name, args)
+        if ann is not None:
+            with ann:
+                pass
         ev = {
             "name": name,
             "ph": "i",

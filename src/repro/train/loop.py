@@ -37,6 +37,14 @@ allows signal handlers there): preemption is then requested via the
 Fault-injection hook point: ``train.step`` (inside the timed window, so
 injected stalls register as stragglers).  Recovery actions record
 structured events on the optional :class:`repro.faults.FailureLog`.
+
+Tracer spans per step (docs/telemetry.md): ``train/next_batch`` (the wait
+for the next batch), then ``train/step`` holding ``train/dispatch`` (the
+step call) and ``train/loss_fetch`` (the loss's device-to-host copy),
+each with ``step=``.  Every backend compile while :meth:`TrainLoop.run`
+lasts, on any thread of the process (JAX's monitoring listeners are
+process-wide, so a publisher's or a server's compiles count too), is a
+``train/compile`` instant and counts in the heartbeat's ``compiles``.
 """
 
 from __future__ import annotations
@@ -61,6 +69,8 @@ from repro.data.pipeline import ThreadedIterator
 from repro.faults.plan import NO_FAULTS, InjectedCrash
 
 _EXHAUSTED = object()
+# the JAX monitoring event of one backend compile (``jax._src.dispatch``)
+_BACKEND_COMPILE = "/jax/core/compile/backend_compile_duration"
 
 
 class PrefetchIterator:
@@ -149,6 +159,10 @@ class TrainLoopConfig:
     # is copied to host and emitted as a trace counter.  Only meaningful
     # when the model def set step_metrics=True.
     metrics_every: int = 10
+    # with heartbeats on: a jax.profiler trace of the steps between the
+    # first and the second heartbeat is written here (device ops, stage
+    # scopes and the tracer's spans on one clock); None = off
+    device_trace_dir: Optional[str] = None
 
 
 class StragglerMonitor:
@@ -245,6 +259,9 @@ class TrainLoop:
         self._owns_batches = cfg.prefetch > 0
         self._metrics_prev: Optional[dict] = None
         self._metrics_window: Optional[dict] = None
+        self._compiles = 0              # backend compiles since the heartbeat
+        self._compiles_lock = threading.Lock()   # listeners run on any thread
+        self._device_trace = "off" if cfg.device_trace_dir is None else "armed"
         if self.ckpt and self.ckpt.latest_valid_step() is not None:
             self.start_step, self.state = self.ckpt.restore(
                 self.state, shardings=state_shardings)
@@ -303,8 +320,11 @@ class TrainLoop:
         leaves the tail on disk."""
         from repro.telemetry import metrics as step_mx
 
+        with self._compiles_lock:
+            compiles, self._compiles = self._compiles, 0
         rec: dict = {"step": step, "t": time.time(),
-                     "skipped_batches": self.skipped_batches}
+                     "skipped_batches": self.skipped_batches,
+                     "compiles": compiles}
         if window:
             a = np.asarray(window, np.float64) * 1e3
             rec["window_steps"] = int(a.size)
@@ -333,6 +353,37 @@ class TrainLoop:
         telemetry.instant("train/heartbeat", cat="train", step=step)
         return rec
 
+    def _on_duration(self, event: str, secs: float, **_) -> None:
+        """JAX monitoring listener: count each backend compile (a compile
+        or a compile-cache load), on whatever thread compiled, and mark it
+        on the trace."""
+        if event == _BACKEND_COMPILE:
+            with self._compiles_lock:
+                self._compiles += 1
+            telemetry.instant("train/compile", cat="train", seconds=secs)
+
+    def _step_device_trace(self) -> None:
+        """At a heartbeat: start the profiler at the first, stop it at
+        the second (``cfg.device_trace_dir``; a run that ends before the
+        second stops it on its way out).  A profiler that fails, such as
+        one another session already holds, is recorded as an event and the
+        run goes on without the trace."""
+        if self._device_trace not in ("armed", "on"):
+            return
+        import jax
+        state, self._device_trace = self._device_trace, "done"
+        try:
+            if state == "armed":
+                opts = jax.profiler.ProfileOptions()
+                opts.python_tracer_level = 0     # it would slow the host path
+                jax.profiler.start_trace(self.cfg.device_trace_dir,
+                                         profiler_options=opts)
+                self._device_trace = "on"
+            elif state == "on":
+                jax.profiler.stop_trace()
+        except Exception as e:  # noqa: BLE001 — telemetry must not mask the run
+            self._record("device_trace_failed", error=repr(e))
+
     def run(self) -> Any:
         """Run to ``cfg.steps``, checkpointing every ``cfg.ckpt_every``
         completed steps.  The FINAL checkpoint is written in a ``finally``:
@@ -351,9 +402,11 @@ class TrainLoop:
                 "installed (Python restricts signal handling to the main "
                 "thread); preemption degrades to the _stop flag",
                 RuntimeWarning, stacklevel=2)
+        import jax
         tr = telemetry.get_tracer()
         tr.set_track("train_loop")
         hb_on = self.cfg.heartbeat_path is not None
+        jax.monitoring.register_event_duration_secs_listener(self._on_duration)
         window: list[float] = []
         completed = self.start_step
         crashed = False
@@ -363,7 +416,8 @@ class TrainLoop:
                     print(f"[train] preemption at step {step}; checkpointing")
                     self._record("preempted", step=step)
                     break
-                batch = self._next_batch()
+                with tr.span("train/next_batch", cat="train", step=step):
+                    batch = self._next_batch()
                 if batch is _EXHAUSTED:
                     print(f"[train] batch stream ended at step {step}")
                     self._record("stream_exhausted", step=step)
@@ -376,8 +430,10 @@ class TrainLoop:
                     else:
                         self._stop = True
                 with tr.span("train/step", cat="train", step=step):
-                    self.state, loss = self.step_fn(self.state, batch)
-                    loss = float(loss)
+                    with tr.span("train/dispatch", cat="train", step=step):
+                        self.state, loss = self.step_fn(self.state, batch)
+                    with tr.span("train/loss_fetch", cat="train", step=step):
+                        loss = float(loss)
                 dt = time.perf_counter() - t0
                 self.losses.append(loss)
                 window.append(dt)
@@ -395,6 +451,7 @@ class TrainLoop:
                 if hb_on and completed % self.cfg.heartbeat_every == 0:
                     self._heartbeat(completed, window)
                     window.clear()
+                    self._step_device_trace()
         except InjectedCrash:
             crashed = True  # simulated kill -9: no final checkpoint
             raise
@@ -415,6 +472,10 @@ class TrainLoop:
                             self._heartbeat(completed, window)
                 except Exception:  # noqa: BLE001 — telemetry must not mask the run
                     pass
+                jax.monitoring.unregister_event_duration_listener(
+                    self._on_duration)
+                if self._device_trace == "on":
+                    self._step_device_trace()
                 if self._owns_batches:
                     try:
                         self.batches.close()

@@ -110,3 +110,21 @@ def test_chip_smoke_refuses_without_a_tpu(tmp_path, alone):
                        capture_output=True, text=True, timeout=300)
     assert r.returncode != 0
     assert '"ok"' not in r.stdout
+
+
+def test_chip_smoke_serve_phase_runs_on_the_built_run():
+    """``chip_smoke.serve_phase`` on what ``build_dlrm`` returns, at the
+    reduced dlrm-small widths on the CPU: every bucket runs and the
+    server's scores equal ``make_score_step``'s, bitwise."""
+    import importlib.util
+
+    from repro.launch import train as T
+    spec = importlib.util.spec_from_file_location(
+        "chip_smoke", os.path.join(ROOT, "chip_smoke.py"))
+    smoke = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(smoke)
+    args = T.parse_args(["--arch", "dlrm-small", "--emb-mode", "table",
+                         "--batch", str(smoke.N_REQUESTS)])
+    mesh = T.local_mesh()
+    run = T.build_dlrm(args, mesh, jax.random.PRNGKey(0))
+    smoke.serve_phase(run, mesh, run.state)

@@ -1,14 +1,15 @@
-"""Telemetry stack: tracer, histogram, in-graph metrics, stage profile,
-summarize, heartbeat.  The load-bearing contracts:
+"""Telemetry stack: tracer, histogram, in-graph metrics, summarize,
+heartbeat.  The load-bearing contracts:
 
 * disabled tracer = shared no-op span, zero events (safe to leave wired
   into every hot path);
+* an enabled tracer's spans are host events of a profiler trace, on the
+  clock of the device ops;
 * ``step_metrics=True`` is bitwise invisible to training and its drained
   window reproduces the cache bench's hit-rate arithmetic exactly;
-* the stage profiler emits one span per pipeline stage with modeled
-  bytes/flops;
 * the train-loop heartbeat JSONL carries step percentiles, the straggler
-  snapshot, ingest stats and the metrics window.
+  snapshot, ingest stats, the metrics window and the compile count; the
+  loop spans the wait for a batch, the step call and the loss fetch.
 """
 
 import json
@@ -111,6 +112,44 @@ def test_global_configure_round_trip(tmp_path):
         telemetry.configure(enabled=False)
         tr.reset()
     assert telemetry.span("after") is _NOOP_SPAN
+
+
+def test_enabled_tracer_spans_are_profiler_host_events(tmp_path):
+    """While enabled, a span is also a profiler annotation of the same name
+    and args (an instant a zero-length one), so a device trace holds the
+    program's spans on its own clock; a disabled tracer adds nothing."""
+    import glob
+
+    import jax
+    from jax.profiler import ProfileData
+
+    on, off = Tracer(enabled=True), Tracer(enabled=False)
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+    try:
+        with on.span("train/step", cat="train", step=7):
+            with off.span("quiet/step", step=7):
+                jax.numpy.ones(8).block_until_ready()
+        on.instant("train/compile", seconds=0.25)
+        off.instant("quiet/compile")
+    finally:
+        jax.profiler.stop_trace()
+    (path,) = glob.glob(f"{tmp_path}/**/*.xplane.pb", recursive=True)
+    host: dict = {}
+    for plane in ProfileData.from_file(path).planes:
+        if plane.name.startswith("/host:"):
+            for line in plane.lines:
+                for e in line.events:
+                    host.setdefault(e.name, []).append(e)
+    (step,) = host["train/step"]
+    assert dict(step.stats)["step"] == 7 and step.duration_ns > 0
+    (mark,) = host["train/compile"]
+    assert dict(mark.stats)["seconds"] == 0.25
+    assert not any(name.startswith("quiet/") for name in host)
+    # the Chrome JSON export keeps the same span
+    assert [e["name"] for e in on.events() if e["ph"] == "X"] == ["train/step"]
+    assert off.events() == []
 
 
 # ---------------------------------------------------------------------------
@@ -329,44 +368,6 @@ def test_cache_hit_metrics_match_hot_bag_local():
 
 
 # ---------------------------------------------------------------------------
-# Stage profiler
-# ---------------------------------------------------------------------------
-
-
-def test_profile_stages_emits_all_six_stages():
-    from repro.core.dlrm import as_hybrid_def
-    from repro.telemetry import stages as stage_prof
-
-    tr = Tracer(enabled=True)
-    out = stage_prof.profile_stages(as_hybrid_def(_small_cfg()), tracer=tr,
-                                    steps=2, warmup=1)
-    expect = {"index_exchange", "embedding_fwd", "dense_fwd_bwd",
-              "dY_exchange", "sparse_update", "dense_update"}
-    assert set(out["stages"]) == expect
-    spans = [e for e in tr.events() if e.get("ph") == "X"]
-    assert {e["name"] for e in spans} == {f"stage/{s}" for s in expect}
-    assert all(e["args"]["modeled_bytes"] > 0 for e in spans)
-    for rec in out["stages"].values():
-        assert rec["ms"] > 0
-        assert rec["bytes"] > 0 and rec["modeled_us"] >= 0
-    # spans land on the virtual pipeline_stages track
-    meta = {e["args"]["name"] for e in tr.events() if e.get("ph") == "M"}
-    assert "pipeline_stages" in meta
-
-
-def test_modeled_stage_costs_cover_stages():
-    from repro.core.dlrm import as_hybrid_def
-    from repro.telemetry.stages import modeled_stage_costs
-
-    costs = modeled_stage_costs(as_hybrid_def(_small_cfg()))
-    assert {"index_exchange", "embedding_fwd", "dense_fwd_bwd",
-            "dY_exchange", "sparse_update", "dense_update"} <= set(costs)
-    for rec in costs.values():
-        assert rec["bytes"] >= 0 and rec["flops"] >= 0
-        assert rec["modeled_us"] >= 0
-
-
-# ---------------------------------------------------------------------------
 # Summarize
 # ---------------------------------------------------------------------------
 
@@ -500,3 +501,122 @@ def test_trainloop_emits_step_spans_and_closes_prefetch(tmp_path):
     finally:
         telemetry.configure(enabled=False)
         tr.reset()
+
+
+def test_trainloop_spans_each_leg_of_a_step_and_counts_compiles(tmp_path):
+    """``train/next_batch``, then ``train/dispatch`` and ``train/loss_fetch``
+    inside ``train/step``, each with ``step=``; a recompile forced by a
+    new batch shape counts in its heartbeat window's ``compiles``."""
+    import jax
+    import jax.numpy as jnp
+
+    from repro.train import TrainLoop, TrainLoopConfig
+
+    step = jax.jit(lambda s, b: (s + b.sum(), b.sum()))
+    # the batch widens at step 2: a compile in the second window too
+    batches = iter([np.ones(4, np.float32)] * 2 + [np.ones(5, np.float32)] * 4)
+    hb = tmp_path / "heartbeat.jsonl"
+    tr = telemetry.configure(enabled=True)
+    try:
+        TrainLoop(TrainLoopConfig(steps=6, prefetch=2, log_every=100,
+                                  heartbeat_path=str(hb), heartbeat_every=2),
+                  step, jnp.float32(0), batches).run()
+        evs = tr.events()
+    finally:
+        telemetry.configure(enabled=False)
+        tr.reset()
+    spans = [e for e in evs if e.get("ph") == "X"]
+    by_step = {e["args"]["step"]: e for e in spans if e["name"] == "train/step"}
+    for name in ("train/next_batch", "train/dispatch", "train/loss_fetch"):
+        got = [e for e in spans if e["name"] == name]
+        assert [e["args"]["step"] for e in got] == list(range(6)), name
+        if name != "train/next_batch":
+            for e in got:
+                outer = by_step[e["args"]["step"]]
+                assert outer["ts"] <= e["ts"]
+                assert e["ts"] + e["dur"] <= outer["ts"] + outer["dur"] + 1.0
+    recs = [json.loads(line) for line in hb.read_text().splitlines()]
+    assert [r["step"] for r in recs] == [2, 4, 6, 6]
+    assert recs[0]["compiles"] >= 1 and recs[1]["compiles"] >= 1
+    assert recs[2]["compiles"] == 0 and recs[3]["compiles"] == 0
+    marks = [e for e in evs if e["name"] == "train/compile"]
+    assert len(marks) == sum(r["compiles"] for r in recs)
+    assert all(e["args"]["seconds"] > 0 for e in marks)
+
+
+def test_trainloop_device_trace_holds_the_second_heartbeat_window(tmp_path):
+    """``device_trace_dir``: a profiler trace of the steps between the first
+    and the second heartbeat, with the loop's spans in it."""
+    import glob
+
+    from jax.profiler import ProfileData
+
+    from repro.train import TrainLoop, TrainLoopConfig
+
+    tr = telemetry.configure(enabled=True)
+    try:
+        TrainLoop(TrainLoopConfig(steps=7, log_every=100, heartbeat_every=2,
+                                  heartbeat_path=str(tmp_path / "hb.jsonl"),
+                                  device_trace_dir=str(tmp_path / "device")),
+                  lambda s, b: (s + b, float(b)), 0, iter(range(10))).run()
+    finally:
+        telemetry.configure(enabled=False)
+        tr.reset()
+    (path,) = glob.glob(f"{tmp_path}/device/**/*.xplane.pb", recursive=True)
+    steps = sorted(dict(e.stats)["step"]
+                   for plane in ProfileData.from_file(path).planes
+                   if plane.name.startswith("/host:")
+                   for line in plane.lines for e in line.events
+                   if e.name == "train/dispatch")
+    assert steps == [2, 3]
+
+
+def test_trainloop_device_trace_failure_is_recorded_and_the_run_goes_on(
+        tmp_path):
+    """A profiler session that is already running makes the loop's own
+    ``start_trace`` fail: the failure is an event and every step runs."""
+    import jax
+
+    from repro.faults import FailureLog
+    from repro.train import TrainLoop, TrainLoopConfig
+
+    log = FailureLog()
+    jax.profiler.start_trace(str(tmp_path / "outer"))
+    try:
+        loop = TrainLoop(
+            TrainLoopConfig(steps=6, log_every=100, heartbeat_every=2,
+                            heartbeat_path=str(tmp_path / "hb.jsonl"),
+                            device_trace_dir=str(tmp_path / "device")),
+            lambda s, b: (s + b, float(b)), 0, iter(range(10)),
+            event_log=log)
+        assert loop.run() == sum(range(6))
+    finally:
+        jax.profiler.stop_trace()
+    (failed,) = [e for e in log.events if e["kind"] == "device_trace_failed"]
+    assert failed["error"]
+    assert len(loop.losses) == 6
+
+
+def test_trainloop_counts_a_compile_on_another_thread(tmp_path):
+    """The compile count is process-wide: a compile that another thread
+    makes while the loop runs counts in that heartbeat window."""
+    import jax
+    import jax.numpy as jnp
+
+    from repro.train import TrainLoop, TrainLoopConfig
+
+    def step(state, batch):
+        if batch == 2:                 # a fresh function: a new compile
+            t = threading.Thread(
+                target=lambda: jax.jit(lambda x: x * 3 + 1)(jnp.ones(7)))
+            t.start()
+            t.join()
+        return state + batch, float(batch)
+
+    hb = tmp_path / "heartbeat.jsonl"
+    TrainLoop(TrainLoopConfig(steps=4, log_every=100, heartbeat_every=2,
+                              heartbeat_path=str(hb)),
+              step, 0, iter(range(10))).run()
+    recs = [json.loads(line) for line in hb.read_text().splitlines()]
+    assert [r["step"] for r in recs] == [2, 4, 4]
+    assert recs[0]["compiles"] == 0 and recs[1]["compiles"] >= 1
