@@ -5,59 +5,81 @@
 The embedding backward is NOT a gradient materialization: it is a scatter-SGD
 applied directly to the table.  The paper's CPU kernel walks the minibatch's
 rows and applies ``W[r] -= lr * sum(dY of bags touching r)`` in one pass; the
-TPU-native structure here is a ``PrefetchScalarGridSpec`` over the SORTED
-flat lookups:
+TPU-native structure here walks the SORTED flat lookups:
 
 * XLA side (cheap, O(L) on int32): sort the flat local row ids, so duplicate
   rows form contiguous runs and each touched row is visited exactly once.
-* The sorted row ids are scalar-prefetched and drive the slab DMA.  The
-  unit of HBM traffic is the ROW GROUP: the ``G`` consecutive rows of one
-  native TPU tile (8 rows for 32-bit slabs, 16 when any slab of the store
-  is 16-bit — bf16 ``hi``, uint16 ``lo``, bf16 state).  A single row of a
-  16-bit slab is not a legal DMA or block on the chip (the tiling packs
-  row pairs into one 32-bit sublane), so the kernel blocks whole groups:
-  a group is fetched when the sorted stream enters it, every run inside it
-  updates its row in VMEM, and the group is written back once when the
-  stream leaves it.  Consecutive runs in one group see each other's
-  writes because the output block stays resident while its index repeats.
-* Inside the kernel the duplicate contributions are accumulated in a VMEM
-  fp32 scratch (segment accumulation); at the run end the optimizer's row
-  step (the RowOptimizer ``step`` hook) runs elementwise on the group
-  block and a row mask keeps only the run's row.
+* The unit of HBM traffic on the tables is the ROW GROUP: the ``G``
+  consecutive rows of one native TPU tile (128 when any slab holds its
+  rows on lanes, else 8 rows for 32-bit slabs and 16 when any is 16-bit).
+  A single row of a 16-bit slab, or of a lanes-held slab, is not a legal
+  copy on the chip, so the kernel moves whole groups: the slabs stay in
+  HBM, each touched group is copied into VMEM once, while the stream is
+  still in the group before it (three buffers per slab: one being
+  updated, one loading, one writing back), and copied back once when the
+  stream leaves it.
+* Per group a [G, Wp] fp32 VMEM accumulator gathers every lookup's
+  contribution into the row it hits, in stream order from +0.0; lane
+  ``E`` counts the row's valid lookups.  When the stream leaves the group
+  the optimizer's row step (the RowOptimizer ``step`` hook) runs ONCE on
+  the whole [G, W] group block and is kept on the rows whose count is
+  nonzero; the transposes into and out of a slab's stored orientation
+  are once per group.
 * ``input_output_aliases`` makes the update in-place on the HBM table:
   groups holding no touched row are never read or written, the untouched
   rows of a touched group are written back with their own bits, and no
   dense ``dW`` or fp32 shard copy ever exists.
 
+The cotangent rows reach the kernel as one more XLA-side prep of the
+stream, per call: ``pre`` [C, Wp] holds each lookup's ``dY`` row times its
+bag weight (zero where masked) in lanes ``:E`` and its mask in lane ``E``,
+gathered in sorted order and streamed into VMEM in blocks of ``K``
+lookups by the grid's own pipeline.  A copy per lookup from inside the
+kernel (a ring of 8-row tile copies) measured 0.2 us a lookup on a TPU
+v5e, almost all of it the scalar work of issuing and picking each copy;
+``dY`` itself never goes whole into VMEM.
+
+Per call of ``C`` lookups: ``C / K`` grid steps.  Each grid step walks
+its block group by group: one binary search of the sorted rows per group
+finds where the group ends (and which group to prefetch), and the
+lookups inside it are a branch-free loop of one [1, Wp] add each.  So a
+step costs about L lookups' adds, one optimizer step per touched group,
+and L / K grid steps.
+
 Bytes per step (shard of M rows x E, L flat lookups, T touched groups of
-G rows, NB = L / pooling bags, ``s`` bytes per row over all slabs):
+G rows, NB = L / pooling bags, ``s`` bytes per row over all slabs, Wp
+lanes of ``pre``):
 
     path                         reads                       writes
     ------------------------------------------------------------------
     reference (segment_sum +     L*E*4 (grad expand)         M*s (new slab
     functional scatter)          + M*s (scatter copy-in)      copies)
-    fused (this kernel)          T*G*s + L*8*E*4 (dY group   T*G*s
-                                 per lookup)
+    fused (this kernel)          T*G*s + L*E*4 (gather)      T*G*s
+                                 + L*Wp*4 (pre)              + L*Wp*4 (pre)
 
 i.e. the fused path touches ``O(touched groups)`` slab data instead of
 ``O(M)`` — the bandwidth profile Hsia et al. (2020) identify as the
 dominant memory bottleneck of DLRM-class training.
 
-The sorted stream lives in SMEM (scalar prefetch), which holds a few
-hundred KiB, so a step's stream is cut into chunks of at most
-``CHUNK`` lookups, one kernel call each.  A run that crosses a chunk
-boundary hands its partial fp32 sum and liveness flag to the next call,
-which continues the SAME sequential accumulation — the result does not
-depend on where the cuts fall.
+Budgets: the sorted rows of a call live in SMEM (scalar prefetch, 4 B a
+lookup: ``CHUNK`` = 32,768 take 128 KiB), so a step's stream is cut into
+chunks, one kernel call each, each with its own [C, Wp] ``pre`` in HBM
+(16 MiB at Wp = 128).  VMEM holds three group buffers per slab
+(``3 * G * W`` elements each), the [G, Wp] accumulator and two [K, Wp]
+fp32 blocks of ``pre`` (1 MiB at K = 1024, Wp = 128).  A group that
+crosses a chunk boundary hands its [G, Wp] partial sums to the next
+call, which continues the SAME sequential accumulation and steps the
+group once the stream leaves it — the result does not depend on where
+the cuts fall.
 
 Numerics: duplicate contributions are pre-reduced in fp32 in sorted order —
 the same order ``jax.ops.segment_sum`` uses on sorted segments — and the
 step is applied once per row, so the split result is bit-identical to the
 ``dedup_rows`` + ``combine_split`` reference path
-(:func:`repro.optim.row.apply_rows_split_sgd`).  A run made ONLY of masked
-padding lookups (the sorted tail, other shards' rows) writes nothing:
-``beta * m`` is not a no-op the way ``w - lr * 0`` is, so every run carries
-a 1-word SMEM liveness flag and the row step runs only on live runs.
+(:func:`repro.optim.row.apply_rows_split_sgd`).  A row reached ONLY by
+masked padding lookups (the sorted tail, other shards' rows) keeps its
+bits: ``beta * m`` is not a no-op the way ``w - lr * 0`` is, so the step
+is kept only on rows with a nonzero lookup count.
 """
 
 from __future__ import annotations
@@ -68,9 +90,14 @@ from jax.experimental import pallas as pl
 from jax.experimental.layout import Layout
 from jax.experimental.pallas import tpu as pltpu
 
-# lookups per kernel call: rows/bags/msk/wgt are 4 x 4 B each per lookup
-# in SMEM, so 16384 lookups take 256 KiB of it
-CHUNK = 16384
+# lookups per kernel call: their sorted rows take 4 B each of SMEM (32768
+# take 128 KiB of it), and their ``pre`` rows Wp * 4 B each of HBM
+CHUNK = 32768
+# lookups per grid step (one [K, Wp] block of ``pre``)
+BLOCK = 1024
+# row-group buffers per slab: one being updated, one loading the next
+# group, one writing the previous group back
+GROUP_SLOTS = 3
 LANES = 128
 
 
@@ -112,123 +139,211 @@ def rows_on_lanes(shape, dtype, device=None) -> bool:
             > _round_up(W, sub) * _round_up(M, LANES))
 
 
-def _make_kernel(step, cols: tuple, G: int, Gd: int):
+def _make_kernel(step, cols: tuple, tiles: tuple, G: int, E: int, M: int,
+                 C: int, K: int):
     """The fused kernel body for row-aligned slabs (all read and written
     back; ``cols[k]`` says slab ``k`` arrives transposed, as [W, M]).
     ``step(blocks, g, lr, seed, rows) -> blocks`` is the optimizer's row
-    math on [G, W] group blocks (``g`` [1, E] is the run's pre-reduced
-    gradient, ``rows`` [G, 1] the group's row ids).
+    math on [G, W] group blocks (``g`` [G, E] the group's per-row
+    pre-reduced gradients, ``rows`` [G, 1] the group's row ids).
 
-    Scalar prefetch: sorted rows / bags / msk / wgt of this chunk, ``lr``
-    [1] fp32, ``sd`` [1] int32 seed, ``ctl`` [3] int32 = (last row of the
-    previous chunk or -1, first row of the next chunk or -1, liveness of
-    the run carried in)."""
+    Scalar prefetch: the sorted rows of this chunk of ``C`` lookups,
+    ``lr`` [1] fp32, ``sd`` [1] int32 seed, ``ctl`` [2] int32 = (last row
+    of the previous chunk or -1, first row of the next chunk or -1).
+    Grid step ``i`` walks lookups ``i*K .. i*K+K-1``, whose ``pre`` rows
+    are its VMEM block."""
+    S = GROUP_SLOTS
+    # a partial last group moves each slab's rows up to its own tile
+    # boundary, which lies inside the slab's padded memory
+    last, rem = divmod(M, G)
+    tails = tuple(min(G, _round_up(rem, t)) if rem else G for t in tiles)
+    search_steps = C.bit_length()
     n_slabs = len(cols)
 
-    def kernel(rows_ref, bags_ref, msk_ref, wgt_ref, lr_ref, sd_ref, ctl_ref,
-               *refs):
-        slabs = refs[:n_slabs]
-        dY_ref, cacc_ref = refs[n_slabs:n_slabs + 2]
-        outs = refs[n_slabs + 2:2 * n_slabs + 2]
-        oacc_ref, oflg_ref, acc_ref, flg_ref = refs[2 * n_slabs + 2:]
+    def kernel(rows_ref, lr_ref, sd_ref, ctl_ref, pre_ref, *refs):
+        # a slab and its output are one buffer (aliased): groups are read
+        # from the input and written to the output, each at most once a
+        # call, read before written
+        n = n_slabs
+        src, cacc_ref = refs[:n], refs[n]
+        dst, oacc_ref = refs[n + 1:2 * n + 1], refs[2 * n + 1]
+        bufs = refs[2 * n + 2:3 * n + 2]
+        acc_ref, sem, st = refs[3 * n + 2:]
         i = pl.program_id(0)
-        n = pl.num_programs(0)
-        row = rows_ref[i]
-        prev = jnp.where(i == 0, ctl_ref[0], rows_ref[jnp.maximum(i - 1, 0)])
-        nxt = jnp.where(i == n - 1, ctl_ref[1],
-                        rows_ref[jnp.minimum(i + 1, n - 1)])
 
-        # entering a group (or a new call): the output block is a fresh
-        # VMEM buffer, so seed it with the group's current HBM contents
-        @pl.when((i == 0) | (row // G != prev // G))
-        def _load_group():
-            for o, s in zip(outs, slabs):
-                o[...] = s[...]
+        def group_copy(g, slot, load: bool, start: bool):
+            """Start or wait the copies of row group ``g`` between HBM and
+            group buffer ``slot``, one per slab."""
+            def go(sizes):
+                for k, (b, c, size) in enumerate(zip(bufs, cols, sizes)):
+                    h = src[k] if load else dst[k]
+                    at = pl.ds(pl.multiple_of(g * G, G), size)
+                    h = h.at[:, at] if c else h.at[at, :]
+                    b = b.at[slot] if size == G else (
+                        b.at[slot, :, pl.ds(0, size)] if c
+                        else b.at[slot, pl.ds(0, size), :])
+                    cp = pltpu.make_async_copy(*((h, b) if load else (b, h)),
+                                               sem.at[k, slot])
+                    cp.start() if start else cp.wait()
 
-        @pl.when(row != prev)
-        def _start_run():
-            acc_ref[...] = jnp.zeros_like(acc_ref)
-            flg_ref[0] = 0
+            full = (G,) * n_slabs
+            if tails == full:
+                go(full)
+            elif not last:
+                go(tails)
+            else:
+                pl.when(g != last)(lambda: go(full))
+                pl.when(g == last)(lambda: go(tails))
 
-        @pl.when((i == 0) & (row == prev))
-        def _continue_run():
-            acc_ref[...] = cacc_ref[...]
-            flg_ref[0] = ctl_ref[2]
+        @pl.when(i == 0)
+        def _init():
+            # st[0]: the current group's buffer slot; st[1]: the current
+            # group; st[2]: the chunk index where it ends; st[3 + s]: the
+            # group being written back from slot s, or -1
+            st[0], st[1] = 0, -1
+            for s in range(S):
+                st[3 + s] = -1
 
-        # this lookup's cotangent row out of its bag's tile: a masked
-        # sublane sum is exact (one nonzero term; a -0.0 turning +0.0
-        # cannot change an accumulation that starts at +0.0)
-        pick = (jax.lax.broadcasted_iota(jnp.int32, dY_ref.shape, 0)
-                == bags_ref[i] % Gd)
-        g = jnp.sum(jnp.where(pick, dY_ref[...].astype(jnp.float32), 0.0),
-                    axis=0, keepdims=True)
-        acc_ref[...] += jnp.where(msk_ref[i] != 0, g * wgt_ref[i], 0.0)
-        flg_ref[0] = flg_ref[0] | msk_ref[i]
+        def enter_group(t, grp):
+            slot = st[0]
 
-        @pl.when((nxt != row) & (flg_ref[0] != 0))
-        def _apply():
-            rows = (row // G) * G + jax.lax.broadcasted_iota(
-                jnp.int32, (G, 1), 0)
-            cur = tuple(o[...].T if c else o[...] for o, c in zip(outs, cols))
-            new = step(cur, acc_ref[...], lr_ref[0], sd_ref[0], rows)
-            hit = rows == row
-            for o, c, x, v in zip(outs, cols, cur, new):
-                v = jnp.where(hit, v.astype(o.dtype), x)
-                o[...] = v.T if c else v
+            # nobody prefetched the call's first group
+            @pl.when(t == 0)
+            def _first_load():
+                group_copy(grp, slot, load=True, start=True)
 
-        # the last lookup of the call hands a continuing run to the next
-        @pl.when(i == n - 1)
-        def _carry_out():
+            group_copy(grp, slot, load=True, start=False)
+            carried = (t == 0) & (ctl_ref[0] // G == grp)
+
+            @pl.when(carried)
+            def _continue():
+                acc_ref[...] = cacc_ref[...]
+
+            @pl.when(jnp.logical_not(carried))
+            def _fresh():
+                acc_ref[...] = jnp.zeros_like(acc_ref)
+
+            # where the group ends (a binary search of the sorted rows);
+            # the next group loads into the next slot once that slot's
+            # write-back has landed
+            bound = (grp + 1) * G
+
+            def halve(_, lh):
+                lo, hi = lh
+                mid = (lo + hi) // 2
+                right = rows_ref[jnp.minimum(mid, C - 1)] < bound
+                return (jnp.where((lo < hi) & right, mid + 1, lo),
+                        jnp.where((lo < hi) & jnp.logical_not(right),
+                                  mid, hi))
+
+            end, _ = jax.lax.fori_loop(0, search_steps, halve, (t + 1, C))
+            st[1], st[2] = grp, end
+
+            @pl.when(end < C)
+            def _prefetch():
+                ns = (slot + 1) % S
+
+                @pl.when(st[3 + ns] >= 0)
+                def _landed():
+                    group_copy(st[3 + ns], ns, load=False, start=False)
+
+                st[3 + ns] = -1
+                group_copy(rows_ref[end] // G, ns, load=True, start=True)
+
+        def leave_group(grp):
+            # one optimizer step on the whole group, kept on the rows some
+            # valid lookup reached (a row reached only by masked lookups
+            # keeps its bits), then one write-back
+            slot = st[0]
+            rows = grp * G + jax.lax.broadcasted_iota(jnp.int32, (G, 1), 0)
+            cur = tuple(b[slot].T if c else b[slot]
+                        for b, c in zip(bufs, cols))
+            acc = acc_ref[...]
+            new = step(cur, acc[:, :E], lr_ref[0], sd_ref[0], rows)
+            hit = acc[:, E:E + 1] > 0
+            for b, c, x, v in zip(bufs, cols, cur, new):
+                v = jnp.where(hit, v.astype(b.dtype), x)
+                b[slot] = v.T if c else v
+            group_copy(grp, slot, load=False, start=True)
+            st[3 + slot] = grp
+            st[0] = (slot + 1) % S
+
+        def segment(j):
+            """The lookups of one group from block index ``j`` on; returns
+            the block index after them."""
+            t = i * K + j
+            grp = rows_ref[t] // G
+            pl.when(grp != st[1])(lambda: enter_group(t, grp))
+            end = st[2]
+            stop = jnp.minimum(end - i * K, K)
+            base = grp * G
+
+            def add(jj, carry):
+                r = rows_ref[i * K + jj] - base
+                acc_ref[pl.ds(r, 1), :] += pre_ref[pl.ds(jj, 1), :]
+                return carry
+
+            jax.lax.fori_loop(j, stop, add, 0)
+            # the stream leaves the group in this block, unless the next
+            # chunk goes on in it
+            leaves = (end <= i * K + K) & (
+                (end < C) | (ctl_ref[1] // G != grp))
+            pl.when(leaves)(lambda: leave_group(grp))
+            return stop
+
+        jax.lax.while_loop(lambda j: j < K, segment, 0)
+
+        @pl.when(i == pl.num_programs(0) - 1)
+        def _last_block():
+            # hand a group the next chunk goes on in to the next call, and
+            # let every write-back land
             oacc_ref[...] = acc_ref[...]
-            oflg_ref[0] = flg_ref[0]
+            for s in range(S):
+                pl.when(st[3 + s] >= 0)(
+                    lambda s=s: group_copy(st[3 + s], s, load=False,
+                                           start=False))
 
     return kernel
 
 
-def _call(step, cols, slabs, rows, bags, msk, wgt, dY, lr, seed, ctl, cacc,
-          interpret):
+def _call(step, cols, tiles, slabs, M, rows, pre, E, lr, seed, ctl, cacc,
+          K, interpret):
     """One kernel call on one chunk of the sorted stream (slabs already in
     their stored orientation)."""
     n = len(slabs)
-    # one group of rows for every slab: a lane tile when any slab holds
-    # its rows on lanes, else the tallest sublane tile
-    G = LANES if any(cols) else max(tile_rows(s.dtype) for s in slabs)
-    Gd = tile_rows(dY.dtype)
-    E = dY.shape[1]
-    slab_specs = [
-        pl.BlockSpec((s.shape[0], G), lambda i, rows, *_: (0, rows[i] // G))
-        if c else
-        pl.BlockSpec((G, s.shape[1]), lambda i, rows, *_: (rows[i] // G, 0))
-        for s, c in zip(slabs, cols)]
-    whole = pl.BlockSpec((1, E), lambda i, *_: (0, 0))
-    in_specs = slab_specs + [
-        pl.BlockSpec((Gd, E), lambda i, rows, bags, *_: (bags[i] // Gd, 0)),
-        whole]
-    out_specs = slab_specs + [whole, pl.BlockSpec(memory_space=pltpu.SMEM)]
+    G = cacc.shape[0]
+    C = rows.shape[0]
+    hbm = pl.BlockSpec(memory_space=pltpu.HBM)
+    whole = pl.BlockSpec(cacc.shape, lambda i, *_: (0, 0))
     out = pl.pallas_call(
-        _make_kernel(step, cols, G, Gd),
+        _make_kernel(step, cols, tiles, G, E, M, C, K),
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=7,
-            grid=(rows.shape[0],),
-            in_specs=in_specs,
-            out_specs=out_specs,
-            scratch_shapes=[pltpu.VMEM((1, E), jnp.float32),
-                            pltpu.SMEM((1,), jnp.int32)],
+            num_scalar_prefetch=4,
+            grid=(C // K,),
+            in_specs=([pl.BlockSpec((K, pre.shape[1]), lambda i, *_: (i, 0))]
+                      + [hbm] * n + [whole]),
+            out_specs=[hbm] * n + [whole],
+            scratch_shapes=[
+                pltpu.VMEM((GROUP_SLOTS, s.shape[0], G) if c else
+                           (GROUP_SLOTS, G, s.shape[1]), s.dtype)
+                for s, c in zip(slabs, cols)] + [
+                pltpu.VMEM(cacc.shape, jnp.float32),
+                pltpu.SemaphoreType.DMA((n, GROUP_SLOTS)),
+                pltpu.SMEM((3 + GROUP_SLOTS,), jnp.int32)],
         ),
         out_shape=([jax.ShapeDtypeStruct(s.shape, s.dtype) for s in slabs]
-                   + [jax.ShapeDtypeStruct((1, E), jnp.float32),
-                      jax.ShapeDtypeStruct((1,), jnp.int32)]),
-        # args: (rows, bags, msk, wgt, lr, sd, ctl, *slabs, dY, cacc):
-        # the slabs alias their outputs
-        input_output_aliases={7 + k: k for k in range(n)},
+                   + [jax.ShapeDtypeStruct(cacc.shape, cacc.dtype)]),
+        # args: (rows, lr, sd, ctl, pre, *slabs, cacc): the slabs alias
+        # their outputs
+        input_output_aliases={5 + k: k for k in range(n)},
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
         interpret=interpret,
         # the kernel's name in the compiled step and the device trace
         # (docs/telemetry.md: a new kernel brings its own name)
         name="sparse_row_update",
-    )(rows, bags, msk, wgt, lr, seed, ctl, *slabs, dY, cacc)
-    return tuple(out[:n]), out[n], out[n + 1]
+    )(rows, lr, seed, ctl, pre, *slabs, cacc)
+    return tuple(out[:n]), out[n]
 
 
 def sparse_row_update_pallas(step, slabs, sorted_rows, sorted_bags,
@@ -252,15 +367,35 @@ def sparse_row_update_pallas(step, slabs, sorted_rows, sorted_bags,
 
     Each slab is handed to the kernel in the orientation XLA:TPU stores
     it in (:func:`rows_on_lanes`), on every backend, so interpret mode
-    runs the same blocks as the chip."""
+    runs the same blocks as the chip.  On the chip a slab's rows are
+    padded in memory to whole tiles, and a partial last group is copied
+    up to that boundary; interpret mode has no such padding, so there the
+    slabs are padded to it around the call."""
     cols = tuple(rows_on_lanes(s.shape, s.dtype) for s in slabs)
     views = tuple(s.T if c else s for s, c in zip(slabs, cols))
+    # the tile of each slab along its rows, to a whole number of which
+    # XLA:TPU pads them in memory
+    tiles = tuple(LANES if c else tile_rows(s.dtype)
+                  for s, c in zip(slabs, cols))
+    M = slabs[0].shape[0]
+    if interpret:
+        views = tuple(
+            jnp.pad(v, ((0, 0), (0, _round_up(M, t) - M)) if c else
+                    ((0, _round_up(M, t) - M), (0, 0)))
+            for v, c, t in zip(views, cols, tiles))
+    # one group of rows for every slab: a lane tile when any slab holds
+    # its rows on lanes, else the tallest sublane tile
+    G = LANES if any(cols) else max(tile_rows(s.dtype) for s in slabs)
     L = sorted_rows.shape[0]
     C = min(CHUNK, L)
+    K = min(BLOCK, C)
+    C = _round_up(C, K)
     nc = -(-L // C)
     lr_arr = jnp.full((1,), lr, jnp.float32)
     sd = jnp.full((1,), seed, jnp.int32)
     E = dY.shape[1]
+    # the cotangent lanes, then the lookup count
+    Wp = _round_up(E + 1, LANES)
     # pad the stream to whole chunks with masked repeats of the last row
     # (the sort's maximum, so the stream stays ascending)
     pad = nc * C - L
@@ -271,18 +406,25 @@ def sparse_row_update_pallas(step, slabs, sorted_rows, sorted_bags,
     wgt = jnp.pad(sorted_wgt, (0, pad))
 
     def body(k, carry):
-        views, cacc, cflg = carry
+        views, cacc = carry
         take = lambda a: jax.lax.dynamic_slice_in_dim(a, k * C, C)  # noqa: E731
+        live = take(msk) != 0
+        g = jnp.take(dY, take(bags), axis=0).astype(jnp.float32)
+        g = jnp.where(live[:, None], g * take(wgt)[:, None], 0.0)
+        pre = jnp.concatenate(
+            [g, live[:, None].astype(jnp.float32),
+             jnp.zeros((C, Wp - E - 1), jnp.float32)], axis=1)
         prev = jnp.where(k == 0, -1, rows[jnp.maximum(k * C - 1, 0)])
         nxt = jnp.where(k == nc - 1, -1,
                         rows[jnp.minimum((k + 1) * C, nc * C - 1)])
-        ctl = jnp.stack([prev, nxt, cflg[0]]).astype(jnp.int32)
-        return _call(step, cols, views, take(rows), take(bags), take(msk),
-                     take(wgt), dY, lr_arr, sd, ctl, cacc, interpret)
+        ctl = jnp.stack([prev, nxt]).astype(jnp.int32)
+        return _call(step, cols, tiles, views, M, take(rows), pre, E, lr_arr,
+                     sd, ctl, cacc, K, interpret)
 
-    out, _, _ = jax.lax.fori_loop(
-        0, nc, body, (views, jnp.zeros((1, E), jnp.float32),
-                      jnp.zeros((1,), jnp.int32)))
+    out, _ = jax.lax.fori_loop(0, nc, body,
+                               (views, jnp.zeros((G, Wp), jnp.float32)))
+    if interpret:
+        out = tuple(v[:, :M] if c else v[:M] for v, c in zip(out, cols))
     return tuple(v.T if c else v for v, c in zip(out, cols))
 
 

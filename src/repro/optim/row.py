@@ -23,7 +23,7 @@ module is the plug-in point:
 
   - ``step``            — the row step: the optimizer's per-row math,
     elementwise over [n, W] row blocks of every slab.  The fused Pallas
-    kernel runs it on one tile-aligned row group per touched row; the
+    kernel runs it once on each touched tile-aligned row group; the
     reference runs the SAME function on the gathered unique rows with
     their per-row gradient sums, once per row per step (the chunked scan
     path accumulates across chunks first).
@@ -211,13 +211,13 @@ class RowOptimizer:
 
     ``step(opt, blocks, g, lr, seed, rows) -> blocks``
         the row step.  ``blocks``: one [n, W] block per slab, in
-        ``slab_keys`` order; ``g`` [n or 1, E] fp32 per-row gradient sums;
+        ``slab_keys`` order; ``g`` [n, E] fp32 per-row gradient sums;
         ``lr`` fp32 scalar; ``seed`` int32 stochastic-rounding seed;
         ``rows`` [n, 1] int32 row ids of the blocks.  Returns the new
         blocks, elementwise per row (a row's result must not depend on
         its neighbours: the fused kernel runs it on a whole tile-aligned
-        row group and keeps one row).  Applied exactly ONCE per touched
-        row per step, by the kernel and by the reference alike.
+        row group and keeps the touched rows).  Applied exactly ONCE per
+        touched row per step, by the kernel and by the reference alike.
     ``flat_reference(opt, store, tgt, grad, lr, seed) -> store``
         optional per-lookup reference (the stateless kinds' scatter
         semantics); ``None`` means dedup + ``step``.
@@ -438,7 +438,7 @@ class RowOptimizer:
 # ---------------------------------------------------------------------------
 # Built-in row steps.  Each is the optimizer's whole per-row math, written
 # elementwise over [n, W] row blocks so the SAME expression runs in the
-# fused kernel (on one tile-aligned row group, ``g`` [1, E]) and in the
+# fused kernel (on one tile-aligned row group, ``g`` [G, E]) and in the
 # reference (on the gathered unique rows, ``g`` [n, E]).  ``blocks`` and
 # the returned tuple follow ``RowOptimizer.slab_keys``; ``rows`` [n, 1]
 # are the blocks' row ids (the stochastic-rounding counter).

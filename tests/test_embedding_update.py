@@ -188,22 +188,18 @@ def test_sort_lookups_properties():
 # ---------------------------------------------------------------------------
 
 def test_weighted_split_matches_scaled_reference():
-    """Fused weighted update vs jitted reference on pre-scaled grads: the
-    kernel scales each lookup's dY row by its weight inside the sorted-
-    order pre-reduction.  The compiler contracts scale+accumulate into an
-    FMA (one rounding instead of two per lookup), so the weighted result
-    is within 1 ulp/step of the pre-scaled reference — NOT bitwise (the
-    unweighted path multiplies by exactly 1.0 and keeps its bit-identity
-    contract, enforced by the tests above).  Untouched rows stay bitwise
-    intact."""
+    """Fused weighted update vs jitted reference on pre-scaled grads: each
+    lookup's dY row is scaled by its weight before the kernel (one
+    rounding, as the reference's product), and the kernel only adds the
+    scaled rows in sorted order, so the result equals the pre-scaled
+    reference bitwise.  Untouched rows stay bitwise intact."""
     M, E_, L = 60, 16, 48
     W, hi, lo, tgt, dY = _mk(M, E_, L, 1, dup_vocab=7, seed=3)
     w = jnp.asarray(RNG.standard_normal(L).astype(np.float32))
     nh, nl = _fused_split(hi, lo, tgt, dY, 0.05, weights=w, pooling=1)
     rh, rl = _ref_split(hi, lo, tgt, dY * w[:, None], 0.05)
-    np.testing.assert_allclose(np.asarray(combine_split(nh, nl)),
-                               np.asarray(combine_split(rh, rl)),
-                               rtol=1e-6, atol=1e-6)
+    np.testing.assert_array_equal(np.asarray(combine_split(nh, nl)),
+                                  np.asarray(combine_split(rh, rl)))
     untouched = np.setdiff1d(np.arange(M), np.asarray(tgt))
     np.testing.assert_array_equal(
         np.asarray(combine_split(nh, nl))[untouched],
@@ -230,7 +226,7 @@ def test_weighted_fused_bag_update_matches_scatter():
 
 def test_weighted_split_bag_update():
     """bag_update_split with weights: pooled (P>1) weighted bags, fused vs
-    reference on the weighted grad expansion (1-ulp FMA tolerance)."""
+    reference on the weighted grad expansion, bitwise."""
     B, S, P, E_, M = 4, 2, 3, 8, 30
     W = jnp.asarray(RNG.standard_normal((M, E_)), jnp.float32)
     hi, lo = split_fp32(W)
@@ -241,9 +237,8 @@ def test_weighted_split_bag_update():
     grad = jnp.broadcast_to(dY[:, :, None, :], (B, S, P, E_)) \
         * w[..., None]
     rh, rl = _ref_split(hi, lo, g.reshape(-1), grad.reshape(-1, E_), 0.1)
-    np.testing.assert_allclose(np.asarray(combine_split(nh, nl)),
-                               np.asarray(combine_split(rh, rl)),
-                               rtol=1e-6, atol=1e-6)
+    np.testing.assert_array_equal(np.asarray(combine_split(nh, nl)),
+                                  np.asarray(combine_split(rh, rl)))
     untouched = np.setdiff1d(np.arange(M), np.asarray(g).ravel())
     np.testing.assert_array_equal(
         np.asarray(combine_split(nh, nl))[untouched],
@@ -330,40 +325,103 @@ def test_rows_on_lanes_follows_the_tpu_layout_rule():
     assert EU.rows_on_lanes((60, 32), jnp.bfloat16)
 
 
+# (CHUNK, BLOCK) of the cut stream: one call; calls of 5 lookups, one grid
+# step each (a run of about 7 lookups straddles three calls); calls of 24
+# lookups in grid steps of 4 (groups straddle grid steps and calls)
+_CUTS = {"whole": None, "calls": (5, 5), "blocks": (24, 4)}
+
+
+def _masked_stream(M, E_, L, P, seed=5):
+    """A duplicate-heavy stream over 13 rows 23 apart, some lookups masked
+    (``valid``): sorted to the tail on row M - 1, whose group nothing
+    else reaches.  The presorted stream also masks every lookup of row
+    276 in place, mid-stream, alone in its group.  Returns (W, tgt, dY,
+    valid of the reference, presorted stream of the kernel)."""
+    rng = np.random.default_rng(seed)
+    W = jnp.asarray(rng.standard_normal((M, E_)), jnp.float32)
+    tgt = jnp.asarray(rng.integers(0, 13, (L,)) * 23, jnp.int32)
+    dY = jnp.asarray(rng.standard_normal((L // P, E_)), jnp.float32)
+    valid = jnp.asarray(rng.random(L) > 0.2)
+    rows, bags, msk, wgt = EU.sort_lookups(tgt, valid, M, P)
+    assert int(jnp.sum((rows == 276) & (msk != 0))) > 0
+    msk = jnp.where(rows == 276, 0, msk)
+    return W, tgt, dY, valid & (tgt != 276), (rows, bags, msk, wgt)
+
+
+@pytest.mark.parametrize("cut", list(_CUTS))
 @pytest.mark.parametrize("name", ["split_sgd", "momentum", "momentum_bf16",
                                   "adagrad_rowwise"])
 @pytest.mark.parametrize("E_", [16, 128])
-def test_chunked_stream_matches_reference_bitwise(monkeypatch, name, E_):
-    """A stream cut into many kernel calls (runs straddling every cut,
-    carried partial sums) equals the one-call kernel AND the jitted
+def test_chunked_stream_matches_reference_bitwise(monkeypatch, name, E_,
+                                                  cut):
+    """The kernel on a stream cut into calls and grid steps (groups and
+    runs straddling every cut, carried partial sums) equals the jitted
     reference bitwise, in both slab orientations (E=16: every slab on
-    lanes; E=128: row-major weights beside a lanes-held [M, 1] slab)."""
+    lanes; E=128: row-major weights beside a lanes-held [M, 1] slab).  A
+    row reached only by masked lookups, in the tail's group or alone in a
+    group mid-stream, keeps its weights' and its state's bits."""
     from functools import partial
     from repro.optim import row
     opt = row.get(name)
     M, L, P = 300, 96, 3
-    W, _, _, tgt, dY = _mk(M, E_, L, P, dup_vocab=13, seed=5)
+    W, tgt, dY, valid, srt = _masked_stream(M, E_, L, P)
     store = opt.init_store(W)
     if opt.state:
-        # nonzero state, so the transition is not a degenerate first step
+        # nonzero state on every row the stream reaches, masked or not, so
+        # a step that should not run would change it
         store = opt.apply_sparse(store, row.SparseStream(
-            idx=tgt.reshape(-1, 1, P), dY=dY[:, None]), 0.05, seed=1)
-    srt = EU.sort_lookups(tgt, None, M, P)
+            idx=jnp.concatenate([tgt, jnp.full((P,), M - 1, jnp.int32)]
+                                ).reshape(-1, 1, P),
+            dY=jnp.concatenate([dY, dY[:1]])[:, None]), 0.05, seed=1)
+    if _CUTS[cut]:
+        monkeypatch.setattr(EU, "CHUNK", _CUTS[cut][0])
+        monkeypatch.setattr(EU, "BLOCK", _CUTS[cut][1])
     keys = opt.slab_keys
-
-    def kernel():
-        out = EU.sparse_row_update_pallas(partial(opt.step, opt),
-                                          [store[k] for k in keys], *srt,
-                                          dY, 0.05, 7, interpret=True)
-        return dict(zip(keys, out))
-
-    whole = kernel()
-    monkeypatch.setattr(EU, "CHUNK", 5)
-    cut = kernel()
+    got = dict(zip(keys, EU.sparse_row_update_pallas(
+        partial(opt.step, opt), [store[k] for k in keys], *srt, dY, 0.05, 7,
+        interpret=True)))
     want = jax.jit(lambda s: opt.apply_sparse(s, row.SparseStream(
-        idx=tgt.reshape(-1, 1, P), dY=dY[:, None]), 0.05, seed=7))(store)
+        idx=tgt.reshape(-1, 1, P), dY=dY[:, None],
+        valid=valid.reshape(-1, 1, P)), 0.05, seed=7))(store)
     for k in keys:
-        for got in (whole[k], cut[k]):
+        np.testing.assert_array_equal(
+            np.asarray(got[k]).view(np.uint8),
+            np.asarray(want[k]).view(np.uint8), err_msg=k)
+        # the masked-only rows kept their bits (the reference's claim too)
+        for r in (276, M - 1):
             np.testing.assert_array_equal(
-                np.asarray(got).view(np.uint8), np.asarray(want[k]).view(
-                    np.uint8), err_msg=k)
+                np.asarray(got[k][r]).view(np.uint8),
+                np.asarray(store[k][r]).view(np.uint8), err_msg=(k, r))
+
+
+def test_kernel_copies_race_free_under_the_tpu_interpreter(monkeypatch,
+                                                           capsys):
+    """The kernel's group and block copies under the TPU interpreter,
+    which counts DMA semaphores, moves data only when a copy is waited on
+    and reports reads and writes that race: no race, and every row of
+    every touched group equals the reference.  (That interpreter leaves
+    an aliased output's untouched groups unwritten, so the comparison is
+    one call's touched groups.)"""
+    from functools import partial
+    from jax.experimental.pallas import tpu as pltpu
+    from repro.optim import row
+    opt = row.get("momentum")
+    M, L, P = 300, 96, 3
+    W, tgt, dY, valid, srt = _masked_stream(M, 16, L, P)
+    store = opt.init_store(W)
+    monkeypatch.setattr(EU, "BLOCK", 8)
+    out = EU.sparse_row_update_pallas(
+        partial(opt.step, opt), [store[k] for k in opt.slab_keys], *srt, dY,
+        0.05, 7, interpret=pltpu.InterpretParams(
+            dma_execution_mode="on_wait", detect_races=True))
+    want = jax.jit(lambda s: opt.apply_sparse(s, row.SparseStream(
+        idx=tgt.reshape(-1, 1, P), dY=dY[:, None],
+        valid=valid.reshape(-1, 1, P)), 0.05, seed=7))(store)
+    assert "RACE DETECTED" not in capsys.readouterr().out
+    groups = np.unique(np.asarray(srt[0]) // EU.LANES)
+    rows = np.concatenate([np.arange(g * EU.LANES, min(M, (g + 1) * EU.LANES))
+                           for g in groups])
+    for k, o in zip(opt.slab_keys, out):
+        np.testing.assert_array_equal(
+            np.asarray(o)[rows].view(np.uint8),
+            np.asarray(want[k])[rows].view(np.uint8), err_msg=k)

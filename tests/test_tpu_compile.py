@@ -6,8 +6,8 @@ the chip's memory — at no chip time.
 * every registered RowOptimizer's fused sparse update, at dlrm-small
   widths (one chip's 8 x 1M rows, E=64, 8192 x 8 x 50 lookups), and the
   entry that sorts the lookups on the device: the kernel is a
-  ``tpu_custom_call``, it updates the store in place, and it copies no
-  slab;
+  ``tpu_custom_call`` whose grid steps each walk a block of lookups, it
+  updates the store in place, and it copies no slab;
 * one whole dlrm-small train step per placement mode, with the kernel
   compiled (not interpreted), fitting one chip's 16 GiB, and reading
   every slab in place; its op_names carry the pipeline's stage scopes,
@@ -100,6 +100,22 @@ def _kernel_modules(text) -> list[bytes]:
             re.findall(r"\\22body\\22: \\22([A-Za-z0-9+/=]+)", text)]
 
 
+def _kernel_grids(jaxpr) -> list[tuple]:
+    """The grid of every Pallas call in a (closed) jaxpr, nested ones
+    included."""
+    from jax.extend import core
+    jaxpr = getattr(jaxpr, "jaxpr", jaxpr)
+    grids = []
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            grids.append(tuple(eqn.params["grid_mapping"].grid))
+        for v in eqn.params.values():
+            for sub in v if isinstance(v, (list, tuple)) else (v,):
+                if isinstance(sub, (core.Jaxpr, core.ClosedJaxpr)):
+                    grids += _kernel_grids(sub)
+    return grids
+
+
 def test_rows_on_lanes_models_the_compiler_layout(topo):
     """Off the chip the kernel's slab orientation comes from a model of
     XLA:TPU's default layout; on the chip it is that layout.  Both agree
@@ -163,7 +179,10 @@ def test_kernel_cache_key_holds_no_checkout_path(one_chip):
 
 @pytest.mark.parametrize("name", row.names())
 def test_fused_update_compiles_at_dlrm_small_widths(one_chip, name):
-    """Every registered optimizer's kernel, on the pre-sorted stream."""
+    """Every registered optimizer's kernel, on the pre-sorted stream.  A
+    grid step walks a block of ``BLOCK`` lookups: the grid of a call of
+    ``CHUNK`` lookups is ``CHUNK / BLOCK``, not one step per lookup."""
+    from repro.kernels import embedding_update as EU
     from repro.kernels import ops
     opt = row.get(name)
 
@@ -177,11 +196,13 @@ def test_fused_update_compiles_at_dlrm_small_widths(one_chip, name):
         ops.fused_row_update_presorted(opt, st, rows, bags, msk, wgt, dY,
                                        lr, seed=3, interpret=False),
         donate_argnums=(0,))
-    compiled = update.lower(
-        store, sds((L,), jnp.int32), sds((L,), jnp.int32),
-        sds((L,), jnp.int32), sds((L,), jnp.float32),
-        sds((L // POOLING, E), jnp.float32), sds((), jnp.float32)).compile()
-    _assert_in_place(compiled, store)
+    args = (store, sds((L,), jnp.int32), sds((L,), jnp.int32),
+            sds((L,), jnp.int32), sds((L,), jnp.float32),
+            sds((L // POOLING, E), jnp.float32), sds((), jnp.float32))
+    assert _kernel_grids(update.trace(*args).jaxpr) == [
+        (EU.CHUNK // EU.BLOCK,)]
+    assert EU.CHUNK // EU.BLOCK < EU.CHUNK
+    _assert_in_place(update.lower(*args).compile(), store)
 
 
 def test_sorting_entry_compiles_at_dlrm_small_widths(one_chip):

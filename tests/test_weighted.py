@@ -127,8 +127,7 @@ def test_zero_weight_slot_freezes_its_table():
 def test_weighted_presort_bakes_weights():
     """host_presort + weighted: the loader bakes bag weights into
     psort_wgt and the presorted step tracks the weighted reference step
-    (same kernel-vs-reference tolerance as the unweighted fp32 contract;
-    the Split-SGD weighted kernel is documented 1-ulp vs pre-scaled)."""
+    (same kernel-vs-reference tolerance as the unweighted fp32 contract)."""
     from repro.data.pipeline import presort_batch
     mesh = _mesh()
     rng = np.random.default_rng(3)
