@@ -45,6 +45,10 @@ class DLRMConfig:
     pooling: int                    # P look-ups per table (paper's P)
     batch: int = 2048               # global minibatch
     emb_mode: str = "row"           # 'row' | 'table'  (C3 placement)
+    # the chips of the deployment this config is one chip's share of
+    # (configs/dlrm_paper.chip_share: tables sliced row-wise, batch / N);
+    # 1 = the whole model
+    deployment_chips: int = 1
     # sparse RowOptimizer for the embedding path (repro/optim/row.py):
     # 'sgd' | 'split_sgd' | 'momentum' | 'adagrad_rowwise' | 'adagrad' |
     # 'momentum_bf16' | 'adagrad_bf16' (compressed bf16-hi state +

@@ -299,14 +299,19 @@ def init_state(key, mdef: HybridDef, mesh):
     scale = 1.0 / np.sqrt(np.mean(mdef.spec.table_rows))
     W = jax.random.uniform(ke, (layout.total_rows, mdef.spec.dim),
                            jnp.float32, -scale, scale)
+    opt = row_optim.resolve(mdef)
+    hot_rows = getattr(mdef, "hot_rows", 0)
+    # one compiled split, and the fp32 table freed before the MLPs are
+    # made: eager bit ops would hold three table-sized temporaries (a
+    # chip's share of dlrm-large is a 6 GB table)
+    emb = jax.jit(opt.init_store, static_argnames="counters")(
+        W, counters=hot_rows > 0)
+    del W
     dense = mdef.init_dense(kd)
     ex_cfg = resolve_exchange(mdef)
     arrays = dp.dp_global_arrays(dense, ns_total,
                                  compress=ex_cfg.needs_err,
                                  num_buckets=ex_cfg.num_buckets)
-    opt = row_optim.resolve(mdef)
-    hot_rows = getattr(mdef, "hot_rows", 0)
-    emb = opt.init_store(W, counters=hot_rows > 0)
     state = {"emb": emb, "dense": {"hi": arrays["hi"], "lo": arrays["lo"],
                                    "err": arrays["err"]}}
     if opt.stochastic_round or ex_cfg.needs_sr:

@@ -138,16 +138,17 @@ def reduced_dlrm(name: str, batch: int):
 
 def dlrm_config(args):
     """The DLRMConfig a run trains: with ``--paper`` the registered paper
-    config (``configs/dlrm_paper.py``) at its published widths and batch
-    (``--batch`` overrides the batch), else the reduced one; then the
-    run's optimizer / pipeline flags."""
+    config (``configs/dlrm_paper.py``) at its published widths and batch,
+    or with ``--share-of N`` one chip's share of it deployed over N chips
+    (``--batch`` overrides the batch either trains), else the reduced
+    one; then the run's optimizer / pipeline flags."""
     if args.paper:
         from repro.configs import dlrm_paper
         make = {"dlrm-small": dlrm_paper.dlrm_small,
                 "dlrm-large": dlrm_paper.dlrm_large,
                 "dlrm-mlperf": dlrm_paper.dlrm_mlperf}[args.arch]
-        cfg = make(mode=args.emb_mode or "row",
-                   **({} if args.batch is None else {"batch": args.batch}))
+        cfg = make(mode=args.emb_mode or "row", batch=args.batch,
+                   share_of=args.share_of)
     else:
         cfg = reduced_dlrm(args.arch, args.batch or 256)
         if args.emb_mode:
@@ -169,6 +170,12 @@ def build_dlrm(args, mesh, key):
     from repro.core import dlrm as D
     from repro.data.synthetic import dlrm_stream
     cfg = dlrm_config(args)
+    # what the run stands for, on the trace beside its compiles
+    telemetry.instant("train/config", cat="train", arch=cfg.name,
+                      deployment_chips=cfg.deployment_chips,
+                      rows_per_table=list(cfg.table_rows), batch=cfg.batch,
+                      lookups_per_step=(cfg.batch * len(cfg.table_rows)
+                                        * cfg.pooling))
     state, layout = D.init_state(key, cfg, mesh)
     step, shardings, bspecs, _ = D.make_train_step(cfg, mesh)
     if args.data_format == "packed":
@@ -227,10 +234,18 @@ def parse_args(argv=None):
                     help="embedding placement of a dlrm arch: row-wise "
                          "sharded rows or the paper's table-wise "
                          "placement (default: the config's own, row)")
+    ap.add_argument("--share-of", type=int, default=1, metavar="N",
+                    help="with --paper: train one chip's share of the "
+                         "config deployed row-wise over N chips — every "
+                         "table's rows divided evenly over them (this "
+                         "chip holds one slice of each), the dense half "
+                         "data-parallel at the published minibatch / N; "
+                         "every width as published, no exchange")
     ap.add_argument("--steps", type=int, default=50)
     ap.add_argument("--batch", type=int, default=None,
                     help="global batch (default: 256 for the reduced "
-                         "archs, the published batch with --paper)")
+                         "archs, the published batch with --paper, the "
+                         "published batch / N with --share-of N)")
     ap.add_argument("--seq", type=int, default=128)
     ap.add_argument("--lr", type=float, default=None,
                     help="learning rate (default: 0.05 for the reduced "
@@ -349,6 +364,17 @@ def parse_args(argv=None):
                          "dlrm-small | dlrm-large | dlrm-mlperf")
     if args.emb_mode and not args.arch.startswith("dlrm"):
         raise SystemExit("--emb-mode places the embeddings of a dlrm arch")
+    if args.share_of != 1:
+        if not args.paper:
+            raise SystemExit("--share-of takes a share of a paper config: "
+                             "add --paper")
+        if args.emb_mode == "table":
+            raise SystemExit("--share-of holds a row-wise slice of every "
+                             "table: use --emb-mode row")
+        try:
+            dlrm_config(args)
+        except ValueError as e:
+            raise SystemExit(f"--share-of {args.share_of}: {e}") from None
     return args
 
 
@@ -388,7 +414,10 @@ def main():
         stream, smoke_stream = run.stream, run.smoke_stream
         profile_def = run.profile_def
         n_params = cfg.spec.total_rows * cfg.emb_dim
-        print(f"[train] {args.arch}: ~{n_params/1e6:.1f}M embedding params")
+        share = (f" (one chip's share of {cfg.deployment_chips})"
+                 if cfg.deployment_chips > 1 else "")
+        print(f"[train] {args.arch}: ~{n_params/1e6:.1f}M embedding "
+              f"params{share}")
     elif args.arch in ("fm", "bst", "sasrec", "din"):
         from repro.core import hybrid as H
         from repro.data.synthetic import hybrid_stream

@@ -425,3 +425,70 @@ def test_kernel_copies_race_free_under_the_tpu_interpreter(monkeypatch,
         np.testing.assert_array_equal(
             np.asarray(o)[rows].view(np.uint8),
             np.asarray(want[k])[rows].view(np.uint8), err_msg=k)
+
+
+def _kernel_call(jaxpr):
+    """The first Pallas call's equation in a (closed) jaxpr, nested ones
+    included."""
+    for eqn in getattr(jaxpr, "jaxpr", jaxpr).eqns:
+        if eqn.primitive.name == "pallas_call":
+            return eqn
+        for v in eqn.params.values():
+            for sub in v if isinstance(v, (list, tuple)) else (v,):
+                if hasattr(sub, "eqns") or hasattr(sub, "jaxpr"):
+                    found = _kernel_call(sub)
+                    if found is not None:
+                        return found
+    return None
+
+
+@pytest.mark.parametrize("cut", list(_CUTS))
+def test_e256_row_major_groups_of_16_match_reference_bitwise(monkeypatch,
+                                                             cut):
+    """dlrm-large's width, E=256: the bf16 and 16-bit slabs are row-major,
+    a group is G=16 rows (one 16-bit tile) and ``pre`` is Wp=384 lanes
+    wide (the count lane opens a third lane tile).  Duplicate runs on
+    both sides of group boundaries, a partial last group, and the stream
+    cut into calls and grid steps (groups straddling both): the kernel
+    equals the jitted reference bitwise, and untouched rows keep their
+    bits."""
+    from functools import partial
+    from repro.optim import row
+    opt = row.get("split_sgd")
+    M, E_, L, P = 300, 256, 96, 3
+    rng = np.random.default_rng(11)
+    W = jnp.asarray(rng.standard_normal((M, E_)), jnp.float32)
+    hi, lo = split_fp32(W)
+    # rows on both sides of the boundaries of groups 0|1, 1|2, 2|3 and
+    # 17|18; the last group (rows 288..299) holds 12 of its 16 rows
+    near = np.array([0, 15, 16, 17, 31, 32, 47, 48, 100, 287, 288, 299])
+    tgt = jnp.asarray(near[rng.integers(0, near.size, L)], jnp.int32)
+    dY = jnp.asarray(rng.standard_normal((L // P, E_)), jnp.float32)
+    assert not EU.rows_on_lanes((M, E_), jnp.bfloat16)
+    assert not EU.rows_on_lanes((M, E_), jnp.uint16)
+    if _CUTS[cut]:
+        monkeypatch.setattr(EU, "CHUNK", _CUTS[cut][0])
+        monkeypatch.setattr(EU, "BLOCK", _CUTS[cut][1])
+    srt = EU.sort_lookups(tgt, None, M, P)
+
+    def update(hi, lo):
+        return EU.sparse_row_update_pallas(partial(opt.step, opt), [hi, lo],
+                                           *srt, dY, 0.05, 7, interpret=True)
+
+    call = _kernel_call(jax.make_jaxpr(update)(hi, lo))
+    gm = call.params["grid_mapping"]
+    vmem = [(tuple(a.shape), str(a.dtype)) for a in gm.scratch_avals
+            if str(a.dtype) in ("bfloat16", "uint16", "float32")]
+    assert vmem == [((3, 16, E_), "bfloat16"), ((3, 16, E_), "uint16"),
+                    ((16, 384), "float32")]
+    assert gm.block_mappings[0].block_shape[-1].block_size == 384
+    nh, nl = update(hi, lo)
+    grad = jnp.take(dY, jnp.arange(L) // P, axis=0)
+    rh, rl = _ref_split(hi, lo, tgt, grad, 0.05)
+    for got, want, old in ((nh, rh, hi), (nl, rl, lo)):
+        np.testing.assert_array_equal(np.asarray(got).view(np.uint8),
+                                      np.asarray(want).view(np.uint8))
+        untouched = np.setdiff1d(np.arange(M), near)
+        np.testing.assert_array_equal(
+            np.asarray(got)[untouched].view(np.uint8),
+            np.asarray(old)[untouched].view(np.uint8))

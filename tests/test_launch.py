@@ -45,6 +45,59 @@ def test_paper_flag_rejects_archs_without_a_paper_config():
         T.parse_args(["--arch", "fm", "--emb-mode", "table"])
 
 
+def test_share_of_builds_one_chips_share_at_published_widths():
+    """``--share-of 64``: one chip's share of dlrm-large deployed row-wise
+    over 64 chips, 93,750 rows of each of the 64 tables at E=256 and a
+    batch of 16,384 / 64; every other size as registered."""
+    from repro.configs import dlrm_paper
+    from repro.launch import train as T
+    cfg = T.dlrm_config(T.parse_args(
+        ["--arch", "dlrm-large", "--paper", "--emb-mode", "row",
+         "--share-of", "64"]))
+    whole = dlrm_paper.dlrm_large()
+    assert cfg.table_rows == (93_750,) * 64 and cfg.emb_dim == 256
+    assert cfg.batch == 256 and cfg.deployment_chips == 64
+    assert whole.table_rows == (6_000_000,) * 64 and whole.batch == 16_384
+    assert whole.deployment_chips == 1
+    assert (cfg.num_dense, cfg.bottom, cfg.top, cfg.pooling, cfg.emb_mode,
+            cfg.lr) == (2048, (2048,) * 7 + (256,), (4096,) * 16, 100,
+                        "row", whole.lr)
+    assert cfg.top_sizes == [2336, *(4096,) * 16, 1]
+    assert dataclasses.replace(cfg, table_rows=whole.table_rows,
+                               batch=whole.batch, deployment_chips=1,
+                               sr_seed=whole.sr_seed) == whole
+    # --batch sets the batch the share trains
+    half = T.dlrm_config(T.parse_args(
+        ["--arch", "dlrm-large", "--paper", "--share-of", "64",
+         "--batch", "128"]))
+    assert half.batch == 128 and half.table_rows == cfg.table_rows
+    # the registry's build carries the deployment in its meta
+    assert dlrm_paper.dlrm_large(share_of=64) == dataclasses.replace(
+        cfg, sr_seed=whole.sr_seed)
+
+
+@pytest.mark.parametrize("argv", [
+    ["--arch", "dlrm-large", "--share-of", "64"],            # no --paper
+    ["--arch", "dlrm-large", "--paper", "--share-of", "3"],  # rows, batch
+    ["--arch", "dlrm-large", "--paper", "--share-of", "0"],
+    ["--arch", "dlrm-small", "--paper", "--share-of", "5"],  # the batch
+    ["--arch", "dlrm-large", "--paper", "--share-of", "64",
+     "--emb-mode", "table"]])
+def test_share_of_refuses_what_it_cannot_build(argv):
+    from repro.launch import train as T
+    with pytest.raises(SystemExit):
+        T.parse_args(argv)
+
+
+def test_registry_meta_carries_the_deployment():
+    from repro.configs import base
+    from repro.configs import dlrm_paper  # noqa: F401 — registers them
+    from repro.launch.mesh import make_mesh
+    mesh = make_mesh((1, 1), ("data", "model"))
+    build = base.get("dlrm-small").build("train", mesh, batch=64)
+    assert build.meta["deployment_chips"] == 1
+
+
 def test_table_mode_stream_reads_padded_slots():
     """The synthetic stream of a table-mode run is in the padded slot
     order the step reads, the host twin of ``permute_indices``."""

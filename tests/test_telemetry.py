@@ -372,6 +372,34 @@ def test_cache_hit_metrics_match_hot_bag_local():
 # ---------------------------------------------------------------------------
 
 
+def test_build_marks_the_deployment_on_the_trace(tmp_path):
+    """``build_dlrm`` leaves a ``train/config`` instant: the deployment a
+    chip's share stands for, the rows it holds of each table, its batch
+    and its lookups a step."""
+    import jax
+    from repro.launch import train as T
+    from repro.launch.mesh import make_mesh
+    args = T.parse_args(["--arch", "dlrm-small", "--paper", "--emb-mode",
+                         "row", "--share-of", "64"])
+    tr = telemetry.configure(enabled=True, trace_dir=str(tmp_path))
+    try:
+        T.build_dlrm(args, make_mesh((1, 1), ("data", "model")),
+                     jax.random.PRNGKey(0))
+        path = tr.export()
+    finally:
+        telemetry.configure(enabled=False)
+        tr.reset()
+    with open(path) as f:
+        events = json.load(f)["traceEvents"]
+    marks = [e for e in events if e.get("name") == "train/config"]
+    assert len(marks) == 1 and marks[0]["ph"] == "i"
+    assert marks[0]["args"] == {
+        "arch": "dlrm-small", "deployment_chips": 64,
+        "rows_per_table": [15_625] * 8, "batch": 128,
+        "lookups_per_step": 128 * 8 * 50}
+    assert summarize(path)["instants"]["train/config"] == 1
+
+
 def test_summarize_round_trip(tmp_path):
     tr = Tracer(enabled=True, trace_dir=str(tmp_path))
     tr.set_track("train_loop")

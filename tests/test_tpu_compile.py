@@ -224,39 +224,47 @@ def test_sorting_entry_compiles_at_dlrm_small_widths(one_chip):
     _assert_in_place(compiled, store)
 
 
-@pytest.fixture(scope="module")
-def dlrm_small_step(topo):
-    """``mode -> (compiled, state structs)``: one dlrm-small train step per
-    placement mode compiled for the described chip, once per module."""
+def _described_step(topo, cfg):
+    """``(compiled, state structs, jaxpr)`` of ``cfg``'s train step with
+    the kernel compiled (not interpreted), for one described chip."""
     from jax.sharding import AxisType, Mesh, NamedSharding
-    from repro.configs.dlrm_paper import dlrm_small
     from repro.core import dlrm as D
     from repro.kernels import ops
     mesh = Mesh(np.asarray(topo.devices[:1]).reshape(1, 1),
                 ("data", "model"), axis_types=(AxisType.Auto,) * 2)
-    made = {}
 
     def placed(s, sh):
         return jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=sh)
 
+    cfg = dataclasses.replace(cfg, fused_update=True)
+    # the backend here is the CPU: steer the kernels to their compiled
+    # form, as on the chip
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setattr(ops, "_default_interpret", lambda: False)
+        step, shardings, bspecs, layout = D.make_train_step(cfg, mesh)
+        structs, _, _, _ = D.state_struct(cfg, mesh)
+        bstructs, _ = D.batch_struct(cfg, mesh, layout)
+        state = jax.tree.map(placed, structs, shardings)
+        batch = jax.tree.map(
+            lambda s, spec: placed(s, NamedSharding(mesh, spec)),
+            bstructs, bspecs, is_leaf=lambda x: isinstance(x, P))
+        traced = step.trace(state, batch)
+        return traced.lower().compile(), structs, traced.jaxpr
+
+
+@pytest.fixture(scope="module")
+def dlrm_small_step(topo):
+    """``mode -> (compiled, state structs)``: one dlrm-small train step per
+    placement mode compiled for the described chip, once per module."""
+    from repro.configs.dlrm_paper import dlrm_small
+    made = {}
+
     def get(mode):
-        if mode in made:
-            return made[mode]
-        cfg = dataclasses.replace(dlrm_small(mode=mode), fused_update=True)
-        assert (cfg.table_rows, cfg.emb_dim, cfg.pooling, cfg.batch) == (
-            (ROWS,) * TABLES, E, POOLING, BATCH)
-        # the backend here is the CPU: steer the kernels to their compiled
-        # form, as on the chip
-        with pytest.MonkeyPatch.context() as mp:
-            mp.setattr(ops, "_default_interpret", lambda: False)
-            step, shardings, bspecs, layout = D.make_train_step(cfg, mesh)
-            structs, _, _, _ = D.state_struct(cfg, mesh)
-            bstructs, _ = D.batch_struct(cfg, mesh, layout)
-            state = jax.tree.map(placed, structs, shardings)
-            batch = jax.tree.map(
-                lambda s, spec: placed(s, NamedSharding(mesh, spec)),
-                bstructs, bspecs, is_leaf=lambda x: isinstance(x, P))
-            made[mode] = (step.lower(state, batch).compile(), structs)
+        if mode not in made:
+            cfg = dlrm_small(mode=mode)
+            assert (cfg.table_rows, cfg.emb_dim, cfg.pooling, cfg.batch) == (
+                (ROWS,) * TABLES, E, POOLING, BATCH)
+            made[mode] = _described_step(topo, cfg)[:2]
         return made[mode]
 
     return get
@@ -294,8 +302,7 @@ def _named_exceptions(mode):
         # reshapes between stages): the call stack starts at whoever
         # lowered the step, ``<module>`` in a benchmark run, so it names
         # no function of the program
-        return not i.stack or i.stack[0] in (
-            "<module>", "dlrm_small_step.<locals>.get")
+        return not i.stack or i.stack[0] in ("<module>", "_described_step")
 
     ex = {
         "JAX glue, scoped but with no program frame": lambda n, o, i: (
@@ -381,3 +388,68 @@ def test_dlrm_small_step_carries_stage_scopes_and_kernel_name(
     assert not left, left
     # every exception listed still occurs: one that no longer does goes
     assert seen == set(exceptions), set(exceptions) - seen
+
+
+@pytest.fixture(scope="module")
+def dlrm_large_share_step(topo):
+    """One chip's share of dlrm-large over 64 chips (``--share-of 64``),
+    row mode: its train step compiled for the described chip."""
+    from repro.configs.dlrm_paper import dlrm_large
+    cfg = dlrm_large(mode="row", share_of=64)
+    assert (cfg.table_rows, cfg.emb_dim, cfg.pooling, cfg.batch) == (
+        (93_750,) * 64, 256, 100, 256)
+    return _described_step(topo, cfg)
+
+
+def _kernel_scratch(jaxpr) -> list[list[tuple]]:
+    """The VMEM scratch buffers (shape, dtype) of every Pallas call in a
+    (closed) jaxpr, nested ones included."""
+    from jax.extend import core
+    jaxpr = getattr(jaxpr, "jaxpr", jaxpr)
+    out = []
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            out.append([(tuple(a.shape), str(a.dtype)) for a in
+                        eqn.params["grid_mapping"].scratch_avals
+                        if str(a.dtype) in ("bfloat16", "uint16",
+                                            "float32")])
+        for v in eqn.params.values():
+            for sub in v if isinstance(v, (list, tuple)) else (v,):
+                if isinstance(sub, (core.Jaxpr, core.ClosedJaxpr)):
+                    out += _kernel_scratch(sub)
+    return out
+
+
+def test_dlrm_large_share_train_step_compiles_on_one_chip(
+        dlrm_large_share_step):
+    """The share fits the chip, the kernel is there and reads and writes
+    the row-major E=256 slabs in place (no whole-slab copy: the forward
+    gathers from them as stored), in row groups of G=16 with a
+    ``pre`` of Wp=384 lanes, in 50 calls of 32 grid steps."""
+    from repro.kernels import embedding_update as EU
+    compiled, structs, jaxpr = dlrm_large_share_step
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    assert _hbm_bytes(compiled) < HBM_BYTES
+    slabs = structs["emb"]
+    assert [(v.shape[1], v.dtype) for v in slabs.values()] == [
+        (256, jnp.bfloat16), (256, jnp.uint16)]
+    assert _slab_relayouts(text, slabs.values()) == []
+    assert _kernel_scratch(jaxpr) == [[((3, 16, 256), "bfloat16"),
+                                       ((3, 16, 256), "uint16"),
+                                       ((16, 384), "float32")]]
+    assert _kernel_grids(jaxpr) == [(EU.CHUNK // EU.BLOCK,)]
+
+
+def test_dlrm_large_share_step_carries_stage_scopes(dlrm_large_share_step):
+    """The stage scopes and the kernel's name, which the benchmark reads,
+    name dlrm-large's ops too."""
+    text = dlrm_large_share_step[0].as_text()
+    op_names = set(re.findall(r'op_name="([^"]*)"', text))
+    for scope in ("embedding_fwd", "dense_fwd_bwd", "sparse_update",
+                  "dense_update", "lookup_sort"):
+        assert any(f"/{scope}/" in o for o in op_names), scope
+    kernels = re.findall(r'%([\w.\-]+) = [^\n]*custom_call_target='
+                         r'"tpu_custom_call"', text)
+    assert kernels and all(k.startswith("sparse_row_update.")
+                           for k in kernels), kernels
