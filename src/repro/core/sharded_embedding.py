@@ -497,10 +497,13 @@ def apply_update(layout: ShardedEmbeddingLayout, store: dict, optimizer,
     bf16-hi state optimizers dither with it; deterministic optimizers
     ignore it) — this module stays per-optimizer-agnostic."""
     from repro.optim.row import SparseStream
+    # a row shard's sorted stream is the whole step's, the other shards'
+    # lookups masked: the kernel sizes its row groups by the live ones
+    shards = layout.num_shards if layout.mode == "row" else 1
     if presort is not None:
         return optimizer.apply_sparse(store, SparseStream(presort=presort,
                                                           dY=dY), lr,
-                                      seed=seed, fused=True)
+                                      seed=seed, fused=True, shards=shards)
     if layout.mode == "table" and replica_axes is not None:
         idx_local = jax.lax.all_gather(idx_local, replica_axes, axis=0,
                                        tiled=True)
@@ -521,7 +524,7 @@ def apply_update(layout: ShardedEmbeddingLayout, store: dict, optimizer,
             None if weights is None else weights.reshape(-1))
         return optimizer.apply_sparse(store, SparseStream(presort=streams,
                                                           dY=dY), lr,
-                                      seed=seed)
+                                      seed=seed, shards=shards)
     local, valid = _local_rows(layout, idx_local, axis_name)
     B, S, P = local.shape
     E = dY.shape[-1]

@@ -9,15 +9,20 @@ TPU-native structure here walks the SORTED flat lookups:
 
 * XLA side (cheap, O(L) on int32): sort the flat local row ids, so duplicate
   rows form contiguous runs and each touched row is visited exactly once.
-* The unit of HBM traffic on the tables is the ROW GROUP: the ``G``
-  consecutive rows of one native TPU tile (128 when any slab holds its
-  rows on lanes, else 8 rows for 32-bit slabs and 16 when any is 16-bit).
-  A single row of a 16-bit slab, or of a lanes-held slab, is not a legal
-  copy on the chip, so the kernel moves whole groups: the slabs stay in
-  HBM, each touched group is copied into VMEM once, while the stream is
-  still in the group before it (three buffers per slab: one being
-  updated, one loading, one writing back), and copied back once when the
-  stream leaves it.
+* The unit of HBM traffic on the tables is the ROW GROUP of ``G``
+  consecutive rows (:func:`group_rows`).  A single row of a 16-bit slab,
+  or of a lanes-held slab, is not a legal copy on the chip, so a group
+  is at least one native TPU tile: 128 rows when any slab holds its rows
+  on lanes, else 8 rows for 32-bit slabs and 16 when any is 16-bit.  A
+  store of row-major slabs takes wider groups, up to 128 rows, when the
+  stream touches nearly every tile group anyway: the wider groups then
+  copy hardly more rows, and the fixed cost of a group step (a binary
+  search, a wait on its load, a write-back started) is paid up to
+  sixteen times less often.  The kernel moves whole groups: the slabs
+  stay in HBM, each touched group is copied into VMEM once, while the
+  stream is still in the group before it (three buffers per slab: one
+  being updated, one loading, one writing back), and copied back once
+  when the stream leaves it.
 * Per group a [G, Wp] fp32 VMEM accumulator gathers every lookup's
   contribution into the row it hits, in stream order from +0.0; lane
   ``E`` counts the row's valid lookups.  When the stream leaves the group
@@ -84,6 +89,8 @@ is kept only on rows with a nonzero lookup count.
 
 from __future__ import annotations
 
+import math
+
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
@@ -99,6 +106,10 @@ BLOCK = 1024
 # group, one writing the previous group back
 GROUP_SLOTS = 3
 LANES = 128
+# the most rows, as a share of what groups of one tile copy, that wider
+# groups of a row-major store may copy more under uniform ids
+# (:func:`group_rows`)
+GROUP_COPY_SLACK = 0.05
 
 
 def tile_rows(dtype) -> int:
@@ -137,6 +148,43 @@ def rows_on_lanes(shape, dtype, device=None) -> bool:
     sub = tile_rows(dtype)
     return (_round_up(M, sub) * _round_up(W, LANES)
             > _round_up(W, sub) * _round_up(M, LANES))
+
+
+def group_rows(shapes, dtypes, live_lookups: float) -> int:
+    """Rows ``G`` of one row group of a store whose slabs (one [M, W]
+    ``shape`` and ``dtype`` each, as one shard holds them) a stream of
+    ``live_lookups`` valid lookups updates.
+
+    A lanes-held slab (:func:`rows_on_lanes`) makes it one lane tile,
+    128 rows.  Otherwise let ``t`` be the tallest sublane tile
+    (:func:`tile_rows`): ``G`` is the widest of t, 2t, 4t, ... 128 whose
+    groups would copy at most ``GROUP_COPY_SLACK`` (5%) more rows than
+    groups of ``t`` do.  Under uniform ids, groups of ``g`` rows copy
+    ``M * (1 - exp(-lam * g))`` rows, ``lam`` being the live lookups per
+    row.  So a dense stream, which touches nearly every tile group
+    already, takes wide groups for almost no bytes, and a sparse one
+    keeps the tile:
+
+    * dlrm-large's chip share (E=256, 16-bit slabs, 1,638,400 lookups on
+      6,000,128 rows, lam = 0.273): 16 rows copy 98.7% of the rows and
+      128 rows all of them, 1.3% more, so G = 128;
+    * a sparse stream such as dlrm-mlperf's Criteo tables at E=128
+      (lam far below 0.1): groups of 32 rows would already copy 20% or
+      more than groups of 16, so G = 16.
+
+    The slack sits between the two: 16-row tiles widen from
+    lam = ln 20 / 16 = 0.187 on.  ``live_lookups`` counts only the
+    lookups live on this shard; a masked lookup touches no row."""
+    if any(rows_on_lanes(s, d) for s, d in zip(shapes, dtypes)):
+        return LANES
+    t = max(tile_rows(d) for d in dtypes)
+    lam = live_lookups / shapes[0][0]
+    copied = lambda g: -math.expm1(-lam * g)  # noqa: E731  share of rows
+    G = t
+    while (lam > 0 and 2 * G <= LANES
+           and copied(2 * G) <= (1 + GROUP_COPY_SLACK) * copied(t)):
+        G *= 2
+    return G
 
 
 def _make_kernel(step, cols: tuple, tiles: tuple, G: int, E: int, M: int,
@@ -348,7 +396,8 @@ def _call(step, cols, tiles, slabs, M, rows, pre, E, lr, seed, ctl, cacc,
 
 def sparse_row_update_pallas(step, slabs, sorted_rows, sorted_bags,
                              sorted_msk, sorted_wgt, dY, lr, seed=0,
-                             interpret: bool = False) -> tuple:
+                             interpret: bool = False,
+                             shards: int = 1) -> tuple:
     """Fused sparse-backward + row-optimizer update, in place on ``slabs``.
 
     ``slabs``: the store's row-aligned [M, W] slabs (weights first, then
@@ -362,8 +411,12 @@ def sparse_row_update_pallas(step, slabs, sorted_rows, sorted_bags,
     cotangent.  ``sorted_wgt`` [L] fp32: per-lookup bag weight (1.0 for
     plain sum bags) scaling the cotangent row before the VMEM
     pre-reduction.  ``dY`` [NB, E].  ``seed``: int32 stochastic-rounding
-    seed handed to ``step``.  Returns the updated slabs; groups holding
-    no touched row are untouched (aliased buffers, no shard copy).
+    seed handed to ``step``.  ``shards``: the shards the stream's
+    lookups spread over (row placement hands every shard the whole
+    stream, the others' lookups masked), so that 1 / ``shards`` of them
+    count as live here when the row groups are sized (:func:`group_rows`).
+    Returns the updated slabs; groups holding no touched row are
+    untouched (aliased buffers, no shard copy).
 
     Each slab is handed to the kernel in the orientation XLA:TPU stores
     it in (:func:`rows_on_lanes`), on every backend, so interpret mode
@@ -383,10 +436,10 @@ def sparse_row_update_pallas(step, slabs, sorted_rows, sorted_bags,
             jnp.pad(v, ((0, 0), (0, _round_up(M, t) - M)) if c else
                     ((0, _round_up(M, t) - M), (0, 0)))
             for v, c, t in zip(views, cols, tiles))
-    # one group of rows for every slab: a lane tile when any slab holds
-    # its rows on lanes, else the tallest sublane tile
-    G = LANES if any(cols) else max(tile_rows(s.dtype) for s in slabs)
     L = sorted_rows.shape[0]
+    # one group of rows for every slab
+    G = group_rows([s.shape for s in slabs], [s.dtype for s in slabs],
+                   L / shards)
     C = min(CHUNK, L)
     K = min(BLOCK, C)
     C = _round_up(C, K)

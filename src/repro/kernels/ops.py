@@ -103,14 +103,15 @@ def _coerce_opt(opt):
 
 
 def _dispatch_row_kernel(opt, store, srows, sbags, smsk, swgt, dY, lr,
-                         seed, interpret):
+                         seed, interpret, shards=1):
     """Run the fused kernel with the optimizer's row step on its slabs.
     Any slab width goes to the chip as it is: the kernel's blocks span
     the full width, so no lane padding (and no slab copy) is needed."""
     keys = opt.slab_keys
     out = sparse_row_update_pallas(partial(opt.step, opt),
                                    [store[k] for k in keys], srows, sbags,
-                                   smsk, swgt, dY, lr, seed, interpret)
+                                   smsk, swgt, dY, lr, seed, interpret,
+                                   shards=shards)
     return dict(zip(keys, out))
 
 
@@ -146,21 +147,24 @@ def fused_row_update(opt, store, tgt, dY, lr, *, seed=0, valid=None,
                                 lr, seed, interpret)
 
 
-@partial(jax.jit, static_argnames=("opt", "interpret"))
+@partial(jax.jit, static_argnames=("opt", "interpret", "shards"))
 def fused_row_update_presorted(opt, store, srows, sbags, smsk, swgt, dY,
                                lr, *, seed=0,
-                               interpret: bool | None = None):
+                               interpret: bool | None = None,
+                               shards: int = 1):
     """:func:`fused_row_update` with the sort done ON THE HOST: the caller
     supplies the ``(sorted_rows, sorted_bags, sorted_msk, sorted_wgt)``
     arrays of ``sort_lookups`` (produced per shard by
     ``repro.data.pipeline.presort_batch`` while the previous step runs on
     device) and the per-step XLA argsort disappears from the hot path.
     Bit-identical to the sorting entry point — a stable sort's permutation
-    is unique, so host and device sorts agree exactly."""
+    is unique, so host and device sorts agree exactly.  ``shards``: the
+    shards the stream's lookups spread over (``sparse_row_update_pallas``),
+    1 for a stream of this shard's own lookups."""
     opt = _coerce_opt(opt)
     interpret = _default_interpret() if interpret is None else interpret
     return _dispatch_row_kernel(opt, store, srows, sbags, smsk, swgt, dY,
-                                lr, seed, interpret)
+                                lr, seed, interpret, shards)
 
 
 @partial(jax.jit, static_argnames=("interpret",))

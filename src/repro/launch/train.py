@@ -169,14 +169,22 @@ def build_dlrm(args, mesh, key):
     pipelined step, the batch placement and the batch source."""
     from repro.core import dlrm as D
     from repro.data.synthetic import dlrm_stream
+    from repro.kernels.embedding_update import group_rows
+    from repro.optim import row as row_optim
     cfg = dlrm_config(args)
-    # what the run stands for, on the trace beside its compiles
+    state, layout = D.init_state(key, cfg, mesh)
+    # what the run stands for, on the trace beside its compiles, and the
+    # row groups of the sparse-update kernel on one shard's slabs, sized
+    # by that shard's share of the step's lookups
+    lookups = cfg.batch * len(cfg.table_rows) * cfg.pooling
+    slabs = [state["emb"][k] for k in row_optim.resolve(cfg).slab_keys]
+    G = group_rows([(layout.rows_per_shard, s.shape[1]) for s in slabs],
+                   [s.dtype for s in slabs], lookups / layout.num_shards)
     telemetry.instant("train/config", cat="train", arch=cfg.name,
                       deployment_chips=cfg.deployment_chips,
                       rows_per_table=list(cfg.table_rows), batch=cfg.batch,
-                      lookups_per_step=(cfg.batch * len(cfg.table_rows)
-                                        * cfg.pooling))
-    state, layout = D.init_state(key, cfg, mesh)
+                      lookups_per_step=lookups, sparse_group_rows=G,
+                      sparse_groups=-(-layout.rows_per_shard // G))
     step, shardings, bspecs, _ = D.make_train_step(cfg, mesh)
     if args.data_format == "packed":
         stream = packed_stream(

@@ -306,7 +306,8 @@ class RowOptimizer:
     # ---------------------------------------------------------- apply --
     def apply_sparse(self, store: dict, stream: SparseStream, lr, *,
                      seed=None, fused: bool = False,
-                     interpret: Optional[bool] = None) -> dict:
+                     interpret: Optional[bool] = None,
+                     shards: int = 1) -> dict:
         """THE sparse update dispatcher: new store from one stream.
 
         ``fused=True`` (and always for pre-sorted streams) runs the Pallas
@@ -325,7 +326,12 @@ class RowOptimizer:
         optimizer math — so a declared-state counter (``adagrad_freq``)
         reads the post-bump count and an auxiliary counter (the hot-row
         cache's promotion signal) is carried through unchanged by hooks
-        that never see it."""
+        that never see it.
+
+        ``shards``: the shards a pre-sorted stream's lookups spread over
+        (row placement hands every shard the whole stream, the others'
+        lookups masked); the kernel sizes its row groups by the lookups
+        live here."""
         from repro.kernels import ops
         seed = jnp.asarray(0 if seed is None else seed, jnp.int32)
         num_rows = self.fwd_weights(store).shape[0]
@@ -359,7 +365,7 @@ class RowOptimizer:
             dYr = dY.reshape(-1, dY.shape[-1]) if dY.ndim != 2 else dY
             return _out(ops.fused_row_update_presorted(
                 self, store, *stream.presort, dYr, lr, seed=seed,
-                interpret=interpret))
+                interpret=interpret, shards=shards))
         idx, dY = stream.idx, stream.dY
         P = idx.shape[-1]
         E = dY.shape[-1]

@@ -442,26 +442,41 @@ def _kernel_call(jaxpr):
     return None
 
 
+# (rows M, rows the stream reaches) of the E=256 streams: dense, 96
+# lookups on 272 rows (lam = 0.35), on both sides of the boundaries of
+# 128-row groups 0|1 and 1|2 and of 16-row tiles, the last group (rows
+# 256..271) one whole 16-row tile; sparse, 96 lookups on 1,996 rows
+# (lam = 0.048), on both sides of 16-row groups 0|1, 1|2, 2|3 and
+# 123|124, the last group (rows 1984..1995) holding 12 of its 16 rows
+_DENSITIES = {
+    "dense": (272, [0, 1, 15, 16, 127, 128, 129, 200, 255, 256, 257, 271],
+              128),
+    "sparse": (1996, [0, 15, 16, 17, 31, 32, 47, 48, 100, 1983, 1984,
+                      1995], 16),
+}
+
+
+@pytest.mark.parametrize("density", list(_DENSITIES))
 @pytest.mark.parametrize("cut", list(_CUTS))
 def test_e256_row_major_groups_of_16_match_reference_bitwise(monkeypatch,
-                                                             cut):
-    """dlrm-large's width, E=256: the bf16 and 16-bit slabs are row-major,
-    a group is G=16 rows (one 16-bit tile) and ``pre`` is Wp=384 lanes
-    wide (the count lane opens a third lane tile).  Duplicate runs on
-    both sides of group boundaries, a partial last group, and the stream
-    cut into calls and grid steps (groups straddling both): the kernel
-    equals the jitted reference bitwise, and untouched rows keep their
-    bits."""
+                                                             cut, density):
+    """dlrm-large's width, E=256: the bf16 and 16-bit slabs are row-major
+    and ``pre`` is Wp=384 lanes wide (the count lane opens a third lane
+    tile).  A dense stream, which touches every 16-row tile group, runs
+    in groups of G=128 rows; a sparse one keeps G=16 (one 16-bit tile).
+    Duplicate runs on both sides of group boundaries, a partial last
+    group, and the stream cut into calls and grid steps (groups
+    straddling both): the kernel equals the jitted reference bitwise, and
+    untouched rows keep their bits."""
     from functools import partial
     from repro.optim import row
     opt = row.get("split_sgd")
-    M, E_, L, P = 300, 256, 96, 3
+    M, near, G = _DENSITIES[density]
+    E_, L, P = 256, 96, 3
     rng = np.random.default_rng(11)
     W = jnp.asarray(rng.standard_normal((M, E_)), jnp.float32)
     hi, lo = split_fp32(W)
-    # rows on both sides of the boundaries of groups 0|1, 1|2, 2|3 and
-    # 17|18; the last group (rows 288..299) holds 12 of its 16 rows
-    near = np.array([0, 15, 16, 17, 31, 32, 47, 48, 100, 287, 288, 299])
+    near = np.array(near)
     tgt = jnp.asarray(near[rng.integers(0, near.size, L)], jnp.int32)
     dY = jnp.asarray(rng.standard_normal((L // P, E_)), jnp.float32)
     assert not EU.rows_on_lanes((M, E_), jnp.bfloat16)
@@ -479,8 +494,8 @@ def test_e256_row_major_groups_of_16_match_reference_bitwise(monkeypatch,
     gm = call.params["grid_mapping"]
     vmem = [(tuple(a.shape), str(a.dtype)) for a in gm.scratch_avals
             if str(a.dtype) in ("bfloat16", "uint16", "float32")]
-    assert vmem == [((3, 16, E_), "bfloat16"), ((3, 16, E_), "uint16"),
-                    ((16, 384), "float32")]
+    assert vmem == [((3, G, E_), "bfloat16"), ((3, G, E_), "uint16"),
+                    ((G, 384), "float32")]
     assert gm.block_mappings[0].block_shape[-1].block_size == 384
     nh, nl = update(hi, lo)
     grad = jnp.take(dY, jnp.arange(L) // P, axis=0)
@@ -492,3 +507,59 @@ def test_e256_row_major_groups_of_16_match_reference_bitwise(monkeypatch,
         np.testing.assert_array_equal(
             np.asarray(got)[untouched].view(np.uint8),
             np.asarray(old)[untouched].view(np.uint8))
+
+
+# one shard's [M, W] slabs: dlrm-large's chip share (64 x 93,750 rows,
+# 64 x 100 x 256 lookups a step) and dlrm-small's (8 x 1M rows, E=64,
+# 8 x 50 x 8192 lookups); dlrm-mlperf's Criteo tables at E=128 (26
+# tables, 187,767,399 rows, 26 x 16,384 lookups)
+_LARGE = (6_000_128, 256)
+_SMALL = (8_000_000, 64)
+_MLPERF = (187_767_399, 128)
+_SPLIT = (jnp.bfloat16, jnp.uint16)
+
+
+@pytest.mark.parametrize("shape,dtypes,live,want", [
+    pytest.param(_LARGE, _SPLIT, 1_638_400, 128, id="dlrm-large"),
+    pytest.param(_SMALL, _SPLIT, 3_276_800, 128, id="e64-lanes"),
+    pytest.param(_SMALL, _SPLIT, 1_000, 128, id="e64-lanes-sparse"),
+    pytest.param(_MLPERF, _SPLIT, 26 * 16_384, 16, id="mlperf-e128"),
+    pytest.param(_LARGE, _SPLIT, 0, 16, id="no-live-lookups"),
+    # fp32 tiles are 8 rows: at dlrm-large's density 16 rows would copy
+    # 11% more than 8, so the tile stays; at one lookup a row it widens
+    pytest.param(_LARGE, (jnp.float32,), 1_638_400, 8, id="fp32-tile"),
+    pytest.param(_LARGE, (jnp.float32,), 6_000_128, 128, id="fp32-dense"),
+    # row placement over four shards: each is handed the whole stream of
+    # 96 lookups, a quarter of it live on its 272 rows (lam 0.088, where
+    # all 96 would make 0.35 and groups of 128)
+    pytest.param((272, 256), _SPLIT, 96 / 4, 16, id="row-shards-4"),
+])
+def test_group_rows(shape, dtypes, live, want):
+    """The row group of a store: a lane tile when any slab is held on
+    lanes, else the widest of the tile, twice it, ... 128 rows whose
+    groups copy at most 5% more rows than the tile's under uniform ids."""
+    assert EU.group_rows([shape] * len(dtypes), dtypes, live) == want
+
+
+def test_row_shards_divide_the_live_lookups():
+    """A row shard's stream is the whole step's, the other shards'
+    lookups masked: the kernel sizes its groups by the live ones.  The
+    dense E=256 stream runs in groups of 128 rows on one shard and of 16
+    when it is spread over four."""
+    from repro.optim import row
+    M, near, _ = _DENSITIES["dense"]
+    L, P = 96, 3
+    rng = np.random.default_rng(11)
+    store = row.get("split_sgd").init_store(
+        jnp.asarray(rng.standard_normal((M, 256)), jnp.float32))
+    tgt = jnp.asarray(np.array(near)[rng.integers(0, len(near), L)],
+                      jnp.int32)
+    srt = EU.sort_lookups(tgt, None, M, P)
+    dY = jnp.zeros((L // P, 256), jnp.float32)
+    for shards, G in ((1, 128), (4, 16)):
+        call = _kernel_call(jax.make_jaxpr(
+            lambda s: ops.fused_row_update_presorted(
+                "split_sgd", s, *srt, dY, 0.05, interpret=True,
+                shards=shards))(store))
+        assert call.params["grid_mapping"].scratch_avals[0].shape == (
+            3, G, 256), shards

@@ -374,8 +374,10 @@ def test_cache_hit_metrics_match_hot_bag_local():
 
 def test_build_marks_the_deployment_on_the_trace(tmp_path):
     """``build_dlrm`` leaves a ``train/config`` instant: the deployment a
-    chip's share stands for, the rows it holds of each table, its batch
-    and its lookups a step."""
+    chip's share stands for, the rows it holds of each table, its batch,
+    its lookups a step, and the sparse-update kernel's row groups (E=64
+    slabs are held on lanes: groups of 128 of the 8 x 15,632 padded
+    rows)."""
     import jax
     from repro.launch import train as T
     from repro.launch.mesh import make_mesh
@@ -396,7 +398,8 @@ def test_build_marks_the_deployment_on_the_trace(tmp_path):
     assert marks[0]["args"] == {
         "arch": "dlrm-small", "deployment_chips": 64,
         "rows_per_table": [15_625] * 8, "batch": 128,
-        "lookups_per_step": 128 * 8 * 50}
+        "lookups_per_step": 128 * 8 * 50, "sparse_group_rows": 128,
+        "sparse_groups": 8 * 15_632 // 128}
     assert summarize(path)["instants"]["train/config"] == 1
 
 
