@@ -32,7 +32,7 @@ dtype: an analytic per-rank wire-volume model at the 64 modeled ranks
 reduce-scatter share of Eq. 1 scale with the wire itemsize; the index
 stream, the fwd layout switch, and the always-bf16 weight all-gather do
 not), the Sect. VI overlap model re-run with the compressed exchange,
-and a compiled-HLO leg (``exchange_dtype`` threaded into the measured
+and a compiled-HLO leg (the wire dtype threaded into the measured
 subprocess) whose collective bytes shrink accordingly.  Paired rows land
 in the ``wire`` section next to ``wire_reduction_x`` — the modeled
 compressible-byte reduction vs the fp32 wire, an EXACT gate key
@@ -128,14 +128,17 @@ os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count={ranks}"
 import json, time, jax, jax.numpy as jnp, numpy as np
 from repro.core.dlrm import DLRMConfig, make_train_step, init_state
 from repro.core import sharded_embedding as se
+from repro.dist.exchange import ExchangeConfig
 from repro.launch.mesh import make_mesh
 from repro.launch.dryrun import parse_collective_bytes
 
 mesh = make_mesh((1, {ranks}), ("data", "model"))
+wire = {exdt}
 cfg = DLRMConfig(name="bench", num_dense=32, bottom=(64, 16), top=(64,),
                  table_rows=(2000,) * 8, emb_dim=16, pooling=5,
                  batch={batch}, emb_mode="table", microbatches={mb},
-                 exchange_dtype={exdt})
+                 exchange=(ExchangeConfig() if wire is None else
+                           ExchangeConfig(dY_dtype=wire, dense_dtype=wire)))
 step, shardings, bspecs, layout = make_train_step(cfg, mesh)
 state, _ = init_state(jax.random.PRNGKey(0), cfg, mesh)
 rng = np.random.default_rng(0)
@@ -165,10 +168,9 @@ print(json.dumps(dict(microbatches={mb}, measured_ms=measured_ms,
 
 
 def run_measured(ranks: int, batch: int, mb: int, dry_run: bool,
-                 exchange_dtype: str | None = None) -> dict:
+                 wire: str | None = None) -> dict:
     return _run_sub(SUB.format(ranks=ranks, batch=batch, mb=mb,
-                               dry_run=dry_run,
-                               exdt=repr(exchange_dtype)))
+                               dry_run=dry_run, exdt=repr(wire)))
 
 
 def _run_sub(code: str) -> dict:
@@ -291,7 +293,7 @@ def wire_rows(dtypes, ranks: int, batch: int, dry_run: bool,
               json_path: Path, chip=TPU_V5E):
     """Compressed exchange wire formats (repro/dist/exchange.py): analytic
     per-rank wire volume + the Sect. VI overlap model at each dtype, and a
-    compiled-HLO leg with ``exchange_dtype`` threaded into the subprocess.
+    compiled-HLO leg with the wire dtype threaded into the subprocess.
 
     The compressible volume is the bwd dY all_to_all share of Eq. 2 plus
     the dense-gradient reduce-scatter share of Eq. 1; the index stream,
@@ -331,7 +333,7 @@ def wire_rows(dtypes, ranks: int, batch: int, dry_run: bool,
         rec = model(wire_itemsize(dt))
         rec["modeled_overlap_speedup_x"] = (fp32_ref["modeled_overlap_s"]
                                             / rec["modeled_overlap_s"])
-        measured = run_measured(ranks, batch, 1, dry_run, exchange_dtype=dt)
+        measured = run_measured(ranks, batch, 1, dry_run, wire=dt)
         rec["collective_bytes"] = measured["collective_bytes"]
         rec["collective_counts"] = measured["collective_counts"]
         section[dt] = rec

@@ -35,7 +35,8 @@ import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count={ranks}"
 import json, jax
 from repro.configs.dlrm_paper import dlrm_small
-from repro.core.dlrm import make_train_step, state_struct, batch_struct
+from repro.core import hybrid as H
+from repro.core.dlrm import as_hybrid_def, make_train_step
 from repro.launch.mesh import make_mesh
 from repro.launch.dryrun import parse_collective_bytes
 import dataclasses
@@ -44,8 +45,8 @@ mesh = make_mesh((1, {ranks}), ("data", "model"))
 cfg = dataclasses.replace(dlrm_small(mode="table", batch={batch}),
                           microbatches={mb})
 step, shardings, bspecs, layout = make_train_step(cfg, mesh)
-sstructs, _, _, _ = state_struct(cfg, mesh)
-bstructs, _ = batch_struct(cfg, mesh, layout)
+sstructs, _, _, _ = H.state_struct(as_hybrid_def(cfg), mesh)
+bstructs, _ = H.batch_struct(as_hybrid_def(cfg), mesh, layout)
 # no jax.set_mesh here: the shard_mapped step carries its mesh explicitly
 compiled = step.lower(sstructs, bstructs).compile()
 ca = compiled.cost_analysis() or {{}}

@@ -22,6 +22,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.core import dlrm as D
+from repro.core import hybrid as H
 from repro.data.synthetic import dlrm_stream
 from repro.launch.mesh import make_mesh
 from repro.train import TrainLoop, TrainLoopConfig
@@ -56,7 +57,7 @@ def main():
         loop2.run()
         print(f"restored at step {loop2.start_step}, continued to 80 OK")
 
-    ev, _, _, _ = D.make_eval_step(cfg, mesh)
+    ev, _, _, _ = H.make_score_step(D.as_hybrid_def(cfg), mesh)
     batch = next(stream)
     scores = ev(state, batch)
     print(f"eval scores: shape {scores.shape}, "

@@ -10,8 +10,8 @@ deployed row-wise over N chips instead (:func:`chip_share`).
 import dataclasses
 
 from repro.configs.base import ArchDef, Cell, CellBuild, register
-from repro.core.dlrm import DLRMConfig, make_train_step, batch_struct, \
-    state_struct
+from repro.core import hybrid as H
+from repro.core.dlrm import DLRMConfig, as_hybrid_def, make_train_step
 from repro.configs.fm_arch import CRITEO_TB
 
 
@@ -64,8 +64,9 @@ def _archdef(name, cfg_fn):
         mode = "table" if shape == "train_tablewise" else "row"
         cfg = cfg_fn(mode=mode, batch=batch)
         fn, shardings, bspecs, layout = make_train_step(cfg, mesh)
-        sstructs, _, _, _ = state_struct(cfg, mesh)
-        bstructs, _ = batch_struct(cfg, mesh, layout)
+        mdef = as_hybrid_def(cfg)
+        sstructs, _, _, _ = H.state_struct(mdef, mesh)
+        bstructs, _ = H.batch_struct(mdef, mesh, layout)
         meta = dict(arch=name, shape=shape, kind="train", family="dlrm",
                     batch=cfg.batch, slots=len(cfg.table_rows),
                     pooling=cfg.pooling, emb_dim=cfg.emb_dim,

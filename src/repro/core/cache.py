@@ -236,13 +236,13 @@ def step_cache(mdef, layout, opt, cache: dict, new_emb: dict, emb_ax) -> dict:
     ``promote_every`` steps) re-ranks the hot set from the gathered
     counters and FORCES a refresh; otherwise the slab refreshes on the
     ``hot_sync`` cadence (every step for 'allreduce')."""
-    sync_n = parse_hot_sync(getattr(mdef, "hot_sync", "allreduce"))
-    every = int(getattr(mdef, "promote_every", 1))
+    sync_n = parse_hot_sync(mdef.hot_sync)
+    every = int(mdef.promote_every)
     tick = cache["tick"] + jnp.asarray(1, jnp.int32)
     cnt_full = jax.lax.all_gather(
         new_emb["cnt"][:, 0].astype(jnp.int32), emb_ax, axis=0, tiled=True
     )
-    new_ids = select_hot(layout, cnt_full, int(mdef.hot_rows), int(getattr(mdef, "sr_seed", 0)))
+    new_ids = select_hot(layout, cnt_full, int(mdef.hot_rows), int(mdef.sr_seed))
     promote = (tick % every) == 0
     ids = jnp.where(promote, new_ids, cache["hot_ids"])
     refresh = promote | ((tick % sync_n) == 0)

@@ -3,9 +3,13 @@
 Every recsys architecture here (DLRM, FM, BST, SASRec, DIN) shares one
 skeleton: model-parallel unified embedding + data-parallel dense net +
 all-to-all / reduce-scatter layout switch + fused sparse update + RS+AG
-dense optimizer.  This module hosts the skeleton's *definition*
-(:class:`HybridDef`: what a model must provide) and its state/batch
-structure builders; the step itself is composed from the explicit
+dense optimizer.  This module hosts the skeleton's *definition* —
+:class:`StepOptions` (how the step trains, each option declared once) and
+:class:`HybridDef` (what a model must provide, plus those options) — and
+its state/batch structure builders.  Every model reaches the step as a
+``HybridDef``: the recsys makers build one directly, and a
+``repro.core.dlrm.DLRMConfig`` builds one with ``as_hybrid_def``.  The
+step itself is composed from the explicit
 :class:`repro.core.pipeline.Stage` objects —
 
     index_exchange -> embedding_fwd -> dense_fwd_bwd -> dY_exchange
@@ -43,38 +47,24 @@ from repro.optim import data_parallel as dp
 from repro.optim import row as row_optim
 
 
-@dataclasses.dataclass(frozen=True)
-class HybridDef:
-    """What a hybrid-parallel recsys model must provide."""
-    name: str
-    spec: EmbeddingSpec
-    pooling: int                   # P (max lookups per slot)
-    batch: int                     # global batch
-    init_dense: Callable[[jax.Array], Any]
-    # dense_loss(dense_hi, emb_out [b,S,E] fp32, batch) -> per-shard SUM loss
-    dense_loss: Callable[[Any, jax.Array, dict], jax.Array]
-    # dense_score(dense_hi, emb_out, batch) -> [b] scores
-    dense_score: Callable[[Any, jax.Array, dict], jax.Array]
-    # extra batch fields: name -> (shape-after-B, dtype); all batch-sharded
-    extras: dict = dataclasses.field(default_factory=dict)
-    # slot -> table map (sequence models share one item table across slots)
-    slot_to_table: Optional[tuple] = None
-    emb_mode: str = "row"
+@dataclasses.dataclass(frozen=True, kw_only=True)
+class StepOptions:
+    """How the hybrid step trains a model: placement, optimizers, exchange,
+    pipeline, hot-row cache and telemetry.  Each option is declared here
+    once; :class:`HybridDef` and ``repro.core.dlrm.DLRMConfig`` inherit
+    them."""
+    emb_mode: str = "row"          # 'row' | 'table' (C3 placement)
     # sparse RowOptimizer (repro/optim/row.py): registry name ('sgd',
-    # 'split_sgd', 'momentum', 'adagrad_rowwise', 'adagrad') or a
-    # RowOptimizer instance.  Owns the embedding store layout (weight
-    # slab(s) + per-row state slabs) and the single fused apply the
-    # sparse_update stage dispatches through.  None/'' falls back to the
-    # legacy ``split_sgd`` bool below.
+    # 'split_sgd', 'momentum', 'adagrad_rowwise', 'adagrad',
+    # 'momentum_bf16', 'adagrad_bf16') or a RowOptimizer instance.  Owns
+    # the embedding store layout (weight slab(s) + per-row state slabs)
+    # and the single fused apply the sparse_update stage dispatches
+    # through.  None/'' = 'split_sgd'.
     sparse_optimizer: Optional[Any] = None
     # hyperparameter overrides for the registered optimizer (None = its
     # registered default): momentum coefficient / adagrad denominator floor
     opt_beta: Optional[float] = None
     opt_eps: Optional[float] = None
-    # DEPRECATED sugar (only read when sparse_optimizer is unset): True ->
-    # sparse_optimizer='split_sgd', False -> 'sgd'.  None (default) keeps
-    # the 'split_sgd' fallback without the DeprecationWarning.
-    split_sgd: Optional[bool] = None
     # fused Pallas sparse-bwd + row-optimizer update (kernels/
     # embedding_update) — the split path is bit-identical to the reference,
     # touches O(touched rows) instead of O(shard rows).  None (default) =
@@ -82,31 +72,21 @@ class HybridDef:
     # interpret emulation pays O(shard) per grid step.  True/False forces
     # the choice (A/B, tests).
     fused_update: Optional[bool] = None
-    # typed comm/precision config (repro/dist/exchange.py): the index-
-    # exchange lowering, the per-collective wire formats of the dY
-    # exchange + dense reduce-scatter ('fp32' | 'bf16' | 'bf16_sr'), the
-    # dense error feedback, and the RS+AG bucketing, as ONE frozen
-    # ExchangeConfig.  Mutually exclusive with the flat kwargs below.
-    exchange: Optional[ExchangeConfig] = None
-    # sugar: set BOTH wire dtypes at once ('fp32' is today's wire,
-    # bitwise; 'bf16' halves the compressible collective bytes; 'bf16_sr'
-    # additionally dithers with the seeded sr counter — deterministic and
-    # checkpoint-replayable)
-    exchange_dtype: Optional[str] = None
-    # DEPRECATED flat kwargs, coerced by resolve_exchange with a
-    # DeprecationWarning: compress_grads=True == dense_dtype='bf16' with
-    # error feedback; num_buckets / exchange_impl map to the same-named
-    # ExchangeConfig fields.  None (default) = unset.
-    compress_grads: Optional[bool] = None
-    num_buckets: Optional[int] = None
-    lr: float = 0.01
-    emb_lr: float = 0.01
-    idx_input: str = "replicated"   # 'sharded': on-chip index exchange
+    # comm/precision config (repro/dist/exchange.py): the index-exchange
+    # lowering, the per-collective wire formats of the dY exchange + dense
+    # reduce-scatter ('fp32' | 'bf16' | 'bf16_sr'), the dense error
+    # feedback, and the RS+AG bucketing
+    exchange: ExchangeConfig = ExchangeConfig()
+    lr: float = 0.01               # dense and embedding learning rate
+    # 'replicated': every rank reads the full global minibatch (the
+    # paper's data loader); 'sharded': batch-sharded indices, exchanged on
+    # chip (row AND table mode; table mode also permutes to padded-slot
+    # order on chip)
+    idx_input: str = "replicated"
     # staged pipeline (repro/core/pipeline.py): number of microbatches the
     # global batch is split into, with the index exchange double-buffered
     # across them.  1 = the monolithic step.
     microbatches: int = 1
-    exchange_impl: Optional[str] = None
     # weighted bags: the batch carries a 'weights' field in the exact
     # layout of 'idx' ([B, S, P] per-lookup bag weights); the forward
     # computes sum(w * row) and the sparse update scales dY per lookup.
@@ -120,9 +100,9 @@ class HybridDef:
     host_presort: bool = False
     # initial value of the per-step stochastic-rounding counter (the
     # replicated int32 ``state["sr"]`` scalar, present only when the
-    # resolved RowOptimizer registered stochastic_round=True; incremented
-    # once per step and checkpointed, so a resumed run replays the exact
-    # dither sequence)
+    # resolved RowOptimizer registered stochastic_round=True or a wire
+    # format is 'bf16_sr'; incremented once per step and checkpointed, so
+    # a resumed run replays the exact dither sequence)
     sr_seed: int = 0
     # frequency-tiered hot-row cache (repro/core/cache.py): > 0 keeps a
     # replicated mirror of the top-``hot_rows`` rows PER TABLE (ranked by
@@ -144,6 +124,25 @@ class HybridDef:
     # steps — no per-step host syncs.  False (default) adds NO state key
     # and leaves the lowered step bit-identical to a build without it.
     step_metrics: bool = False
+
+
+@dataclasses.dataclass(frozen=True, kw_only=True)
+class HybridDef(StepOptions):
+    """What a hybrid-parallel recsys model must provide, and the step
+    options it trains with."""
+    name: str
+    spec: EmbeddingSpec
+    pooling: int                   # P (max lookups per slot)
+    batch: int                     # global batch
+    init_dense: Callable[[jax.Array], Any]
+    # dense_loss(dense_hi, emb_out [b,S,E] fp32, batch) -> per-shard SUM loss
+    dense_loss: Callable[[Any, jax.Array, dict], jax.Array]
+    # dense_score(dense_hi, emb_out, batch) -> [b] scores
+    dense_score: Callable[[Any, jax.Array, dict], jax.Array]
+    # extra batch fields: name -> (shape-after-B, dtype); all batch-sharded
+    extras: dict = dataclasses.field(default_factory=dict)
+    # slot -> table map (sequence models share one item table across slots)
+    slot_to_table: Optional[tuple] = None
 
 
 # stage-shaped mesh helpers live in pipeline.py; re-exported for callers
@@ -175,7 +174,7 @@ def state_struct(mdef: HybridDef, mesh):
         ns_total * ex_cfg.num_buckets)
     rows = layout.total_rows
     opt = row_optim.resolve(mdef)
-    hot_rows = getattr(mdef, "hot_rows", 0)
+    hot_rows = mdef.hot_rows
     structs = {
         # the RowOptimizer owns the embedding store layout: weight slab(s)
         # plus zero or more per-row state slabs, all sharded by the same
@@ -209,7 +208,7 @@ def state_struct(mdef: HybridDef, mesh):
         from repro.core import cache as hot_cache
         structs["cache"] = hot_cache.cache_struct(mdef, layout, opt)
         specs["cache"] = hot_cache.cache_specs(structs["cache"])
-    if getattr(mdef, "step_metrics", False):
+    if mdef.step_metrics:
         from repro.telemetry import metrics as step_mx
         structs["metrics"] = step_mx.metrics_struct()
         specs["metrics"] = P()
@@ -300,7 +299,7 @@ def init_state(key, mdef: HybridDef, mesh):
     W = jax.random.uniform(ke, (layout.total_rows, mdef.spec.dim),
                            jnp.float32, -scale, scale)
     opt = row_optim.resolve(mdef)
-    hot_rows = getattr(mdef, "hot_rows", 0)
+    hot_rows = mdef.hot_rows
     # one compiled split, and the fp32 table freed before the MLPs are
     # made: eager bit ops would hold three table-sized temporaries (a
     # chip's share of dlrm-large is a 6 GB table)
@@ -319,7 +318,7 @@ def init_state(key, mdef: HybridDef, mesh):
     if hot_rows > 0:
         from repro.core import cache as hot_cache
         state["cache"] = hot_cache.init_cache(mdef, layout, opt)
-    if getattr(mdef, "step_metrics", False):
+    if mdef.step_metrics:
         from repro.telemetry import metrics as step_mx
         state["metrics"] = step_mx.init_metrics()
     return jax.device_put(state, shardings), layout

@@ -14,8 +14,8 @@ dependence chain per batch; this module decomposes the step into explicit
                      microbatch i's compute consumes buffer i, so the two
                      have no data dependence and XLA's latency-hiding
                      scheduler can overlap them.  jax.lax exposes no public
-                     async collective start/done pair; ``exchange_impl=
-                     "ring"`` decomposes the gather into ns-1 ppermute
+                     async collective start/done pair; ``ExchangeConfig(
+                     impl="ring")`` decomposes the gather into ns-1 ppermute
                      chunks — finer units the scheduler can interleave —
                      and is the hook an async start/done lowers into when
                      the API lands.
@@ -127,8 +127,7 @@ def validate_pipeline(mdef, mesh, microbatches: int) -> None:
     if mdef.idx_input not in ("replicated", "sharded"):
         raise ValueError(f"unknown idx_input {mdef.idx_input!r}; "
                          "expected 'replicated' or 'sharded'")
-    # unknown exchange_impl / wire dtype, flat-kwarg vs typed-config
-    # conflicts, bad num_buckets — all fail here, loudly
+    # an exchange that is not an ExchangeConfig fails here, loudly
     exchange_cfg.resolve_exchange(mdef)
     if microbatches < 1:
         raise ValueError(f"microbatches must be >= 1, got {microbatches}")
@@ -137,15 +136,15 @@ def validate_pipeline(mdef, mesh, microbatches: int) -> None:
         raise ValueError(
             f"global batch {mdef.batch} must be divisible by microbatches "
             f"* mesh size = {microbatches} * {ns}")
-    hot_rows = int(getattr(mdef, "hot_rows", 0))
+    hot_rows = int(mdef.hot_rows)
     if hot_rows < 0:
         raise ValueError(f"hot_rows must be >= 0, got {hot_rows}")
     # validated even with the cache off: a malformed 'deferred:' string
     # should fail at build time, not when hot_rows is finally turned on
     from repro.core import cache as hot_cache
-    hot_cache.parse_hot_sync(getattr(mdef, "hot_sync", "allreduce"))
+    hot_cache.parse_hot_sync(mdef.hot_sync)
     if hot_rows > 0:
-        if int(getattr(mdef, "promote_every", 1)) < 1:
+        if int(mdef.promote_every) < 1:
             raise ValueError("promote_every must be >= 1, got "
                              f"{mdef.promote_every}")
         if hot_rows > mdef.spec.total_rows:
@@ -283,7 +282,7 @@ def build_stages(mdef, mesh, layout) -> PipelineStages:
         # reference scatter-adds per lookup, so those two paths are close
         # but not bit-identical; the split path is bitwise either way.
         return se.apply_update(layout, emb_store, opt, idx_upd, dY,
-                               mdef.emb_lr, emb_ax, replica_axes=None,
+                               mdef.lr, emb_ax, replica_axes=None,
                                fused=fused, weights=weights,
                                presort=presort, seed=seed)
 
@@ -374,11 +373,11 @@ def make_pipelined_train_step(mdef, mesh, microbatches: int = 1):
     repl_width = ns if mdef.emb_mode == "row" else nm
     perm = (jnp.asarray(_interleave_perm(mdef.batch, M, ns))
             if M > 1 else None)
-    weighted = getattr(mdef, "weighted", False)
-    presorted = getattr(mdef, "host_presort", False)
+    weighted = mdef.weighted
+    presorted = mdef.host_presort
     opt = row_optim.resolve(mdef)
     emb_ax, _ = emb_axes(mdef, mesh)
-    cache_on = int(getattr(mdef, "hot_rows", 0)) > 0
+    cache_on = int(mdef.hot_rows) > 0
     # the exact forward bypass needs every bag computed whole by ONE
     # shard and the rank's own index slice available locally: table mode
     # with the on-chip index exchange.  Row mode's psum_scatter folds
@@ -389,7 +388,7 @@ def make_pipelined_train_step(mdef, mesh, microbatches: int = 1):
               and mdef.idx_input == "sharded")
     if cache_on:
         from repro.core import cache as hot_cache
-    metrics_on = bool(getattr(mdef, "step_metrics", False))
+    metrics_on = bool(mdef.step_metrics)
     if metrics_on:
         from repro.telemetry import metrics as step_mx
 

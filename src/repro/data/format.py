@@ -139,7 +139,7 @@ class DatasetSpec:
         dense_x/labels (seq_mask, hist_mask, ...) are not representable
         in packed shards and are rejected HERE, not as a pytree mismatch
         inside shard_map."""
-        extras = getattr(mdef, "extras", {})
+        extras = mdef.extras
         unsupported = sorted(set(extras) - {"dense_x", "labels"})
         if unsupported:
             raise ValueError(
@@ -149,8 +149,8 @@ class DatasetSpec:
         num_dense = (extras["dense_x"][0][0] if "dense_x" in extras else 0)
         self.check(mdef.spec.table_rows, mdef.pooling, num_dense=num_dense,
                    labels="labels" in extras,
-                   slot_to_table=getattr(mdef, "slot_to_table", None),
-                   weighted=getattr(mdef, "weighted", False))
+                   slot_to_table=mdef.slot_to_table,
+                   weighted=mdef.weighted)
 
 
 # ---------------------------------------------------------------------------

@@ -8,13 +8,13 @@ table mode.  This module owns the knob that compresses them, and the API
 those knobs hang off:
 
 :class:`ExchangeConfig`
-    One frozen dataclass consolidating the comm/precision surface that
-    used to sprawl across flat ``HybridDef`` kwargs: the index-exchange
-    lowering (``exchange_impl``), the dense bf16-wire error feedback
-    (``compress_grads``), the RS+AG bucketing (``num_buckets``), and the
-    new per-collective wire dtypes.  Models pass
-    ``exchange=ExchangeConfig(...)``; the old flat kwargs are still
-    accepted and coerced here (with a ``DeprecationWarning``).
+    One frozen dataclass holding the whole comm/precision surface: the
+    index-exchange lowering (``impl``), the per-collective wire dtypes,
+    the dense bf16-wire error feedback and the RS+AG bucketing
+    (``num_buckets``).  Models pass ``exchange=ExchangeConfig(...)``
+    (``repro.core.hybrid.StepOptions``); the launcher's
+    ``--exchange-dtype D`` is ``ExchangeConfig(dY_dtype=D,
+    dense_dtype=D)``.
 
 Wire formats (per collective, ``dY_dtype`` / ``dense_dtype``):
 
@@ -26,9 +26,9 @@ Wire formats (per collective, ``dY_dtype`` / ``dense_dtype``):
 ``"bf16"``
     Round-to-nearest truncation on the wire: halves the table-mode dY
     all_to_all and the dense reduce-scatter payloads.  On the dense path
-    this is the legacy ``compress_grads`` scheme — the fp32 quantization
-    residual of each device's own contribution is carried to the next
-    step (error feedback) so the update stays unbiased.
+    the fp32 quantization residual of each device's own contribution is
+    carried to the next step (error feedback) so the update stays
+    unbiased.
 
     The dY payloads are bitcast to uint16 around the collective so the
     compiled HLO genuinely moves 2 bytes/element (see
@@ -56,7 +56,6 @@ carry into the kept half.
 from __future__ import annotations
 
 import dataclasses
-import warnings
 
 import jax
 import jax.numpy as jnp
@@ -110,7 +109,7 @@ class ExchangeConfig:
     def __post_init__(self):
         if self.impl not in EXCHANGE_IMPLS:
             raise ValueError(
-                f"unknown exchange_impl {self.impl!r}; expected 'fused' "
+                f"unknown ExchangeConfig.impl {self.impl!r}; expected 'fused' "
                 "(one all_gather) or 'ring' (ppermute-chunked)")
         for field, v in (("dY_dtype", self.dY_dtype),
                          ("dense_dtype", self.dense_dtype)):
@@ -133,55 +132,10 @@ class ExchangeConfig:
 
 
 def resolve_exchange(mdef) -> ExchangeConfig:
-    """The ONE reader of a model definition's comm/precision surface.
-
-    Precedence: a typed ``exchange=ExchangeConfig(...)`` wins and must be
-    the only spelling (mixing it with any flat kwarg raises — a stale
-    flat override silently losing to the typed config would be worse).
-    Otherwise the flat kwargs are coerced: ``exchange_dtype`` is
-    supported sugar setting BOTH wire dtypes; ``exchange_impl`` /
-    ``compress_grads`` / ``num_buckets`` are deprecated and warn."""
-    typed = getattr(mdef, "exchange", None)
-    sugar = getattr(mdef, "exchange_dtype", None)
-    impl = getattr(mdef, "exchange_impl", None)
-    compress = getattr(mdef, "compress_grads", None)
-    buckets = getattr(mdef, "num_buckets", None)
-    if typed is not None:
-        if not isinstance(typed, ExchangeConfig):
-            raise TypeError("exchange must be an ExchangeConfig, got "
-                            f"{type(typed).__name__}")
-        clash = [n for n, v in (("exchange_dtype", sugar),
-                                ("exchange_impl", impl),
-                                ("compress_grads", compress),
-                                ("num_buckets", buckets)) if v is not None]
-        if clash:
-            raise ValueError(
-                "pass either exchange=ExchangeConfig(...) or the flat "
-                f"kwargs, not both (flat also set: {', '.join(clash)})")
-        return typed
-    deprecated = [n for n, v in (("exchange_impl", impl),
-                                 ("compress_grads", compress),
-                                 ("num_buckets", buckets)) if v is not None]
-    if deprecated:
-        warnings.warn(
-            f"flat kwarg(s) {', '.join(deprecated)} are deprecated; pass "
-            "exchange=ExchangeConfig(impl=..., dense_dtype=..., "
-            "num_buckets=...) instead (docs/pipeline.md, 'Communication "
-            "precision')", DeprecationWarning, stacklevel=3)
-    if sugar is not None and compress is not None:
-        raise ValueError(
-            "exchange_dtype and compress_grads both set: compress_grads "
-            "is legacy sugar for dense_dtype='bf16' — drop it (or pass a "
-            "full exchange=ExchangeConfig(...))")
-    if sugar is not None:
-        dY = dense = sugar
-    else:
-        dY = "fp32"
-        dense = "bf16" if compress else "fp32"
-    return ExchangeConfig(
-        impl=impl if impl is not None else "fused",
-        dY_dtype=dY, dense_dtype=dense, error_feedback=True,
-        num_buckets=buckets if buckets is not None else 4)
+    """The comm/precision config of a model definition's step."""
+    if not isinstance(mdef.exchange, ExchangeConfig):
+        raise TypeError(f"exchange must be an ExchangeConfig, got {type(mdef.exchange).__name__}")
+    return mdef.exchange
 
 
 def wire_itemsize(dtype: str) -> int:

@@ -153,14 +153,26 @@ def dlrm_config(args):
         cfg = reduced_dlrm(args.arch, args.batch or 256)
         if args.emb_mode:
             cfg = dataclasses.replace(cfg, emb_mode=args.emb_mode)
-    lr = args.lr if args.lr is not None else cfg.lr if args.paper else 0.05
     return dataclasses.replace(
-        cfg, lr=lr, sparse_optimizer=args.optimizer, opt_beta=args.beta,
+        cfg, **step_options(args, cfg.lr if args.paper else 0.05))
+
+
+def step_options(args, default_lr: float) -> dict:
+    """The step options (``repro.core.hybrid.StepOptions``) a run's flags
+    set, the one list every arch's model definition takes; ``default_lr``
+    is the rate when ``--lr`` is not given."""
+    from repro.dist.exchange import ExchangeConfig
+    wire = args.exchange_dtype
+    return dict(
+        lr=default_lr if args.lr is None else args.lr,
+        sparse_optimizer=args.optimizer, opt_beta=args.beta,
         opt_eps=args.eps, microbatches=args.microbatches,
         host_presort=args.host_presort, weighted=args.weighted,
         sr_seed=args.seed, hot_rows=args.hot_rows,
         promote_every=args.promote_every, hot_sync=args.hot_sync,
-        exchange_dtype=args.exchange_dtype, step_metrics=args.step_metrics)
+        exchange=(ExchangeConfig() if wire is None
+                  else ExchangeConfig(dY_dtype=wire, dense_dtype=wire)),
+        step_metrics=args.step_metrics)
 
 
 def build_dlrm(args, mesh, key):
@@ -200,6 +212,13 @@ def build_dlrm(args, mesh, key):
         batch_shardings=_bspec_shardings(mesh, bspecs), stream=stream,
         smoke_stream=lambda: dlrm_stream(1, cfg, args.alpha, layout),
         profile_def=D.as_hybrid_def(cfg))
+
+
+def hybrid_def(args):
+    """The HybridDef a recsys arch (fm | bst | sasrec | din) trains: its
+    reduced config, then the run's step options."""
+    return dataclasses.replace(reduced_hybrid(args.arch, args.batch or 256),
+                               **step_options(args, 0.05))
 
 
 def reduced_hybrid(name: str, batch: int):
@@ -429,21 +448,7 @@ def main():
     elif args.arch in ("fm", "bst", "sasrec", "din"):
         from repro.core import hybrid as H
         from repro.data.synthetic import hybrid_stream
-        lr = 0.05 if args.lr is None else args.lr
-        mdef = dataclasses.replace(reduced_hybrid(args.arch,
-                                                  args.batch or 256),
-                                   lr=lr, emb_lr=lr,
-                                   sparse_optimizer=args.optimizer,
-                                   opt_beta=args.beta, opt_eps=args.eps,
-                                   microbatches=args.microbatches,
-                                   host_presort=args.host_presort,
-                                   weighted=args.weighted,
-                                   sr_seed=args.seed,
-                                   hot_rows=args.hot_rows,
-                                   promote_every=args.promote_every,
-                                   hot_sync=args.hot_sync,
-                                   exchange_dtype=args.exchange_dtype,
-                                   step_metrics=args.step_metrics)
+        mdef = hybrid_def(args)
         state, layout = H.init_state(key, mdef, mesh)
         profile_def = mdef
         step, shardings, bspecs, _ = H.make_train_step(mdef, mesh)

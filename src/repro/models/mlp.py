@@ -1,8 +1,7 @@
 """MLP stack (paper contribution C2's consumer).
 
-Forward runs in bf16 with fp32 accumulation; the activation (ReLU) is fused
-into the GEMM epilogue — via the Pallas ``fused_mlp`` kernel on TPU, or left
-to XLA fusion on other backends (``impl='xla'``, the dry-run path).
+Forward runs in bf16 with fp32 accumulation; XLA fuses the activation
+(ReLU) into the GEMM epilogue.
 """
 
 from __future__ import annotations
@@ -25,21 +24,16 @@ def init_mlp(key: jax.Array, sizes: Sequence[int], dtype=jnp.float32) -> dict:
     return {"w": ws, "b": bs}
 
 
-def mlp_forward(params: dict, x: jax.Array, final_activation: bool = False,
-                impl: str = "xla") -> jax.Array:
+def mlp_forward(params: dict, x: jax.Array,
+                final_activation: bool = False) -> jax.Array:
     """Apply the stack; ReLU between layers, optionally on the last one."""
     n = len(params["w"])
     h = x
     for i, (w, b) in enumerate(zip(params["w"], params["b"])):
         act = final_activation or i < n - 1
-        if impl == "pallas":
-            from repro.kernels.ops import fused_mlp_layer
-            h = fused_mlp_layer(h.astype(jnp.bfloat16), w.astype(jnp.bfloat16),
-                                b, activation="relu" if act else "none")
-        else:
-            y = jnp.dot(h.astype(jnp.bfloat16), w.astype(jnp.bfloat16),
-                        preferred_element_type=jnp.float32) + b.astype(jnp.float32)
-            h = jax.nn.relu(y) if act else y
+        y = jnp.dot(h.astype(jnp.bfloat16), w.astype(jnp.bfloat16),
+                    preferred_element_type=jnp.float32) + b.astype(jnp.float32)
+        h = jax.nn.relu(y) if act else y
         h = h.astype(jnp.bfloat16) if i < n - 1 else h
     return h  # final layer fp32
 

@@ -40,9 +40,9 @@ module is the plug-in point:
   ``adagrad``, and the compressed-state ``momentum_bf16`` /
   ``adagrad_bf16`` (bf16-hi state + seeded stochastic rounding,
   :mod:`repro.optim.stochastic` — half the state bytes per touched row).
-  :func:`resolve` maps a model definition (``HybridDef``/``DLRMConfig``:
-  ``sparse_optimizer=`` + optional ``opt_beta``/``opt_eps``, with the
-  legacy ``split_sgd`` bool as fallback sugar) to an optimizer instance.
+  :func:`resolve` maps a model definition's step options
+  (``sparse_optimizer=`` + optional ``opt_beta``/``opt_eps``) to an
+  optimizer instance.
 
 Determinism / parity contracts (tests/test_row_optim.py,
 tests/test_stochastic.py):
@@ -81,7 +81,6 @@ see the store as an opaque dict of row-aligned slabs.
 from __future__ import annotations
 
 import dataclasses
-import warnings
 from typing import Any, Callable, Optional
 
 import jax
@@ -568,26 +567,12 @@ def make(spec: Any, *, beta: Optional[float] = None,
 
 
 def resolve(mdef: Any) -> RowOptimizer:
-    """RowOptimizer for a model definition (``HybridDef``, ``DLRMConfig``,
-    or anything with the same fields).  ``sparse_optimizer`` (name or
-    instance) wins; a falsy value falls back to the DEPRECATED
-    ``split_sgd`` bool sugar (True -> 'split_sgd', False -> 'sgd'; an
-    explicit bool warns — the unset ``None`` default resolves to
-    'split_sgd' silently).  ``opt_beta``/``opt_eps`` override the
-    registered defaults."""
-    spec = getattr(mdef, "sparse_optimizer", None)
-    if not spec:
-        sugar = getattr(mdef, "split_sgd", None)
-        if sugar is None:
-            spec = "split_sgd"
-        else:
-            warnings.warn(
-                "split_sgd=<bool> is deprecated sugar; pass "
-                "sparse_optimizer='split_sgd' (or 'sgd') instead",
-                DeprecationWarning, stacklevel=2)
-            spec = "split_sgd" if sugar else "sgd"
-    return make(spec, beta=getattr(mdef, "opt_beta", None),
-                eps=getattr(mdef, "opt_eps", None))
+    """RowOptimizer of a model definition's step options
+    (``repro.core.hybrid.StepOptions``): ``sparse_optimizer`` (name or
+    instance; unset = 'split_sgd'), with ``opt_beta``/``opt_eps``
+    overriding the registered defaults."""
+    return make(mdef.sparse_optimizer or "split_sgd", beta=mdef.opt_beta,
+                eps=mdef.opt_eps)
 
 
 register(RowOptimizer(name="sgd", split=False,

@@ -62,7 +62,7 @@ def snapshot_state(mdef, state: dict, *, copy: bool = False) -> dict:
     (:class:`repro.serve.publish.SnapshotPublisher` always copies)."""
     opt = row_optim.resolve(mdef)
     snap = {"emb_w": opt.fwd_weights(state["emb"]), "dense_hi": state["dense"]["hi"]}
-    if getattr(mdef, "hot_rows", 0) > 0:
+    if mdef.hot_rows > 0:
         snap["hot_w"] = state["cache"]["hot_w"]
         snap["hot_pos"] = state["cache"]["hot_pos"]
     if copy:
@@ -77,7 +77,7 @@ def snapshot_specs(mdef, mesh) -> dict:
     specs: dict = {"emb_w": P(emb_ax, None), "dense_hi": None}
     structs, _, _, _ = hybrid.state_struct(mdef, mesh)
     specs["dense_hi"] = jax.tree.map(lambda _: P(), structs["dense"]["hi"])
-    if getattr(mdef, "hot_rows", 0) > 0:
+    if mdef.hot_rows > 0:
         specs["hot_w"] = P()
         specs["hot_pos"] = P()
     return specs

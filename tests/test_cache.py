@@ -33,6 +33,8 @@ jax = pytest.importorskip("jax")
 import jax.numpy as jnp
 
 from repro.core import cache as hot_cache
+from repro.core import dlrm as D
+from repro.core import hybrid as H
 from repro.core import sharded_embedding as se
 from repro.core.embedding import EmbeddingSpec
 from repro.optim import row
@@ -184,8 +186,7 @@ def test_allreduce_cache_is_bitwise_invisible(optimizer, M, presort):
                 host_presort=presort, sr_seed=3)
     layout = None
     if presort:
-        from repro.core import dlrm as D
-        layout = D.make_layout(base, mesh)
+        layout = H.make_layout(D.as_hybrid_def(base), mesh)
     off, loss_off, _ = _run(base, mesh, 4, presort_layout=layout)
     on, loss_on, _ = _run(
         dataclasses.replace(base, hot_rows=8, promote_every=2), mesh, 4,
@@ -197,9 +198,8 @@ def test_allreduce_cache_is_bitwise_invisible(optimizer, M, presort):
     if "sr" in off:
         assert int(off["sr"]) == int(on["sr"])
     # the identity must not be vacuous: the final hot set really hits
-    from repro.core import dlrm as D
     hit, _ = hot_cache.hot_bag_local(
-        D.make_layout(base, mesh), on["cache"]["hot_w"],
+        H.make_layout(D.as_hybrid_def(base), mesh), on["cache"]["hot_w"],
         on["cache"]["hot_pos"], _zipf_batch(3)["idx"])
     assert float(jnp.mean(hit)) > 0.3
 
@@ -250,7 +250,7 @@ def test_deferred_sync_drift_is_real_and_bounded():
     (stale rows really served) but the weight drift stays pinned — the
     cold store is authoritative and absorbs every update."""
     mesh = _mesh()
-    base = _cfg(sparse_optimizer="sgd", split_sgd=False)
+    base = _cfg(sparse_optimizer="sgd")
     off, _, _ = _run(base, mesh, 50)
     on, _, _ = _run(dataclasses.replace(
         base, hot_rows=8, promote_every=5, hot_sync="deferred:8"),

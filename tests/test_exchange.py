@@ -2,13 +2,11 @@
 (repro/dist/exchange.py).
 
 Contracts under test:
-* API: ExchangeConfig validation; resolve_exchange coercion of the
-  deprecated flat kwargs (DeprecationWarning) and the exchange_dtype
-  sugar; typed-config/flat-kwarg conflicts rejected; the legacy
-  ``split_sgd`` bool sugar warns through the same deprecation path;
-  ``parse_hot_sync`` rejects malformed strings.
-* ``exchange_dtype='fp32'`` is BIT-IDENTICAL to the pre-config step
-  across M in {1,2} x row/table x exchange_impl fused/ring (the default
+* API: ExchangeConfig validation; resolve_exchange returns the typed
+  config and rejects anything else; ``parse_hot_sync`` rejects malformed
+  strings.
+* an explicit fp32 wire is BIT-IDENTICAL to the default-config step
+  across M in {1,2} x row/table x impl fused/ring (the default
   config's step is itself pinned against the pre-refactor monolithic
   step in tests/test_pipeline.py, so equality here closes the chain back
   to the pre-PR step).
@@ -56,6 +54,8 @@ COMMON = """
     BASE = DLRMConfig(name='t', num_dense=16, bottom=(32, 8), top=(32,),
                       table_rows=(100, 60, 40, 30, 20, 200, 51, 77),
                       emb_dim=8, pooling=3, batch=32, fused_update=False)
+    # both wires stochastically rounded: the launcher's --exchange-dtype bf16_sr
+    SR_WIRE = ExchangeConfig(dY_dtype='bf16_sr', dense_dtype='bf16_sr')
 
     def mk_batch(seed, cfg, layout):
         rng = np.random.default_rng(seed)
@@ -90,7 +90,7 @@ def test_exchange_config_validation():
                               error_feedback=False).needs_err
     assert ExchangeConfig(dY_dtype="bf16_sr").needs_sr
     assert ExchangeConfig(dense_dtype="bf16_sr").needs_sr
-    with pytest.raises(ValueError, match="exchange_impl"):
+    with pytest.raises(ValueError, match="ExchangeConfig.impl"):
         ExchangeConfig(impl="smoke")
     with pytest.raises(ValueError, match="dY_dtype"):
         ExchangeConfig(dY_dtype="fp16")
@@ -102,69 +102,21 @@ def test_exchange_config_validation():
 
 def test_resolve_exchange_coercion_and_conflicts():
     import dataclasses as dc
+    from repro.core.dlrm import DLRMConfig
     from repro.dist.exchange import ExchangeConfig, resolve_exchange
 
-    @dc.dataclass
-    class M:
-        exchange: object = None
-        exchange_dtype: object = None
-        exchange_impl: object = None
-        compress_grads: object = None
-        num_buckets: object = None
-
-    # unset flats resolve to the defaults, silently
-    assert resolve_exchange(M()) == ExchangeConfig()
-    # exchange_dtype is supported sugar (no warning): sets BOTH dtypes
-    got = resolve_exchange(M(exchange_dtype="bf16_sr"))
-    assert got.dY_dtype == got.dense_dtype == "bf16_sr"
-    # deprecated flat kwargs coerce with a DeprecationWarning
-    with pytest.warns(DeprecationWarning, match="compress_grads"):
-        got = resolve_exchange(M(exchange_impl="ring", compress_grads=True,
-                                 num_buckets=2))
-    assert got == ExchangeConfig(impl="ring", dense_dtype="bf16",
-                                 num_buckets=2)
-    with pytest.warns(DeprecationWarning):
-        got = resolve_exchange(M(compress_grads=False))
-    assert got.dense_dtype == "fp32"
-    # typed config + any flat kwarg is a hard error, not a silent pick
-    with pytest.raises(ValueError, match="not both"):
-        resolve_exchange(M(exchange=ExchangeConfig(), exchange_impl="ring"))
-    with pytest.raises(ValueError, match="not both"):
-        resolve_exchange(M(exchange=ExchangeConfig(),
-                           exchange_dtype="bf16"))
-    # the two dense-wire spellings conflict (the deprecation warning for
-    # compress_grads still fires first — hence the warns wrapper)
-    with pytest.warns(DeprecationWarning):
-        with pytest.raises(ValueError, match="compress_grads"):
-            resolve_exchange(M(exchange_dtype="bf16_sr",
-                               compress_grads=True))
-    with pytest.raises(TypeError, match="ExchangeConfig"):
-        resolve_exchange(M(exchange="bf16"))
-    # bad values surface through resolution too
-    with pytest.raises(ValueError, match="dY_dtype"):
-        resolve_exchange(M(exchange_dtype="fp16"))
-
-
-def test_split_sgd_sugar_deprecated():
-    import dataclasses as dc
-    from repro.optim import row as row_optim
-
-    @dc.dataclass
-    class M:
-        sparse_optimizer: object = None
-        split_sgd: object = None
-        opt_beta: object = None
-        opt_eps: object = None
-
-    # unset -> the split_sgd default, silently
-    assert row_optim.resolve(M()).name == "split_sgd"
-    with pytest.warns(DeprecationWarning, match="split_sgd"):
-        assert row_optim.resolve(M(split_sgd=True)).name == "split_sgd"
-    with pytest.warns(DeprecationWarning, match="split_sgd"):
-        assert row_optim.resolve(M(split_sgd=False)).name == "sgd"
-    # an explicit sparse_optimizer wins and silences the sugar
-    assert row_optim.resolve(
-        M(sparse_optimizer="sgd", split_sgd=False)).name == "sgd"
+    base = DLRMConfig(name="t", num_dense=4, bottom=(8, 4), top=(8,),
+                      table_rows=(10, 20), emb_dim=4, pooling=2)
+    # unset resolves to the defaults
+    assert resolve_exchange(base) is base.exchange
+    assert resolve_exchange(base) == ExchangeConfig()
+    # a typed config comes back unchanged
+    typed = ExchangeConfig(impl="ring", dense_dtype="bf16", num_buckets=2)
+    assert resolve_exchange(dc.replace(base, exchange=typed)) is typed
+    # anything else is refused
+    for bad in ("bf16", None):
+        with pytest.raises(TypeError, match="ExchangeConfig"):
+            resolve_exchange(dc.replace(base, exchange=bad))
 
 
 def test_parse_hot_sync_validation():
@@ -182,24 +134,26 @@ def test_parse_hot_sync_validation():
 # ---------------------------------------------------------------------------
 
 def test_fp32_bit_identity_matrix():
-    """exchange_dtype='fp32' == the default-config step, bitwise, across
-    M x mode x impl; the typed ExchangeConfig spelling matches the flat
-    exchange_impl spelling bitwise too."""
+    """An explicit fp32 wire == the default-config step, bitwise, across
+    M x mode x impl; so does an fp32 wire with error feedback off (it has
+    nothing to feed back)."""
     out = run_sub(COMMON + """
-    import warnings
-    warnings.simplefilter('ignore', DeprecationWarning)
     for mode in ('row', 'table'):
         for M in (1, 2):
             for impl in ('fused', 'ring'):
                 base = dataclasses.replace(BASE, emb_mode=mode,
                                            idx_input='sharded',
                                            microbatches=M)
+                fp32 = dict(impl=impl, dY_dtype='fp32', dense_dtype='fp32')
                 variants = {
-                    'default': dataclasses.replace(base, exchange_impl=impl),
-                    'fp32': dataclasses.replace(base, exchange_impl=impl,
-                                                exchange_dtype='fp32'),
+                    'default': (base if impl == 'fused' else
+                                dataclasses.replace(
+                                    base, exchange=ExchangeConfig(impl=impl))),
+                    'fp32': dataclasses.replace(
+                        base, exchange=ExchangeConfig(**fp32)),
                     'typed': dataclasses.replace(
-                        base, exchange=ExchangeConfig(impl=impl)),
+                        base, exchange=ExchangeConfig(**fp32,
+                                                      error_feedback=False)),
                 }
                 res = {}
                 for tag, cfg in variants.items():
@@ -306,7 +260,7 @@ def test_bf16_sr_deterministic_and_seeded():
         res = {}
         for tag, seed in (('a', 0), ('b', 0), ('c', 11)):
             cfg = dataclasses.replace(BASE, emb_mode=mode,
-                                      exchange_dtype='bf16_sr',
+                                      exchange=SR_WIRE,
                                       microbatches=2, sr_seed=seed)
             state, layout = init_state(jax.random.PRNGKey(0), cfg, mesh)
             step, _, _, _ = make_train_step(cfg, mesh)
@@ -329,7 +283,7 @@ def test_bf16_sr_checkpoint_resume_replays_wire_dither():
     from repro.checkpoint import CheckpointManager
 
     cfg = dataclasses.replace(BASE, emb_mode='table', idx_input='sharded',
-                              exchange_dtype='bf16_sr', microbatches=2)
+                              exchange=SR_WIRE, microbatches=2)
     step, shardings, _, _ = make_train_step(cfg, mesh)
 
     state, layout = init_state(jax.random.PRNGKey(0), cfg, mesh)

@@ -390,8 +390,9 @@ def test_packed_presorted_train_round_trip(tmp_path):
             res = {{}}
             for tag in ('inproc', 'packed'):
                 cfg = dataclasses.replace(
-                    BASE, emb_mode='row', split_sgd=split, microbatches=M,
-                    host_presort=(tag == 'packed'))
+                    BASE, emb_mode='row',
+                    sparse_optimizer='split_sgd' if split else 'sgd',
+                    microbatches=M, host_presort=(tag == 'packed'))
                 state, layout = init_state(jax.random.PRNGKey(0), cfg, mesh)
                 step, _, _, _ = make_train_step(cfg, mesh)
                 if tag == 'packed':

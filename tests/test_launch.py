@@ -27,14 +27,51 @@ def test_paper_flag_trains_the_registered_config(mode):
     want = dlrm_small(mode=mode)
     run_flags = ("sparse_optimizer", "opt_beta", "opt_eps", "microbatches",
                  "host_presort", "weighted", "sr_seed", "hot_rows",
-                 "promote_every", "hot_sync", "exchange_dtype",
-                 "step_metrics")
+                 "promote_every", "hot_sync", "exchange", "step_metrics")
     assert dataclasses.replace(
         cfg, **{k: getattr(want, k) for k in run_flags}) == want
     assert cfg.batch == 8192 and cfg.lr == want.lr
     smaller = T.dlrm_config(T.parse_args(
         ["--arch", "dlrm-small", "--paper", "--batch", "512"]))
     assert smaller.batch == 512 and smaller.table_rows == want.table_rows
+
+
+STEP_FLAGS = [
+    (["--lr", "0.3"], "lr", 0.3),
+    (["--optimizer", "momentum"], "sparse_optimizer", "momentum"),
+    (["--optimizer", "momentum", "--beta", "0.5"], "opt_beta", 0.5),
+    (["--optimizer", "adagrad", "--eps", "0.001"], "opt_eps", 0.001),
+    (["--microbatches", "2"], "microbatches", 2),
+    (["--host-presort"], "host_presort", True),
+    (["--weighted"], "weighted", True),
+    (["--seed", "7"], "sr_seed", 7),
+    (["--hot-rows", "8"], "hot_rows", 8),
+    (["--promote-every", "3"], "promote_every", 3),
+    (["--hot-sync", "deferred:4"], "hot_sync", "deferred:4"),
+    (["--exchange-dtype", "bf16"], "exchange", "bf16"),
+    (["--step-metrics"], "step_metrics", True),
+]
+
+
+@pytest.mark.parametrize("flags, field, value", STEP_FLAGS,
+                         ids=[f for _, f, _ in STEP_FLAGS])
+def test_step_flag_reaches_every_arch(flags, field, value):
+    """A step flag reaches the HybridDef a dlrm arch and a recsys arch
+    train with the same value, and the cases cover every step option the
+    launcher sets."""
+    from repro.core.dlrm import as_hybrid_def
+    from repro.dist.exchange import ExchangeConfig
+    from repro.launch import train as T
+    if field == "exchange":
+        value = ExchangeConfig(dY_dtype=value, dense_dtype=value)
+    dlrm = as_hybrid_def(T.dlrm_config(T.parse_args(
+        ["--arch", "dlrm-small", *flags])))
+    fm = T.hybrid_def(T.parse_args(["--arch", "fm", *flags]))
+    assert getattr(dlrm, field) == getattr(fm, field) == value
+    plain = T.hybrid_def(T.parse_args(["--arch", "fm"]))
+    assert getattr(plain, field) != value
+    assert set(T.step_options(T.parse_args(["--arch", "fm"]), 0.05)) == {
+        f for _, f, _ in STEP_FLAGS}
 
 
 def test_paper_flag_rejects_archs_without_a_paper_config():

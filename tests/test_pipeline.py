@@ -95,7 +95,8 @@ def test_microbatch_state_identity_property():
                 for M in (1, 2, 4):
                     cfg = dataclasses.replace(
                         BASE, emb_mode=mode, idx_input=inp,
-                        split_sgd=split, microbatches=M, batch=64)
+                        sparse_optimizer='split_sgd' if split else 'sgd',
+                        microbatches=M, batch=64)
                     state, layout = init_state(jax.random.PRNGKey(seed),
                                                cfg, mesh)
                     step, _, _, _ = make_train_step(cfg, mesh)
@@ -208,12 +209,13 @@ def test_ring_exchange_bit_identical():
             check_vma=False))
         assert np.array_equal(np.asarray(f1(x)), np.asarray(f2(x))), axes
 
+    from repro.dist.exchange import ExchangeConfig
     for mode in ('row', 'table'):
         outs = {}
         for impl in ('fused', 'ring'):
             cfg = dataclasses.replace(BASE, emb_mode=mode,
                                       idx_input='sharded', microbatches=2,
-                                      exchange_impl=impl)
+                                      exchange=ExchangeConfig(impl=impl))
             state, layout = init_state(jax.random.PRNGKey(0), cfg, mesh)
             step, _, _, _ = make_train_step(cfg, mesh)
             batch = mk_batch(0, cfg, layout)
@@ -257,13 +259,13 @@ def test_score_step_sharded_inputs():
     """Serve path reuses the exchange stage: scores identical between
     replicated and sharded index input, row and table mode."""
     out = run_sub(COMMON + """
-    from repro.core import dlrm as D
+    from repro.core import dlrm as D, hybrid as H
     for mode in ('row', 'table'):
         sc = {}
         for inp in ('replicated', 'sharded'):
             cfg = dataclasses.replace(BASE, emb_mode=mode, idx_input=inp)
             state, layout = init_state(jax.random.PRNGKey(0), cfg, mesh)
-            ev, _, _, _ = D.make_eval_step(cfg, mesh)
+            ev, _, _, _ = H.make_score_step(D.as_hybrid_def(cfg), mesh)
             batch = mk_batch(3, cfg, layout)
             sc[inp] = np.asarray(ev(state, batch))
         np.testing.assert_allclose(sc['replicated'], sc['sharded'],
@@ -283,6 +285,7 @@ def test_unsupported_combinations_rejected():
     import jax
     from repro.core import pipeline
     from repro.core.dlrm import DLRMConfig, make_train_step
+    from repro.dist.exchange import ExchangeConfig
     from repro.launch.mesh import make_mesh
 
     mesh = make_mesh((1, 1), ("data", "model"))
@@ -298,9 +301,10 @@ def test_unsupported_combinations_rejected():
         make_train_step(base, mesh, microbatches=0)
     with pytest.raises(ValueError, match="divisible"):
         make_train_step(base, mesh, microbatches=5)
-    with pytest.raises(ValueError, match="exchange_impl"):
-        make_train_step(dataclasses.replace(base, exchange_impl="smoke"),
-                        mesh)
+    with pytest.raises(ValueError, match="ExchangeConfig.impl"):
+        ExchangeConfig(impl="smoke")
+    with pytest.raises(TypeError, match="ExchangeConfig"):
+        make_train_step(dataclasses.replace(base, exchange="ring"), mesh)
 
 
 def test_retrieval_rejects_sharded_and_normalizes_extras():

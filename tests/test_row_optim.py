@@ -82,18 +82,21 @@ def test_registry_names_and_overrides():
 
 
 def test_resolve_legacy_and_explicit():
-    class Obj:
-        sparse_optimizer = None
-        split_sgd = True
-    assert row.resolve(Obj()).name == "split_sgd"
-    Obj.split_sgd = False
-    assert row.resolve(Obj()).name == "sgd"
-    Obj.sparse_optimizer = "momentum"
-    Obj.opt_beta = 0.25
-    assert row.resolve(Obj()).beta == 0.25
-    Obj.sparse_optimizer = row.get("adagrad")
-    del Obj.opt_beta
-    assert row.resolve(Obj()).name == "adagrad"
+    import dataclasses
+    from repro.core.hybrid import StepOptions
+    opts = StepOptions()
+    # unset (None or '') -> the split_sgd default
+    assert row.resolve(opts).name == "split_sgd"
+    assert row.resolve(dataclasses.replace(
+        opts, sparse_optimizer="")).name == "split_sgd"
+    assert row.resolve(dataclasses.replace(
+        opts, sparse_optimizer="sgd")).name == "sgd"
+    got = row.resolve(dataclasses.replace(
+        opts, sparse_optimizer="momentum", opt_beta=0.25))
+    assert got.name == "momentum" and got.beta == 0.25
+    got = row.resolve(dataclasses.replace(
+        opts, sparse_optimizer=row.get("adagrad"), opt_eps=1e-3))
+    assert got.name == "adagrad" and got.eps == 1e-3
 
 
 def test_ops_entry_points_only_called_from_row():

@@ -20,6 +20,7 @@ from jax.sharding import PartitionSpec as P
 from repro import compat
 from repro.configs.dlrm_paper import chip_share
 from repro.core import dlrm as D
+from repro.core import hybrid as H
 from repro.core import pipeline
 from repro.launch.mesh import make_mesh
 from repro.optim import row as row_optim
@@ -41,7 +42,7 @@ def _stages(cfg, fused):
     cfg = dataclasses.replace(cfg, weighted=True, fused_update=fused)
     mesh = make_mesh((1, 1), ("data", "model"))
     mdef = D.as_hybrid_def(cfg)
-    layout = D.make_layout(cfg, mesh)
+    layout = H.make_layout(mdef, mesh)
     st = pipeline.build_stages(mdef, mesh, layout)
     opt = row_optim.resolve(mdef)
 

@@ -229,6 +229,7 @@ def _described_step(topo, cfg):
     the kernel compiled (not interpreted), for one described chip."""
     from jax.sharding import AxisType, Mesh, NamedSharding
     from repro.core import dlrm as D
+    from repro.core import hybrid as H
     from repro.kernels import ops
     mesh = Mesh(np.asarray(topo.devices[:1]).reshape(1, 1),
                 ("data", "model"), axis_types=(AxisType.Auto,) * 2)
@@ -242,8 +243,9 @@ def _described_step(topo, cfg):
     with pytest.MonkeyPatch.context() as mp:
         mp.setattr(ops, "_default_interpret", lambda: False)
         step, shardings, bspecs, layout = D.make_train_step(cfg, mesh)
-        structs, _, _, _ = D.state_struct(cfg, mesh)
-        bstructs, _ = D.batch_struct(cfg, mesh, layout)
+        mdef = D.as_hybrid_def(cfg)
+        structs, _, _, _ = H.state_struct(mdef, mesh)
+        bstructs, _ = H.batch_struct(mdef, mesh, layout)
         state = jax.tree.map(placed, structs, shardings)
         batch = jax.tree.map(
             lambda s, spec: placed(s, NamedSharding(mesh, spec)),

@@ -21,6 +21,7 @@ jax = pytest.importorskip("jax")
 import jax.numpy as jnp
 
 from repro.core import dlrm as D
+from repro.core import hybrid as H
 from repro.launch.mesh import make_mesh
 
 TABLES = (100, 60, 40, 30, 20, 200, 51, 77)
@@ -73,7 +74,7 @@ def test_weighted_forward_matches_manual_bag():
     mesh = _mesh()
     cfg = dataclasses.replace(BASE, emb_mode="row", weighted=True)
     state, layout = D.init_state(jax.random.PRNGKey(1), cfg, mesh)
-    ev, _, _, _ = D.make_eval_step(cfg, mesh)
+    ev, _, _, _ = H.make_score_step(D.as_hybrid_def(cfg), mesh)
     rng = np.random.default_rng(2)
     # power-of-two weights: bf16-row * w products are exact in fp32 and a
     # 3-term sum of 8-bit mantissas fits fp32 exactly, so the manual bag
@@ -90,7 +91,7 @@ def test_weighted_forward_matches_manual_bag():
     bag = (hi[g] * w[..., None]).sum(axis=2)            # [B, S, E] fp32
     bag = np.asarray(jnp.asarray(bag, jnp.bfloat16), np.float32)
     logits = D.forward_local(state["dense"]["hi"], jnp.asarray(bag),
-                             b["dense_x"], cfg.mlp_impl)
+                             b["dense_x"])
     want = np.asarray(jax.nn.sigmoid(logits))
     np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-6)
 
@@ -156,7 +157,6 @@ def test_weighted_presort_bakes_weights():
 
 
 def test_score_step_weighted_and_retrieval_rejects():
-    from repro.core import hybrid as H
     from repro.models import recsys as R
     mesh = _mesh()
     mdef = dataclasses.replace(R.make_fm((50,) * 6, batch=8), weighted=True)
