@@ -422,9 +422,10 @@ def _row_sorted_streams(layout: ShardedEmbeddingLayout, g_flat: jax.Array,
                         start, pooling: int,
                         weights_flat: Optional[jax.Array] = None) -> tuple:
     """Device-side sorted streams for the ROW-mode fused update, computed
-    from the GLOBAL row ids: one axis-INVARIANT stable argsort of the
-    global keys, then an elementwise localization into this shard's
-    window (subtract ``start``, mask, clip).  Two reasons this shape —
+    from the GLOBAL row ids: one axis-INVARIANT stable sort of the global
+    keys with their flat positions (``sort_by_key``), then an elementwise
+    localization into this shard's window (subtract ``start``, mask,
+    clip).  Two reasons this shape —
     and not a per-shard sort of the axis-index-derived local rows:
 
     * the global sort is computed once and identically on every shard
@@ -438,16 +439,14 @@ def _row_sorted_streams(layout: ShardedEmbeddingLayout, g_flat: jax.Array,
     Non-owned lookups keep ``msk == 0`` and clip to row 0 / R-1 — exact
     no-op rewrites (stateless kinds) or flag-guarded write-throughs
     (stateful kinds) under the kernel's liveness contract."""
+    from repro.kernels.embedding_update import sort_by_key
     G = layout.total_rows
     R = layout.rows_per_shard
     with jax.named_scope("lookup_sort"):
         in_range = (g_flat >= 0) & (g_flat < G)
         key = jnp.where(in_range, g_flat, G).astype(jnp.int32)
-        order = jnp.argsort(key)             # stable: ties in flat order
-        skey = jnp.take(key, order)
+        skey, order, wgt = sort_by_key(key, weights_flat)
         bags = (order // pooling).astype(jnp.int32)
-        wgt = (jnp.ones(key.shape, jnp.float32) if weights_flat is None
-               else jnp.take(weights_flat.astype(jnp.float32), order))
         local = skey - start
         msk = ((skey < G) & (local >= 0) & (local < R)).astype(jnp.int32)
         rows = jnp.clip(local, 0, R - 1)

@@ -16,7 +16,7 @@ Two jobs, both off the device critical path:
 2. **Pre-sort**: the fused sparse-update kernel
    (repro/kernels/embedding_update.py) wants the flat lookup stream
    sorted by local row id so duplicate rows form contiguous runs.
-   Without host prep, every step pays an on-device ``argsort`` over
+   Without host prep, every step pays an on-device stable sort over
    ``L = B*S*P`` keys.  :func:`presort_batch` computes, per embedding
    shard, the EXACT arrays ``kernels.embedding_update.sort_lookups``
    would produce — stable sort permutations are unique, so numpy here
@@ -66,7 +66,8 @@ def presort_batch(layout, idx: np.ndarray,
     Bit-compatibility with the on-device ``sort_lookups`` path is
     structural: same int32 key construction, and a stable argsort's
     permutation is uniquely determined by the keys, so
-    ``np.argsort(kind='stable')`` here equals ``jnp.argsort`` there.
+    ``np.argsort(kind='stable')`` here equals the device's stable sort
+    (``sort_by_key``) there.
     """
     B, S, P = idx.shape
     ns, R = layout.num_shards, layout.rows_per_shard

@@ -481,6 +481,23 @@ def sparse_row_update_pallas(step, slabs, sorted_rows, sorted_bags,
     return tuple(v.T if c else v for v, c in zip(out, cols))
 
 
+def sort_by_key(key: jax.Array, weights: jax.Array | None = None
+                ) -> tuple[jax.Array, jax.Array, jax.Array]:
+    """One stable sort of ``(key, flat position[, weight])`` by ``key``.
+
+    Returns ``(sorted_key, order, sorted_weights)``: ``order`` is the flat
+    position of each sorted lookup (``argsort(key)``; ties keep their flat
+    order), and ``sorted_weights`` the fp32 ``weights`` in that order, or
+    ones without weights.  Every stream comes out of the sort itself, so
+    nothing is gathered by ``order`` afterwards."""
+    iota = jax.lax.iota(jnp.int32, key.shape[0])
+    if weights is None:
+        return (*jax.lax.sort_key_val(key, iota, is_stable=True),
+                jnp.ones(key.shape, jnp.float32))
+    return tuple(jax.lax.sort((key, iota, weights.astype(jnp.float32)),
+                              num_keys=1, is_stable=True))
+
+
 def sort_lookups(tgt: jax.Array, valid: jax.Array | None, num_rows: int,
                  pooling: int, weights: jax.Array | None = None
                  ) -> tuple[jax.Array, jax.Array, jax.Array, jax.Array]:
@@ -499,11 +516,8 @@ def sort_lookups(tgt: jax.Array, valid: jax.Array | None, num_rows: int,
         valid = ((tgt >= 0) & (tgt < num_rows)) if valid is None else (
             valid & (tgt >= 0) & (tgt < num_rows))
         key = jnp.where(valid, tgt, num_rows).astype(jnp.int32)
-        order = jnp.argsort(key)                  # stable: ties in flat order
-        sorted_key = jnp.take(key, order)
+        sorted_key, order, sorted_wgt = sort_by_key(key, weights)
         sorted_rows = jnp.minimum(sorted_key, num_rows - 1)
         sorted_bags = (order // pooling).astype(jnp.int32)
         sorted_msk = (sorted_key < num_rows).astype(jnp.int32)
-        sorted_wgt = (jnp.ones(tgt.shape, jnp.float32) if weights is None
-                      else jnp.take(weights.astype(jnp.float32), order))
     return sorted_rows, sorted_bags, sorted_msk, sorted_wgt

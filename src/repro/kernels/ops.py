@@ -156,7 +156,7 @@ def fused_row_update_presorted(opt, store, srows, sbags, smsk, swgt, dY,
     supplies the ``(sorted_rows, sorted_bags, sorted_msk, sorted_wgt)``
     arrays of ``sort_lookups`` (produced per shard by
     ``repro.data.pipeline.presort_batch`` while the previous step runs on
-    device) and the per-step XLA argsort disappears from the hot path.
+    device) and the per-step XLA sort disappears from the hot path.
     Bit-identical to the sorting entry point — a stable sort's permutation
     is unique, so host and device sorts agree exactly.  ``shards``: the
     shards the stream's lookups spread over (``sparse_row_update_pallas``),

@@ -183,6 +183,76 @@ def test_sort_lookups_properties():
     np.testing.assert_array_equal(np.asarray(w1), np.ones(7, np.float32))
 
 
+def _argsort_take(key, weights):
+    """The streams' former formula, kept as the oracle: argsort, then a
+    gather of the keys (and weights) by the order."""
+    order = jnp.argsort(key)
+    wgt = (jnp.ones(key.shape, jnp.float32) if weights is None
+           else jnp.take(weights.astype(jnp.float32), order))
+    return jnp.take(key, order), order, wgt
+
+
+def _old_sort_lookups(tgt, valid, num_rows, pooling, weights):
+    ok = (tgt >= 0) & (tgt < num_rows)
+    key = jnp.where(ok if valid is None else valid & ok, tgt,
+                    num_rows).astype(jnp.int32)
+    skey, order, wgt = _argsort_take(key, weights)
+    return (jnp.minimum(skey, num_rows - 1),
+            (order // pooling).astype(jnp.int32),
+            (skey < num_rows).astype(jnp.int32), wgt)
+
+
+def _old_row_sorted_streams(G, R, g_flat, start, pooling, weights):
+    key = jnp.where((g_flat >= 0) & (g_flat < G), g_flat, G).astype(jnp.int32)
+    skey, order, wgt = _argsort_take(key, weights)
+    local = skey - start
+    return (jnp.clip(local, 0, R - 1), (order // pooling).astype(jnp.int32),
+            ((skey < G) & (local >= 0) & (local < R)).astype(jnp.int32), wgt)
+
+
+# (name, lookups, id vocabulary (None: the whole space), share of ids out
+# of range or masked invalid, row shard, pooling)
+_STREAM_CASES = [
+    ("heavy_ties", 4096, 5, 0.0, 0, 8),
+    ("out_of_range_and_invalid", 777, None, 0.3, 0, 3),
+    ("shard_start", 513, None, 0.05, 2, 1),
+    ("one_lookup", 1, None, 0.0, 1, 1),
+    ("chunk_plus_one", EU.CHUNK + 1, None, 0.1, 3, 50),
+]
+
+
+@pytest.mark.parametrize("weighted", [False, True], ids=["sum", "weighted"])
+@pytest.mark.parametrize("name,L,vocab,bad,shard,P", _STREAM_CASES,
+                         ids=[c[0] for c in _STREAM_CASES])
+def test_sorted_streams_equal_argsort_then_take(name, L, vocab, bad, shard,
+                                                P, weighted):
+    """``sort_lookups`` (table mode) and ``_row_sorted_streams`` (row
+    mode) take every stream from the one stable sort: their four arrays
+    are bitwise those of argsort followed by a gather by its order."""
+    from repro.core import sharded_embedding as SE
+    layout = SE.make_layout(E.EmbeddingSpec((100, 37, 250), 8), 4, "row")
+    G, R = layout.total_rows, layout.rows_per_shard
+    start = shard * R
+    rng = np.random.default_rng(L)
+    g = (rng.integers(0, G, L) if vocab is None
+         else start + rng.integers(0, vocab, L))
+    bad_at = rng.random(L) < bad
+    g = np.where(bad_at, rng.choice([-3, G, G + 17], L), g).astype(np.int32)
+    valid = jnp.asarray(rng.random(L) >= bad)
+    w = (jnp.asarray(rng.standard_normal(L), jnp.float32) if weighted
+         else None)
+    local = jnp.asarray(g - start, jnp.int32)
+    got = EU.sort_lookups(local, valid, R, P, weights=w)
+    want = _old_sort_lookups(local, valid, R, P, w)
+    got_row = SE._row_sorted_streams(layout, jnp.asarray(g), start, P, w)
+    want_row = _old_row_sorted_streams(G, R, jnp.asarray(g), start, P, w)
+    for a, b in zip(got + got_row, want + want_row):
+        assert a.dtype == b.dtype and a.shape == b.shape == (L,)
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+    if vocab:                                   # the ties are there
+        assert len(np.unique(g)) <= vocab < L
+
+
 # ---------------------------------------------------------------------------
 # Weighted bags (per-lookup weights) on the fused path
 # ---------------------------------------------------------------------------

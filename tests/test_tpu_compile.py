@@ -25,6 +25,8 @@ program; a compile that passes is not a chip run.
 import base64
 import dataclasses
 import re
+import sys
+from pathlib import Path
 
 import jax
 import jax.numpy as jnp
@@ -285,6 +287,17 @@ def test_dlrm_small_train_step_compiles_on_one_chip(dlrm_small_step, mode):
     assert _slab_relayouts(text, structs["emb"].values()) == ["bf16 copy"]
 
 
+_BENCH = Path(__file__).resolve().parents[1] / "bench"
+
+
+def _bench_trace():
+    """The benchmark's HLO reader and layer rules (``bench/harness``)."""
+    if str(_BENCH) not in sys.path:
+        sys.path.insert(0, str(_BENCH))
+    from harness import trace
+    return trace
+
+
 # the stage each layer of the benchmark's older call-stack rules
 # (bench/layers/dlrm/) belongs to; ``dense`` is either dense stage
 _STAGE_OF_LAYER = {"emb_fwd": "embedding_fwd", "dense": "dense_*",
@@ -338,13 +351,7 @@ def test_dlrm_small_step_carries_stage_scopes_and_kernel_name(
     ``sparse_row_update``; the benchmark's stage rules and its older
     call-stack rules put every instruction the device runs in the same
     stage, in both directions, but for the named exceptions."""
-    import sys
-    from pathlib import Path
-    bench = Path(__file__).resolve().parents[1] / "bench"
-    if str(bench) not in sys.path:
-        sys.path.insert(0, str(bench))
-    from harness import trace as T
-
+    T = _bench_trace()
     text = dlrm_small_step(mode)[0].as_text()
     op_names = set(re.findall(r'op_name="([^"]*)"', text))
     # on one chip the index exchange issues no op in either mode, nor the
@@ -362,8 +369,8 @@ def test_dlrm_small_step_carries_stage_scopes_and_kernel_name(
     assert any("/sparse_row_update/" in o for o in op_names)
 
     instrs = T.parse_hlo(text)
-    old = T.classify(instrs, T.load_layers(bench / "layers" / "dlrm"))
-    new = T.classify(instrs, T.load_layers(bench / "stages" / "dlrm"))
+    old = T.classify(instrs, T.load_layers(_BENCH / "layers" / "dlrm"))
+    new = T.classify(instrs, T.load_layers(_BENCH / "stages" / "dlrm"))
     # the kernel is the sparse update under both rule sets: a rename of
     # the kernel or of the function that calls it fails here
     for k in kernels:
@@ -457,3 +464,25 @@ def test_dlrm_large_share_step_carries_stage_scopes(dlrm_large_share_step):
                          r'"tpu_custom_call"', text)
     assert kernels and all(k.startswith("sparse_row_update.")
                            for k in kernels), kernels
+
+
+@pytest.mark.parametrize("step", ["dlrm-small-row", "dlrm-small-table",
+                                  "dlrm-large-share"])
+def test_lookup_sort_is_one_sort_and_no_gather(request, step):
+    """The lookup sort takes the sorted keys (and weights) from the stable
+    sort itself: the benchmark's ``lookup_sort`` layer holds exactly one
+    ``sort``, and no gather, fused bodies included, carries its scope."""
+    T = _bench_trace()
+    if step == "dlrm-large-share":
+        compiled = request.getfixturevalue("dlrm_large_share_step")[0]
+    else:
+        mode = step.rsplit("-", 1)[1]
+        compiled = request.getfixturevalue("dlrm_small_step")(mode)[0]
+    instrs = T.parse_hlo(compiled.as_text())
+    layer = T.classify(instrs, T.load_layers(_BENCH / "layers" / "dlrm"))
+    sorts = [n for n, i in instrs.items()
+             if i.opcode == "sort" and layer[n] == "lookup_sort"]
+    assert len(sorts) == 1, sorts
+    gathers = [(n, i.op_name) for n, i in instrs.items()
+               if i.opcode == "gather" and "/lookup_sort/" in i.op_name]
+    assert not gathers, gathers
